@@ -70,11 +70,12 @@ def _free_port() -> int:
     return port
 
 
-def _spawn_server(spec: dict, env: dict) -> subprocess.Popen:
+def _spawn_server(spec: dict, env: dict,
+                  stderr=subprocess.DEVNULL) -> subprocess.Popen:
     return subprocess.Popen(
         [sys.executable, "-m", "foundationdb_tpu.net.server_main",
          json.dumps(spec)],
-        stdout=subprocess.PIPE, stderr=subprocess.DEVNULL, env=env)
+        stdout=subprocess.PIPE, stderr=stderr, env=env)
 
 
 def _kill_stray_servers():
@@ -82,15 +83,26 @@ def _kill_stray_servers():
     bench run. The host is a single shared core: one stray `server_main`
     spinning in the background taxes every subsequent measurement by tens
     of percent, and unlike host-load drift the tax is one-sided — it never
-    averages out across interleaved trials."""
+    averages out across interleaved trials. Only ORPHANS (reparented to
+    pid 1) are strays: a live harness's children — another test worker's
+    cluster, chip_smoke.py's — are not this run's to kill."""
     for pat in ("foundationdb_tpu.net.server_main", "bench_e2e.py --worker"):
-        subprocess.run(["pkill", "-f", pat], stdout=subprocess.DEVNULL,
+        subprocess.run(["pkill", "-P", "1", "-f", pat],
+                       stdout=subprocess.DEVNULL,
                        stderr=subprocess.DEVNULL, check=False)
 
 
 def _boot_cluster(tmp, backend="oracle", n_proxies=2, n_storage=2,
                   trace_dir=None, extra_knobs=None, n_grv_proxies=0,
-                  n_replicas=1):
+                  n_replicas=1, cut_keys=None, boot_deadline=None,
+                  log_dir=None):
+    """Boot the process cluster and wait for every server's `ready` line.
+
+    `cut_keys` overrides the storage shard cuts (default: the k%06d keytab
+    split evenly); `boot_deadline` the seconds every server gets to boot
+    (a cold device core compiles its bucket programs inside it);
+    `log_dir` keeps each server's stderr in `<log_dir>/<label>.stderr`
+    instead of discarding it."""
     from foundationdb_tpu.server.interfaces import Token
 
     txn_knobs = {"CONFLICT_BACKEND": backend}
@@ -158,9 +170,13 @@ def _boot_cluster(tmp, backend="oracle", n_proxies=2, n_storage=2,
              for s in range(n_storage)]
 
     # keyspace split into n_storage contiguous shards over k%06d
-    cut_keys = [b"k%06d" % (KEYS * i // n_storage)
-                for i in range(1, n_storage)]
-    boundaries = [b""] + cut_keys
+    if cut_keys is None:
+        cut_keys = [b"k%06d" % (KEYS * i // n_storage)
+                    for i in range(1, n_storage)]
+    if len(cut_keys) != n_storage - 1:
+        raise ValueError(f"{n_storage} shards need {n_storage - 1} cut "
+                         f"keys, got {len(cut_keys)}")
+    boundaries = [b""] + list(cut_keys)
     shard_spec = {"boundaries": [b.hex() for b in boundaries],
                   "tags": [[s * n_replicas + r for r in range(n_replicas)]
                            for s in range(n_storage)]}
@@ -241,17 +257,13 @@ def _boot_cluster(tmp, backend="oracle", n_proxies=2, n_storage=2,
     if trace_dir:
         env["FDBTPU_TRACE_DIR"] = trace_dir  # span files for trace_analyze
     # the core process hosts the resolver: for the device backend it takes
-    # whatever accelerator jax finds (the real TPU on the bench box, CPU
-    # otherwise); proxy/storage/client processes stay off the device. The
-    # persistent compile cache makes the boot-time warmup compile a
-    # once-per-machine cost.
+    # the accelerator jax finds, and dies at boot if there is none;
+    # proxy/storage/client processes stay off the device. The persistent
+    # compile cache (server_main: utils/jaxenv.enable_compile_cache) makes
+    # the boot-time warmup compile a once-per-checkout cost.
     core_env = dict(env)
     if backend != "oracle" and not os.environ.get("FDBTPU_E2E_FORCE_CPU"):
         core_env.pop("JAX_PLATFORMS", None)
-        core_env.setdefault("JAX_COMPILATION_CACHE_DIR",
-                            "/tmp/fdb_tpu_jax_cache")
-        core_env.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS",
-                            "1.0")
     # FDBTPU_E2E_HOST_DEVICES=N: pin the core process's XLA host platform to
     # N virtual devices — how the sharded backend gets a multi-device mesh
     # on a CPU-only host (tier-1 smoke runs it at N=2)
@@ -261,16 +273,23 @@ def _boot_cluster(tmp, backend="oracle", n_proxies=2, n_storage=2,
                  if not f.startswith("--xla_force_host_platform_device_count")]
         flags.append(f"--xla_force_host_platform_device_count={host_devices}")
         core_env["XLA_FLAGS"] = " ".join(flags)
-    procs = [_spawn_server(core_spec, core_env)]
+    def spawn(spec, env, label):
+        if log_dir is None:
+            return _spawn_server(spec, env)
+        with open(os.path.join(log_dir, f"{label}.stderr"), "wb") as err:
+            return _spawn_server(spec, env, stderr=err)
+
+    procs = [spawn(core_spec, core_env, "core")]
     # labels aligned with `procs`: the per-process CPU split keys on these
     labels = ["core"]
     for spec in proxy_specs + storage_specs:
-        procs.append(_spawn_server(spec, env))
         labels.append(os.path.basename(spec["data_dir"]))
-    # bounded boot: a device-backend core can hang for minutes attaching a
-    # remote accelerator that has not released its previous client; kill
-    # the whole boot instead of stalling the bench forever
-    deadline = time.monotonic() + (600 if backend != "oracle" else 120)
+        procs.append(spawn(spec, env, labels[-1]))
+    # bounded boot: a device-backend core compiles its bucket programs
+    # before it answers; kill the whole boot instead of waiting forever
+    if boot_deadline is None:
+        boot_deadline = 600 if backend != "oracle" else 120
+    deadline = time.monotonic() + boot_deadline
     import selectors
     for p in procs:
         sel = selectors.DefaultSelector()
@@ -282,15 +301,18 @@ def _boot_cluster(tmp, backend="oracle", n_proxies=2, n_storage=2,
                 if time.monotonic() >= deadline:
                     for q in procs:
                         q.kill()
+                        q.wait()
                     raise TimeoutError(
-                        f"server {p.args[-1][:60]}... did not boot "
-                        f"(accelerator attach hung?)")
+                        f"server {p.args[-1][:60]}... did not boot within "
+                        f"{boot_deadline:.0f}s")
                 continue
             chunk = p.stdout.read1(4096)
             if not chunk:
                 for q in procs:
                     q.kill()
-                raise RuntimeError("server died during boot")
+                    q.wait()
+                raise RuntimeError(
+                    f"server {labels[procs.index(p)]} died during boot")
             buf += chunk
         sel.close()
         assert buf.startswith(b"ready"), buf[:120]

@@ -682,9 +682,8 @@ class MissingDonation(Rule):
 class RawDeviceTransfer(Rule):
     code = "DEV007"
     summary = ("jax.device_put/device_get outside the utils/jaxenv.py choke "
-               "points — bypasses platform honoring and bounded discovery "
-               "(can hang on a wedged runtime); use jaxenv.device_put/"
-               "device_get")
+               "points — moves bytes the resolver's transfer counters never "
+               "see; use jaxenv.device_put/device_get")
 
     def check(self, mod: ModuleContext) -> Iterable[Finding]:
         if mod.relpath == _SANCTIONED_TRANSFER_MODULE:
@@ -697,9 +696,8 @@ class RawDeviceTransfer(Rule):
                 yield self.finding(
                     mod, node, origin,
                     f"raw {origin}() outside utils/jaxenv.py — transfers "
-                    f"must go through the jaxenv choke points so "
-                    f"JAX_PLATFORMS stays honored and discovery stays "
-                    f"bounded")
+                    f"must go through the jaxenv choke points so every "
+                    f"one of them is counted")
 
 
 # -------------------------------------------------------------- DEV008

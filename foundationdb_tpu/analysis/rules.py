@@ -371,8 +371,8 @@ _DEVICE_ROOT_PREFIXES = ("jax.numpy.", "jax.lax.")
 class DeviceEvalAtImport(Rule):
     code = "FLOW006"
     summary = ("jnp/jax evaluation at module import time — initializes the "
-               "device backend for every importer (and hangs if the "
-               "accelerator runtime is wedged)")
+               "device backend for every importer (and takes the chip from "
+               "the one process that serves with it)")
 
     def check(self, mod: ModuleContext) -> Iterable[Finding]:
         for node in ast.walk(mod.tree):

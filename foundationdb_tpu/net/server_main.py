@@ -69,8 +69,10 @@ def build_role(process, role: str, args: dict):
 
 def main(spec_json: str):
     from foundationdb_tpu.net.transport import NetTransport, RealEventLoop
+    from foundationdb_tpu.utils.jaxenv import enable_compile_cache
     from foundationdb_tpu.utils.knobs import KNOBS
 
+    enable_compile_cache()
     spec = json.loads(spec_json)
     for k, v in spec.get("knobs", {}).items():
         KNOBS.set(k, v)
@@ -103,8 +105,7 @@ def main(spec_json: str):
     import signal
     # graceful SIGTERM always: unwind through finally so the transport
     # closes and, on device-backend servers, the accelerator client is
-    # destroyed cleanly — a hard kill mid-dispatch can wedge a
-    # remote-attached device runtime for every later client
+    # destroyed cleanly before the next process asks for the chip
     signal.signal(signal.SIGTERM,
                   lambda *_a: loop.aio.call_soon_threadsafe(loop.aio.stop))
     prof_path = os.environ.get("FDBTPU_PROFILE")

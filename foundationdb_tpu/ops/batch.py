@@ -43,7 +43,7 @@ def validate_conflict_config(backend=None, num_shards=None):
     """Fail at worker boot on a misconfigured resolver, not on the first
     commit batch minutes later. Arguments default to the live knobs; the
     device-count check against CONFLICT_NUM_SHARDS happens later, at engine
-    construction, where discovery is already bounded."""
+    construction, once the devices are attached."""
     from foundationdb_tpu.utils.errors import FDBError
     from foundationdb_tpu.utils.knobs import KNOBS
 

@@ -42,13 +42,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:  # jax >= 0.6 exposes shard_map at top level with check_vma
-    shard_map = jax.shard_map
-    _SHARD_MAP_CHECK_KW = "check_vma"
-except AttributeError:  # jax 0.4.x: experimental module, kwarg is check_rep
-    from jax.experimental.shard_map import shard_map
-    _SHARD_MAP_CHECK_KW = "check_rep"
-
 from foundationdb_tpu.utils import keys as keylib
 from foundationdb_tpu.ops.batch import TOO_OLD, TxnConflictInfo
 from foundationdb_tpu.ops.conflict import (
@@ -220,7 +213,7 @@ def _build_sharded_step(mesh: Mesh, shapes: ConflictShapes,  # noqa: C901
         "snapshot": P(), "txn_valid": P(), "commit_version": P(),
         "advance_floor": P(),
     }
-    sharded = shard_map(
+    sharded = jax.shard_map(
         local_step, mesh=mesh,
         in_specs=(state_specs, batch_specs),
         out_specs=(state_specs, P(), {"overflow": P(), "boundaries": P(),
@@ -230,7 +223,7 @@ def _build_sharded_step(mesh: Mesh, shapes: ConflictShapes,  # noqa: C901
         # and become shard-varying inside the loop; the static replication /
         # VMA check can't type that, so it is disabled (collectives are only
         # pmin/pmax).
-        **{_SHARD_MAP_CHECK_KW: False},
+        check_vma=False,
     )
     from foundationdb_tpu.ops.conflict import _donate_state_argnums
     return jax.jit(sharded, donate_argnums=_donate_state_argnums())
@@ -277,8 +270,6 @@ class ShardedDeviceConflictSet:
                  writes_per_txn: int | None = None, oldest_version: int = 0,
                  cut_bytes: list[bytes] | None = None):
         from foundationdb_tpu.ops.conflict import BatchEncoder, _resolve_shapes
-        from foundationdb_tpu.utils.jaxenv import ensure_platform_honored
-        ensure_platform_honored()
         self.mesh = mesh or make_resolver_mesh()
         self.n_shards = self.mesh.devices.size
         self.shapes = _resolve_shapes(capacity, txns, reads_per_txn, writes_per_txn)

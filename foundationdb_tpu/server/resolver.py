@@ -44,24 +44,24 @@ def new_conflict_set(oldest_version: int = 0,
     outer cut while the sharded engine's mesh subdivides [begin, end) as the
     inner one, so the two compose instead of fighting over the keyspace.
 
-    Device backends attach the accelerator lazily on their first jax call —
-    which, on a wedged remote runtime, hangs with no deadline. Bound the
-    discovery FIRST: if the probe can't attach within its deadline the
-    process is pinned to CPU and the engine is constructed (and labeled)
-    as a cpu-fallback instead of hanging warmup()/recovery.
+    Device backends attach the accelerator here, in-process, once
+    (utils/jaxenv.serving_platform). If JAX finds no accelerator, or
+    attaching raises, this raises and the server process dies at boot with
+    the reason on stderr. Only when JAX_PLATFORMS=cpu was set from outside
+    (tier-1 tests, the simulator) does a device backend run on the CPU, and
+    only there does CONFLICT_CPU_FALLBACK pick its evaluator.
     """
     validate_conflict_config()
     if KNOBS.CONFLICT_BACKEND in ("device", "sharded"):
-        from foundationdb_tpu.utils.jaxenv import bound_device_discovery
-        backend_label = bound_device_discovery()
-        if (backend_label in ("cpu", "cpu-fallback", "initialized")
-                and KNOBS.CONFLICT_CPU_FALLBACK == "host"):
-            # No accelerator attached: the XLA-on-CPU step costs ~10-20x the
-            # host skiplist per txn (one core runs BOTH the engine and the
-            # whole pipeline), so degrade the *evaluator* to the exact host
-            # path while keeping the backend knob's serving contract.
-            # Decisions are identical by construction (the oracle is the
-            # semantic authority the device kernel is fuzzed against).
+        from foundationdb_tpu.utils.jaxenv import serving_platform
+        backend_label = serving_platform()
+        if backend_label == "cpu" and KNOBS.CONFLICT_CPU_FALLBACK == "host":
+            # The operator asked for the CPU: the XLA-on-CPU step costs
+            # ~10-20x the host skiplist per txn (one core runs BOTH the
+            # engine and the whole pipeline), so degrade the *evaluator* to
+            # the exact host path while keeping the backend knob's serving
+            # contract. Decisions are identical by construction (the oracle
+            # is the semantic authority the device kernel is fuzzed against).
             cs = OracleConflictSet(oldest_version=oldest_version)
             cs.backend_label = f"{backend_label}+host-evaluator"
             return cs
@@ -76,7 +76,7 @@ def new_conflict_set(oldest_version: int = 0,
             ShardedDeviceConflictSet, make_resolver_mesh,
             shard_cut_bytes_range)
         n = int(KNOBS.CONFLICT_NUM_SHARDS)
-        avail = len(jax.devices())  # discovery already bounded above
+        avail = len(jax.devices())
         if n > avail:
             raise FDBError(
                 "invalid_option",
@@ -112,7 +112,10 @@ class Resolver:
             # stall the pipeline for tens of seconds. Subsequent
             # constructions (recoveries) hit the in-process jit cache;
             # cross-process runs hit the persistent compile cache.
+            import time
+            t0 = time.perf_counter()
             self.conflict_set.warmup()
+            self._warmup_seconds = round(time.perf_counter() - t0, 3)
         self._recent_replies: dict[int, ResolveTransactionBatchReply] = {}
         # retained state (metadata) transactions for other proxies' catch-up
         # (Resolver.actor.cpp:59-62,170-224): version -> [(locally_committed,
@@ -177,6 +180,10 @@ class Resolver:
         snap = self.counters.as_dict()
         snap["Version"] = self.version.get()
         snap["Backend"] = getattr(self.conflict_set, "backend_label", "oracle")
+        snap["Poisoned"] = self._poisoned is not None
+        if self._pipelined:
+            snap.update(jaxenv.device_identity())
+            snap["WarmupSeconds"] = self._warmup_seconds
         snap.update(conflict.kernel_metrics.as_dict())
         snap.update(conflict.compile_cache_stats())
         snap.update(jaxenv.transfer_metrics.as_dict())

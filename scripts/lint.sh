@@ -20,7 +20,7 @@ if [[ "${1:-}" == "--github" ]]; then
 fi
 
 # Keep the gate itself off the accelerator: the analyzer is pure AST work,
-# and a wedged remote runtime must not be able to hang CI lint.
+# and must not take the chip from a process that serves with it.
 export JAX_PLATFORMS=cpu
 
 status=0

@@ -1,0 +1,128 @@
+"""The commit path's device programs, compiled for a described TPU v5e.
+
+The TPU compiler is installed here and compiles for a chip that is described,
+not attached (no chip time, nothing runs): it raises what the chip's compiler
+would raise — a program too large, a sort it refuses, an unaligned slice.
+Code that asks `jax.default_backend()` sees the CPU here, so the donated
+programs are jitted by the tests themselves. A compile that passes is not a
+chip run; `chip_smoke.py` is.
+
+All of these live in ONE file and describe the topology inside a module
+fixture: only one process may load the TPU library, and it is the worker that
+is given this file, once one of its tests has started.
+"""
+
+import functools
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from foundationdb_tpu.ops import conflict as C
+from foundationdb_tpu.ops.conflict import BatchEncoder, ConflictShapes
+from foundationdb_tpu.parallel import sharded_conflict as S
+
+WINDOW = 5_000_000
+SMALL = ConflictShapes(capacity=8192, txns=64, reads=128, writes=128)
+# the full bucket chip_smoke.py's core serves with (SERVED_KNOBS)
+SERVED = ConflictShapes(capacity=1 << 18, txns=256, reads=2560, writes=2560)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — whatever keeps libtpu from loading
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache but
+    # cannot be read back without the chip: keep the cache out of these
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+
+
+def _specs(tree, sharding):
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(np.shape(a), np.asarray(a).dtype,
+                                       sharding=sharding), tree)
+
+
+def _step_args(shapes, sharding):
+    return (_specs(C.init_state(shapes), sharding),
+            _specs(BatchEncoder(shapes).encode_batch([], 1, shapes=shapes),
+                   sharding))
+
+
+def _compile_donated_step(shapes, topo):
+    step = jax.jit(functools.partial(
+        C.conflict_step, shapes=shapes, max_write_life=WINDOW,
+        intra_mode="scan", intra_rounds=0), donate_argnums=(0,))
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    compiled = step.lower(*_step_args(shapes, one_chip)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.generated_code_size_in_bytes > 0
+    # the donated state is written in place: the chip holds one copy of it
+    assert mem.alias_size_in_bytes > 0
+    return mem
+
+
+def test_conflict_step_donated_small_shape(topo):
+    _compile_donated_step(SMALL, topo)
+
+
+@pytest.mark.slow(reason="45-80 s to compile (PERF.md, compile times)")
+def test_conflict_step_donated_served_shape(topo):
+    mem = _compile_donated_step(SERVED, topo)
+    assert mem.temp_size_in_bytes < 1 << 30  # 294 MB when written
+
+
+def test_combine_fn(topo):
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    T = SERVED.txns
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    C._combine_fn().lower(sds((T,), jnp.int32), sds((T,), jnp.bool_),
+                          sds((), jnp.bool_), sds((), jnp.bool_)).compile()
+
+
+def test_rebase_donated(topo):
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    rebase = jax.jit(C.rebase_state, donate_argnums=(0,))
+    compiled = rebase.lower(
+        _specs(C.init_state(SMALL), one_chip),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)).compile()
+    assert compiled.memory_analysis().alias_size_in_bytes > 0
+
+
+def test_sharded_step_on_four_device_mesh(topo, monkeypatch):
+    """The SPMD step over a mesh of the four described chips, state sharded
+    along the resolver axis, batch replicated: the verdict combine must
+    compile to cross-chip collectives."""
+    # the chip donates the state; default_backend() here says cpu
+    monkeypatch.setattr(C, "_donate_state_argnums", lambda: (0,))
+    mesh = Mesh(np.asarray(topo.devices[:4]), (S.RESOLVER_AXIS,))
+    shapes = ConflictShapes(capacity=1024, txns=16, reads=32, writes=32)
+    step = S._build_sharded_step(mesh, shapes, WINDOW, "scan",
+                                 shapes.txns // 2 + 1)
+    state = _specs(S.init_sharded_state(shapes, 4),
+                   NamedSharding(mesh, P(S.RESOLVER_AXIS)))
+    batch = _specs(BatchEncoder(shapes).encode_batch([], 1, shapes=shapes),
+                   NamedSharding(mesh, P()))
+    compiled = step.lower(state, batch).compile()
+    assert "all-reduce" in compiled.as_text()  # pmin/pmax over the mesh
+    # each chip holds a quarter of the stacked state, not all of it
+    per_device = compiled.memory_analysis().argument_size_in_bytes
+    whole = sum(np.prod(s.shape) * s.dtype.itemsize
+                for s in jax.tree.leaves(state))
+    assert per_device < whole / 2
