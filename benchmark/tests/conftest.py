@@ -1,0 +1,13 @@
+"""benchmark/tests: run by hand with `pytest benchmark/tests`, on the CPU.
+Not part of the repository's tier-1 tests."""
+
+import os
+import sys
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+BENCH = os.path.dirname(HERE)
+ROOT = os.path.dirname(BENCH)
+for p in (ROOT, BENCH):
+    if p not in sys.path:
+        sys.path.insert(0, p)
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
