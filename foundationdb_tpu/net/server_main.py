@@ -113,11 +113,6 @@ def main(spec_json: str):
         import cProfile
         pr = cProfile.Profile()
         pr.enable()
-    sampler = None
-    if os.environ.get("FDBTPU_SAMPLING_PROFILE"):
-        from foundationdb_tpu.utils.profiler import SamplingProfiler
-        sampler = SamplingProfiler()
-        sampler.start()
     trace_file = None
     trace_dir = os.environ.get("FDBTPU_TRACE_DIR")
     if trace_dir:
@@ -128,15 +123,13 @@ def main(spec_json: str):
         trace_file = trace.RollingTraceFile(os.path.join(
             trace_dir, f"trace.{spec['listen'].replace(':', '_')}.jsonl"))
         trace.set_sink(trace_file.write)
+        trace.span_full_collections()
     try:
         loop.aio.run_forever()
     finally:
         if prof_path:
             pr.disable()
             pr.dump_stats(f"{prof_path}.{spec['listen'].replace(':', '_')}")
-        if sampler is not None:
-            sampler.stop()
-            sampler.trace_report(who=spec["listen"])
         if trace_file is not None:
             from foundationdb_tpu.utils.trace import g_trace_batch, set_sink
             # final counter dump: a short run may never reach the periodic

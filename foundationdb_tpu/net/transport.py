@@ -106,14 +106,57 @@ class RealEventLoop(EventLoop):
     future resolves.
     """
 
+    HEARTBEAT_SECONDS = 0.05
+
     def __init__(self):
         super().__init__()
         self.aio = asyncio.new_event_loop()
         self._pool = None  # lazily-built thread pool for run_blocking
         self._ready: list = []  # delay-0 callbacks drained one batch/tick
+        # slow-task watch: `beat` is stamped by a timer that re-arms itself,
+        # so that an idle loop is not mistaken for a held one; `held` is
+        # left by the watch thread while the stamp is late
+        self.beat = time.monotonic()
+        self.held: tuple | None = None
+        self._watch = None
+        self.aio.call_soon(self._heartbeat)  # runs once the loop does
 
     def now(self) -> float:
         return time.monotonic()
+
+    def _heartbeat(self):
+        now = time.monotonic()
+        held, self.held = self.held, None
+        if held is not None and held[0] == self.beat:
+            self._slow_task(held[1], now, held[2])
+        self.beat = now
+        if self._watch is None:
+            from foundationdb_tpu.utils.profiler import SlowTaskWatch
+            self._watch = SlowTaskWatch(self, self.HEARTBEAT_SECONDS)
+            self._watch.start()
+        self.aio.call_later(self.HEARTBEAT_SECONDS, self._heartbeat)
+
+    def _slow_task(self, began: float, ended: float, stack: tuple):
+        """Net2's SlowTask: the loop did not tick from `began` to `ended`;
+        `stack` is what its thread was running when the watch looked."""
+        from foundationdb_tpu.utils import stats
+        from foundationdb_tpu.utils.trace import (
+            SevWarnAlways, TraceEvent, g_trace_batch)
+        took = round(ended - began, 6)
+        stats.loop_stalls.increment()
+        stats.loop_stall_seconds.increment(took)
+        stats.loop_stall_max_seconds.set(
+            max(stats.loop_stall_max_seconds.value, took))
+        frames = [f"{name} ({filename.rsplit('/', 1)[-1]}:{line})"
+                  for name, filename, line in stack[-12:]]
+        TraceEvent("SlowTask", severity=SevWarnAlways) \
+            .detail("Duration", took) \
+            .detail("Leaf", frames[-1]) \
+            .detail("Stack", frames) \
+            .log()
+        ident = f"stall{stats.loop_stalls.value}"
+        g_trace_batch.span_begin("LoopSpan", ident, "Loop.SlowTask", at=began)
+        g_trace_batch.span_end("LoopSpan", ident, "Loop.SlowTask", at=ended)
 
     def run_blocking(self, fn) -> Future:
         """Run fn() on a worker thread; the loop keeps serving meanwhile.

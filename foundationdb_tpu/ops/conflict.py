@@ -45,6 +45,10 @@ prefix, which can only create false conflicts (safe), never false commits
 from __future__ import annotations
 
 import functools
+import json
+import os
+import re
+import time
 from dataclasses import dataclass
 
 import numpy as np
@@ -54,10 +58,13 @@ import jax.numpy as jnp
 from jax import lax
 
 from foundationdb_tpu.ops.batch import COMMITTED, CONFLICT, TOO_OLD, TxnConflictInfo
+from foundationdb_tpu.utils import jaxenv
 from foundationdb_tpu.utils import keys as keylib
+from foundationdb_tpu.utils import trace
 from foundationdb_tpu.utils.errors import FDBError
 from foundationdb_tpu.utils.knobs import KNOBS
 from foundationdb_tpu.utils.stats import CounterCollection
+from foundationdb_tpu.utils.trace import g_trace_batch
 
 # Process-wide device-kernel gauges (merged into RESOLVER_METRICS):
 # dispatch count from detect_async_impl, chunks that DetectHandle.result
@@ -106,6 +113,35 @@ _NEG_INT = -(1 << 30)
 # jnp expressions promote it exactly like the former device constant.
 NEG = _NEG_INT
 _REBASE_THRESHOLD = 1 << 29
+# the named_scopes inside conflict_step, in program order (what a profile's
+# operations are grouped by; docs/observability.md has the table)
+SCOPES = ("sort", "history", "intra", "merge", "gc", "table")
+
+
+def _profiler_annotation(span: str, ident: str, mono_us: int):
+    return jax.profiler.TraceAnnotation(span, id=ident, mono_us=mono_us)
+
+
+_HLO_INSTRUCTION = re.compile(
+    r'^\s+(?:ROOT )?%([\w.\-]+) = .*\bop_name="([^"]*)"', re.MULTILINE)
+
+
+def scope_map(hlo_text: str) -> dict[str, str]:
+    """{instruction: scope} for every instruction of a compiled module's
+    text whose op_name passes through one of SCOPES."""
+    out = {}
+    for name, op_name in _HLO_INSTRUCTION.findall(hlo_text):
+        scope = next((p for p in op_name.split("/") if p in SCOPES), None)
+        if scope is not None:
+            out[name] = scope
+    return out
+
+
+def install_profiler_annotator() -> None:
+    """Put utils/trace's sections on the profiler's timeline. Called by the
+    device engines' constructors: only a process that builds one has a chip
+    to profile, and utils/trace itself stays off JAX."""
+    trace.set_annotator(_profiler_annotation)
 
 
 def _bulk_encode_at(keys: list[bytes], slots: list[int], out: np.ndarray, *,
@@ -423,64 +459,70 @@ def conflict_step(state: dict, batch: dict, *, shapes: ConflictShapes,
     snapshot, txn_valid = batch["snapshot"], batch["txn_valid"]
     vnew = batch["commit_version"]
 
-    if shapes.strided:
-        # slot validity from the key itself: real keys never carry the
-        # 0xFFFFFFFF length limb the padding uses, so empty-but-real ranges
-        # (b == e) still count as "has reads" for the too-old rule
-        rvalid = rb[L - 1] != jnp.uint32(0xFFFFFFFF)
-        wvalid = wb[L - 1] != jnp.uint32(0xFFFFFFFF)
-        has_reads = rvalid.reshape(T, NR // T).any(axis=1)
-    else:
-        rvalid = rtxn < T
-        wvalid = wtxn < T
-        has_reads = (jnp.zeros(T + 1, bool).at[rtxn].max(rvalid))[:T]
-
-    # ---- 0. THE sort: [state | rb | re | wb | we] ----
-    # Class tiebreak at equal keys: re(0) < state(1) < rb/wb/we(2).
-    #  - rb after equal state keys  -> #state<=rb = upper bound (segment of rb)
-    #  - re before equal state keys -> #state<re  = lower bound
-    #  - wb/we after equal state keys -> duplicate endpoint lands in the SAME
-    #    union slot as the state boundary it equals
-    N_ALL = K + 2 * NR + 2 * NW
-    allk = jnp.concatenate([bkeys, rb, re, wb, we], axis=1)  # (L, N_ALL)
-    cls = jnp.concatenate([
-        jnp.ones(K, jnp.int32),
-        jnp.full(NR, 2, jnp.int32), jnp.zeros(NR, jnp.int32),
-        jnp.full(2 * NW, 2, jnp.int32)])
-    vpay = jnp.concatenate([bval, jnp.full(2 * NR + 2 * NW, NEG, jnp.int32)])
-    sidx = _lex_sort_perm(jnp.concatenate(
-        [allk, cls.astype(jnp.uint32)[None]]))  # original element index
-    skeys = allk[:, sidx]                   # (L, N_ALL) sorted
-    scls = cls[sidx]
-    sval = vpay[sidx]                       # state values in sorted order
-    # inverse permutation: sorted position of each original element
-    spos = jnp.zeros(N_ALL, jnp.int32).at[sidx].set(
-        jnp.arange(N_ALL, dtype=jnp.int32))
-    is_state = scls == 1
-    cum_state = jnp.cumsum(is_state.astype(jnp.int32))  # inclusive
-
-    # ---- 1. too-old (only txns with read ranges expire: SkipList.cpp:985) ----
-    too_old = txn_valid & has_reads & (snapshot < oldest)
-
-    # ---- 2. history check: range-max of step function vs snapshot ----
-    if ablate in ("no_hist", "only_merge"):
-        hist_conflict = jnp.zeros(T, bool)
-    else:
-        ub_rb = cum_state[spos[K:K + NR]]        # #state keys <= rb
-        lb_re = cum_state[spos[K + NR:K + 2 * NR]]  # #state keys < re
-        i0 = jnp.maximum(ub_rb - 1, 0)  # segment containing begin
-        i1 = lb_re  # first boundary >= end
-        nonempty = _key_lt(rb, re)
-        maxver = _range_max(table, i0, jnp.maximum(i1, i0 + 1))
-        rsnap = (jnp.repeat(snapshot, NR // T) if shapes.strided
-                 else snapshot[jnp.minimum(rtxn, T - 1)])
-        read_hits = rvalid & nonempty & (maxver > rsnap)
+    # The numbered phases below run inside jax.named_scope, which names the
+    # phase in every operation's metadata and changes nothing else: a
+    # profile of the step program reads its device time by phase (SCOPES).
+    with jax.named_scope("history"):
         if shapes.strided:
-            hist_conflict = read_hits.reshape(T, NR // T).any(axis=1)
+            # slot validity from the key itself: real keys never carry the
+            # 0xFFFFFFFF length limb the padding uses, so empty-but-real
+            # ranges (b == e) still count as "has reads" for the too-old rule
+            rvalid = rb[L - 1] != jnp.uint32(0xFFFFFFFF)
+            wvalid = wb[L - 1] != jnp.uint32(0xFFFFFFFF)
+            has_reads = rvalid.reshape(T, NR // T).any(axis=1)
         else:
-            hist_conflict = (jnp.zeros(T + 1, bool).at[rtxn].max(read_hits))[:T]
+            rvalid = rtxn < T
+            wvalid = wtxn < T
+            has_reads = (jnp.zeros(T + 1, bool).at[rtxn].max(rvalid))[:T]
 
-    g0 = txn_valid & ~too_old & ~hist_conflict
+    with jax.named_scope("sort"):
+        # ---- 0. THE sort: [state | rb | re | wb | we] ----
+        # Class tiebreak at equal keys: re(0) < state(1) < rb/wb/we(2).
+        #  - rb after equal state keys  -> #state<=rb = upper bound (segment of rb)
+        #  - re before equal state keys -> #state<re  = lower bound
+        #  - wb/we after equal state keys -> duplicate endpoint lands in the SAME
+        #    union slot as the state boundary it equals
+        N_ALL = K + 2 * NR + 2 * NW
+        allk = jnp.concatenate([bkeys, rb, re, wb, we], axis=1)  # (L, N_ALL)
+        cls = jnp.concatenate([
+            jnp.ones(K, jnp.int32),
+            jnp.full(NR, 2, jnp.int32), jnp.zeros(NR, jnp.int32),
+            jnp.full(2 * NW, 2, jnp.int32)])
+        vpay = jnp.concatenate([bval, jnp.full(2 * NR + 2 * NW, NEG, jnp.int32)])
+        sidx = _lex_sort_perm(jnp.concatenate(
+            [allk, cls.astype(jnp.uint32)[None]]))  # original element index
+        skeys = allk[:, sidx]                   # (L, N_ALL) sorted
+        scls = cls[sidx]
+        sval = vpay[sidx]                       # state values in sorted order
+        # inverse permutation: sorted position of each original element
+        spos = jnp.zeros(N_ALL, jnp.int32).at[sidx].set(
+            jnp.arange(N_ALL, dtype=jnp.int32))
+        is_state = scls == 1
+        cum_state = jnp.cumsum(is_state.astype(jnp.int32))  # inclusive
+
+    with jax.named_scope("history"):
+        # ---- 1. too-old (only txns with read ranges expire: SkipList.cpp:985) ----
+        too_old = txn_valid & has_reads & (snapshot < oldest)
+
+        # ---- 2. history check: range-max of step function vs snapshot ----
+        if ablate in ("no_hist", "only_merge"):
+            hist_conflict = jnp.zeros(T, bool)
+        else:
+            ub_rb = cum_state[spos[K:K + NR]]        # #state keys <= rb
+            lb_re = cum_state[spos[K + NR:K + 2 * NR]]  # #state keys < re
+            i0 = jnp.maximum(ub_rb - 1, 0)  # segment containing begin
+            i1 = lb_re  # first boundary >= end
+            nonempty = _key_lt(rb, re)
+            maxver = _range_max(table, i0, jnp.maximum(i1, i0 + 1))
+            rsnap = (jnp.repeat(snapshot, NR // T) if shapes.strided
+                     else snapshot[jnp.minimum(rtxn, T - 1)])
+            read_hits = rvalid & nonempty & (maxver > rsnap)
+            if shapes.strided:
+                hist_conflict = read_hits.reshape(T, NR // T).any(axis=1)
+            else:
+                hist_conflict = (jnp.zeros(T + 1, bool).at[rtxn].max(read_hits))[:T]
+
+        g0 = txn_valid & ~too_old & ~hist_conflict
     if ablate in ("no_intra", "only_merge", "only_hist"):
         commit = g0
         statuses = jnp.where(
@@ -491,105 +533,106 @@ def conflict_step(state: dict, batch: dict, *, shapes: ConflictShapes,
                             max_write_life, ablate, sort_products=(
                                 skeys, scls, sval, sidx, spos, cum_state),
                             eligible=g0)
-    # ---- 3. intra-batch: endpoint ranks -> overlap queries -> fixpoint ----
-    # Endpoint ranks come from the big sort: rank = number of distinct
-    # batch-endpoint key groups at-or-before this element, which is
-    # order-isomorphic to the keys over batch endpoints (state elements
-    # interleave but contribute no rank). The default "scan" evaluator
-    # answers each sweep's "does a committed earlier txn's write overlap
-    # this read" with per-level prefix scans over sorted write endpoints
-    # (geometry built once per step, _intra_scan_levels) — O(n log n) per
-    # sweep with no n×n matrix materialized; the "legacy" evaluator is the
-    # pre-overhaul dense (NW, NR) int8 matvec + unbounded while_loop.
-    is_batch = ~is_state
-    newgrp = jnp.concatenate(
-        [jnp.ones(1, bool), ~_key_eq(skeys[:, 1:], skeys[:, :-1])])
-    cum_b_excl = jnp.cumsum(is_batch.astype(jnp.int32)) - is_batch
-    grp_start_b = lax.cummax(jnp.where(newgrp, cum_b_excl, -1))
-    first_b = is_batch & (cum_b_excl == grp_start_b)
-    rank_grp = jnp.cumsum(first_b.astype(jnp.int32)) - 1
-    # carry each group's first-batch rank forward (monotone -> cummax)
-    rank_carried = lax.cummax(jnp.where(first_b, rank_grp, -1))
-    qranks = rank_carried[spos[K:]]          # ranks of [rb | re | wb | we]
-    rbr, rer = qranks[:NR], qranks[NR:2 * NR]
-    wbr, wer = qranks[2 * NR:2 * NR + NW], qranks[2 * NR + NW:]
+    with jax.named_scope("intra"):
+        # ---- 3. intra-batch: endpoint ranks -> overlap queries -> fixpoint ----
+        # Endpoint ranks come from the big sort: rank = number of distinct
+        # batch-endpoint key groups at-or-before this element, which is
+        # order-isomorphic to the keys over batch endpoints (state elements
+        # interleave but contribute no rank). The default "scan" evaluator
+        # answers each sweep's "does a committed earlier txn's write overlap
+        # this read" with per-level prefix scans over sorted write endpoints
+        # (geometry built once per step, _intra_scan_levels) — O(n log n) per
+        # sweep with no n×n matrix materialized; the "legacy" evaluator is the
+        # pre-overhaul dense (NW, NR) int8 matvec + unbounded while_loop.
+        is_batch = ~is_state
+        newgrp = jnp.concatenate(
+            [jnp.ones(1, bool), ~_key_eq(skeys[:, 1:], skeys[:, :-1])])
+        cum_b_excl = jnp.cumsum(is_batch.astype(jnp.int32)) - is_batch
+        grp_start_b = lax.cummax(jnp.where(newgrp, cum_b_excl, -1))
+        first_b = is_batch & (cum_b_excl == grp_start_b)
+        rank_grp = jnp.cumsum(first_b.astype(jnp.int32)) - 1
+        # carry each group's first-batch rank forward (monotone -> cummax)
+        rank_carried = lax.cummax(jnp.where(first_b, rank_grp, -1))
+        qranks = rank_carried[spos[K:]]          # ranks of [rb | re | wb | we]
+        rbr, rer = qranks[:NR], qranks[NR:2 * NR]
+        wbr, wer = qranks[2 * NR:2 * NR + NW], qranks[2 * NR + NW:]
 
-    # empty/inverted ranges (end <= begin) participate in neither side;
-    # strict wtxn < rtxn = "earlier txns win" (checkIntraBatchConflicts
-    # SkipList.cpp:1139-1152 processes in batch order)
-    g = g0
-    wtxn_c = jnp.minimum(wtxn, T - 1)
-    r_ok = rvalid & (rbr < rer)
-    w_ok = wvalid & (wbr < wer)
+        # empty/inverted ranges (end <= begin) participate in neither side;
+        # strict wtxn < rtxn = "earlier txns win" (checkIntraBatchConflicts
+        # SkipList.cpp:1139-1152 processes in batch order)
+        g = g0
+        wtxn_c = jnp.minimum(wtxn, T - 1)
+        r_ok = rvalid & (rbr < rer)
+        w_ok = wvalid & (wbr < wer)
 
-    def fold_reads(blocked_r):
-        if shapes.strided:
-            return blocked_r.reshape(T, NR // T).any(axis=1)
-        return (jnp.zeros(T + 1, bool).at[rtxn].max(blocked_r))[:T]
+        def fold_reads(blocked_r):
+            if shapes.strided:
+                return blocked_r.reshape(T, NR // T).any(axis=1)
+            return (jnp.zeros(T + 1, bool).at[rtxn].max(blocked_r))[:T]
 
-    if intra_mode == "legacy":
-        if shapes.strided:
-            order_ok = (
-                (jnp.arange(NW, dtype=jnp.int32) // (NW // T))[:, None]
-                < (jnp.arange(NR, dtype=jnp.int32) // (NR // T))[None, :])
+        if intra_mode == "legacy":
+            if shapes.strided:
+                order_ok = (
+                    (jnp.arange(NW, dtype=jnp.int32) // (NW // T))[:, None]
+                    < (jnp.arange(NR, dtype=jnp.int32) // (NR // T))[None, :])
+            else:
+                order_ok = wtxn[:, None] < rtxn[None, :]
+            overlap = ((wbr[:, None] < rer[None, :])
+                       & (rbr[None, :] < wer[:, None])
+                       & w_ok[:, None] & r_ok[None, :]
+                       & order_ok)  # (NW, NR)
+            # int8 halves the fixpoint's HBM traffic vs bf16 (the matrix read
+            # dominates each matvec); int8 x int8 -> int32 runs on the MXU
+            ovf = overlap.astype(jnp.int8)
+
+            def _f_commit(c):
+                """f(c)[t] = g[t] and no committed-in-c earlier txn's write
+                overlaps any of t's reads."""
+                cm = jnp.repeat(c, NW // T) if shapes.strided else c[wtxn_c]
+                cw = (cm & wvalid).astype(jnp.int8)
+                blocked_r = lax.dot_general(
+                    cw[None, :], ovf, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.int32)[0] > 0
+                return g & ~fold_reads(blocked_r)
+
+            upper = g
+            lower = _f_commit(upper)
+
+            def cond(lu):
+                lower, upper = lu
+                return jnp.any(lower != upper)
+
+            def body(lu):
+                lower, upper = lu
+                upper2 = _f_commit(lower)
+                lower2 = _f_commit(upper2)
+                return lower2, upper2
+
+            lower, upper = body((lower, upper))
+            lower, upper = lax.while_loop(cond, body, (lower, upper))
+            commit = lower
+            merge_commit = commit
+            converged = jnp.asarray(True)
         else:
-            order_ok = wtxn[:, None] < rtxn[None, :]
-        overlap = ((wbr[:, None] < rer[None, :])
-                   & (rbr[None, :] < wer[:, None])
-                   & w_ok[:, None] & r_ok[None, :]
-                   & order_ok)  # (NW, NR)
-        # int8 halves the fixpoint's HBM traffic vs bf16 (the matrix read
-        # dominates each matvec); int8 x int8 -> int32 runs on the MXU
-        ovf = overlap.astype(jnp.int8)
+            levels = _intra_scan_levels(T, wtxn_c, rtxn, rbr, rer, wbr, wer)
 
-        def _f_commit(c):
-            """f(c)[t] = g[t] and no committed-in-c earlier txn's write
-            overlaps any of t's reads."""
-            cm = jnp.repeat(c, NW // T) if shapes.strided else c[wtxn_c]
-            cw = (cm & wvalid).astype(jnp.int8)
-            blocked_r = lax.dot_general(
-                cw[None, :], ovf, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.int32)[0] > 0
-            return g & ~fold_reads(blocked_r)
+            def _f_commit(c):
+                cw = ((jnp.repeat(c, NW // T) if shapes.strided
+                       else c[wtxn_c]) & w_ok)
+                blocked_r = _intra_scan_blocked(cw, levels, rbr) & r_ok
+                return g & ~fold_reads(blocked_r)
 
-        upper = g
-        lower = _f_commit(upper)
+            rounds = intra_rounds if intra_rounds > 0 else _auto_rounds(T)
+            # statuses come from `lower` (⊆ truth: never a false commit) and the
+            # merge uses `upper` (⊇ truth: never a missing write in history);
+            # both are the truth itself whenever converged — always, for
+            # rounds >= T//2+1
+            commit, merge_commit, converged = _run_sandwich(_f_commit, g, rounds)
 
-        def cond(lu):
-            lower, upper = lu
-            return jnp.any(lower != upper)
-
-        def body(lu):
-            lower, upper = lu
-            upper2 = _f_commit(lower)
-            lower2 = _f_commit(upper2)
-            return lower2, upper2
-
-        lower, upper = body((lower, upper))
-        lower, upper = lax.while_loop(cond, body, (lower, upper))
-        commit = lower
-        merge_commit = commit
-        converged = jnp.asarray(True)
-    else:
-        levels = _intra_scan_levels(T, wtxn_c, rtxn, rbr, rer, wbr, wer)
-
-        def _f_commit(c):
-            cw = ((jnp.repeat(c, NW // T) if shapes.strided
-                   else c[wtxn_c]) & w_ok)
-            blocked_r = _intra_scan_blocked(cw, levels, rbr) & r_ok
-            return g & ~fold_reads(blocked_r)
-
-        rounds = intra_rounds if intra_rounds > 0 else _auto_rounds(T)
-        # statuses come from `lower` (⊆ truth: never a false commit) and the
-        # merge uses `upper` (⊇ truth: never a missing write in history);
-        # both are the truth itself whenever converged — always, for
-        # rounds >= T//2+1
-        commit, merge_commit, converged = _run_sandwich(_f_commit, g, rounds)
-
-    statuses = jnp.where(
-        commit, COMMITTED,
-        jnp.where(too_old, TOO_OLD, CONFLICT)).astype(jnp.int32)
-    statuses = jnp.where(txn_valid, statuses, COMMITTED)
+        statuses = jnp.where(
+            commit, COMMITTED,
+            jnp.where(too_old, TOO_OLD, CONFLICT)).astype(jnp.int32)
+        statuses = jnp.where(txn_valid, statuses, COMMITTED)
     return _merge_phase(state, batch, statuses, commit, shapes,
                         max_write_life, ablate, sort_products=(
                             skeys, scls, sval, sidx, spos, cum_state),
@@ -625,109 +668,112 @@ def _merge_phase(state, batch, statuses, commit, shapes, max_write_life,
                 "converged": converged, "eligible": eligible}
         return new_state, statuses, info
 
-    # ---- 4. merge surviving writes into the step function at vnew ----
-    # The union of state boundaries and committed write endpoints is already
-    # IN the big sorted array (sort_products); dead elements — read
-    # endpoints, uncommitted/empty writes, dead state slots — are simply not
-    # union slots, and the merged state is carved out with prefix scans + one
-    # compaction scatter. This replaces the previous incremental design's
-    # per-batch multi-limb bisection of candidates into the state (the single
-    # most expensive gather loop) with sort products that history and
-    # intra-batch checks already paid for (the device analogue of the
-    # reference's finger-merge, mergeWriteConflictRanges SkipList.cpp:1260).
-    skeys, scls, sval, sidx, spos, cum_state = sort_products
-    N_ALL = K + 2 * NR + 2 * NW
-    if shapes.strided:
-        wvalid = wb[L - 1] != jnp.uint32(0xFFFFFFFF)
-        commit_w = jnp.repeat(merge_commit, NW // T)
-    else:
-        commit_w = merge_commit[wtxn_c]
-    # committed, non-empty writes only: an inverted range would inject a
-    # reversed -1/+1 coverage delta and cancel other writes' coverage
-    cw = wvalid & commit_w & _key_lt(wb, we)
-    # coverage deltas at each write endpoint's sorted position: +1 at
-    # committed begins, -1 at committed ends (positions are unique)
-    delta_w = jnp.concatenate([cw.astype(jnp.int32), -(cw.astype(jnp.int32))])
-    pos_w = spos[K + 2 * NR:]
-    delta_sorted = jnp.zeros(N_ALL, jnp.int32).at[pos_w].set(delta_w)
+    with jax.named_scope("merge"):
+        # ---- 4. merge surviving writes into the step function at vnew ----
+        # The union of state boundaries and committed write endpoints is already
+        # IN the big sorted array (sort_products); dead elements — read
+        # endpoints, uncommitted/empty writes, dead state slots — are simply not
+        # union slots, and the merged state is carved out with prefix scans + one
+        # compaction scatter. This replaces the previous incremental design's
+        # per-batch multi-limb bisection of candidates into the state (the single
+        # most expensive gather loop) with sort products that history and
+        # intra-batch checks already paid for (the device analogue of the
+        # reference's finger-merge, mergeWriteConflictRanges SkipList.cpp:1260).
+        skeys, scls, sval, sidx, spos, cum_state = sort_products
+        N_ALL = K + 2 * NR + 2 * NW
+        if shapes.strided:
+            wvalid = wb[L - 1] != jnp.uint32(0xFFFFFFFF)
+            commit_w = jnp.repeat(merge_commit, NW // T)
+        else:
+            commit_w = merge_commit[wtxn_c]
+        # committed, non-empty writes only: an inverted range would inject a
+        # reversed -1/+1 coverage delta and cancel other writes' coverage
+        cw = wvalid & commit_w & _key_lt(wb, we)
+        # coverage deltas at each write endpoint's sorted position: +1 at
+        # committed begins, -1 at committed ends (positions are unique)
+        delta_w = jnp.concatenate([cw.astype(jnp.int32), -(cw.astype(jnp.int32))])
+        pos_w = spos[K + 2 * NR:]
+        delta_sorted = jnp.zeros(N_ALL, jnp.int32).at[pos_w].set(delta_w)
 
-    # union slot sources: live state boundaries + committed write endpoints
-    is_state = scls == 1
-    live_state = is_state & (sidx < nb)
-    is_src = live_state | (delta_sorted != 0)
-    # one representative (slot) per distinct key among sources; the class
-    # tiebreak sorted state before equal write endpoints, so a duplicate
-    # endpoint joins the state boundary's slot
-    newgrp = jnp.concatenate(
-        [jnp.ones(1, bool), ~_key_eq(skeys[:, 1:], skeys[:, :-1])])
-    cum_src_excl = jnp.cumsum(is_src.astype(jnp.int32)) - is_src
-    grp_start_src = lax.cummax(jnp.where(newgrp, cum_src_excl, -1))
-    rep = is_src & (cum_src_excl == grp_start_src)
+        # union slot sources: live state boundaries + committed write endpoints
+        is_state = scls == 1
+        live_state = is_state & (sidx < nb)
+        is_src = live_state | (delta_sorted != 0)
+        # one representative (slot) per distinct key among sources; the class
+        # tiebreak sorted state before equal write endpoints, so a duplicate
+        # endpoint joins the state boundary's slot
+        newgrp = jnp.concatenate(
+            [jnp.ones(1, bool), ~_key_eq(skeys[:, 1:], skeys[:, :-1])])
+        cum_src_excl = jnp.cumsum(is_src.astype(jnp.int32)) - is_src
+        grp_start_src = lax.cummax(jnp.where(newgrp, cum_src_excl, -1))
+        rep = is_src & (cum_src_excl == grp_start_src)
 
-    # value of each slot under the CURRENT step function: the last live state
-    # boundary's value at-or-before it, carried forward by scan (sorted-order
-    # values rode the sort as a payload operand; an N_ALL-wide scan is
-    # cheaper than the random bval gather it replaces)
-    val_u = _carry_last_flagged(jnp.where(live_state, sval, NEG), live_state)
+        # value of each slot under the CURRENT step function: the last live state
+        # boundary's value at-or-before it, carried forward by scan (sorted-order
+        # values rode the sort as a payload operand; an N_ALL-wide scan is
+        # cheaper than the random bval gather it replaces)
+        val_u = _carry_last_flagged(jnp.where(live_state, sval, NEG), live_state)
 
-    # coverage at a slot = total delta through the END of its key group
-    # (within a group the +1/-1 order is arbitrary; at the group end it has
-    # settled). Backward-carry the group-end prefix sum to every member.
-    csum_delta = jnp.cumsum(delta_sorted)
-    grp_last = jnp.concatenate([newgrp[1:], jnp.ones(1, bool)])
-    cover_cnt = jnp.flip(_carry_last_flagged(
-        jnp.flip(jnp.where(grp_last, csum_delta, 0)), jnp.flip(grp_last)))
-    cover = cover_cnt > 0
-    newval = jnp.where(cover, jnp.maximum(val_u, vnew), val_u)
+        # coverage at a slot = total delta through the END of its key group
+        # (within a group the +1/-1 order is arbitrary; at the group end it has
+        # settled). Backward-carry the group-end prefix sum to every member.
+        csum_delta = jnp.cumsum(delta_sorted)
+        grp_last = jnp.concatenate([newgrp[1:], jnp.ones(1, bool)])
+        cover_cnt = jnp.flip(_carry_last_flagged(
+            jnp.flip(jnp.where(grp_last, csum_delta, 0)), jnp.flip(grp_last)))
+        cover = cover_cnt > 0
+        newval = jnp.where(cover, jnp.maximum(val_u, vnew), val_u)
 
-    # ---- 5. window GC: clamp to new floor + coalesce equal neighbors ----
-    # advance_floor is False for all but the last chunk of a logical batch:
-    # the too-old check and history clamping must use the PRE-batch floor for
-    # every transaction of the batch (the reference advances oldestVersion
-    # once per detectConflicts call, SkipList.cpp:1199-1206).
-    floor = jnp.where(batch["advance_floor"],
-                      vnew - jnp.int32(max_write_life), oldest)
-    new_oldest = jnp.maximum(oldest, floor)
-    newval = jnp.maximum(newval, new_oldest)
+    with jax.named_scope("gc"):
+        # ---- 5. window GC: clamp to new floor + coalesce equal neighbors ----
+        # advance_floor is False for all but the last chunk of a logical batch:
+        # the too-old check and history clamping must use the PRE-batch floor for
+        # every transaction of the batch (the reference advances oldestVersion
+        # once per detectConflicts call, SkipList.cpp:1199-1206).
+        floor = jnp.where(batch["advance_floor"],
+                          vnew - jnp.int32(max_write_life), oldest)
+        new_oldest = jnp.maximum(oldest, floor)
+        newval = jnp.maximum(newval, new_oldest)
 
-    # coalesce (removeBefore's segment-merge analogue): a slot is redundant
-    # if its value equals its predecessor slot's post-clamp value
-    cum_rep = jnp.cumsum(rep.astype(jnp.int32))
-    rep_val_carried = _carry_last_flagged(jnp.where(rep, newval, NEG), rep)
-    prev_rep_val = jnp.concatenate(
-        [jnp.full(1, NEG, jnp.int32), rep_val_carried[:-1]])
-    keep2 = rep & ((cum_rep == 1) | (newval != prev_rep_val))
-    n2 = jnp.sum(keep2.astype(jnp.int32))
-    # compact kept slots to the front: one int32 source scatter, then gather
-    # keys/values from the sorted arrays (indices are monotone)
-    cpos = jnp.cumsum(keep2.astype(jnp.int32)) - 1
-    cpos = jnp.where(keep2, jnp.minimum(cpos, K - 1), K)
-    csrc = jnp.full(K + 1, -1, jnp.int32).at[cpos].set(
-        jnp.arange(N_ALL, dtype=jnp.int32))[:K]
-    kept = csrc >= 0
-    csrc_c = jnp.clip(csrc, 0, N_ALL - 1)
-    out_keys = jnp.where(kept[None, :], skeys[:, csrc_c],
-                         jnp.uint32(0xFFFFFFFF))
-    out_vals = jnp.where(kept, newval[csrc_c], NEG)
+        # coalesce (removeBefore's segment-merge analogue): a slot is redundant
+        # if its value equals its predecessor slot's post-clamp value
+        cum_rep = jnp.cumsum(rep.astype(jnp.int32))
+        rep_val_carried = _carry_last_flagged(jnp.where(rep, newval, NEG), rep)
+        prev_rep_val = jnp.concatenate(
+            [jnp.full(1, NEG, jnp.int32), rep_val_carried[:-1]])
+        keep2 = rep & ((cum_rep == 1) | (newval != prev_rep_val))
+        n2 = jnp.sum(keep2.astype(jnp.int32))
+        # compact kept slots to the front: one int32 source scatter, then gather
+        # keys/values from the sorted arrays (indices are monotone)
+        cpos = jnp.cumsum(keep2.astype(jnp.int32)) - 1
+        cpos = jnp.where(keep2, jnp.minimum(cpos, K - 1), K)
+        csrc = jnp.full(K + 1, -1, jnp.int32).at[cpos].set(
+            jnp.arange(N_ALL, dtype=jnp.int32))[:K]
+        kept = csrc >= 0
+        csrc_c = jnp.clip(csrc, 0, N_ALL - 1)
+        out_keys = jnp.where(kept[None, :], skeys[:, csrc_c],
+                             jnp.uint32(0xFFFFFFFF))
+        out_vals = jnp.where(kept, newval[csrc_c], NEG)
 
-    overflow = n2 > K
+        overflow = n2 > K
 
-    # Overflow poisons the state (sticky): truncation would drop the
-    # highest-key history segments and cause FALSE COMMITS for batches
-    # already enqueued behind this one (detect_async pipelines without a
-    # host sync). Instead the whole keyspace collapses to one segment at
-    # vnew, so every later stale read conflicts — conservative-only — until
-    # the owner sees info["overflow"] and reconstructs (clearConflictSet
-    # semantics, SkipList.cpp:957). This batch's own statuses are computed
-    # pre-merge and remain exact.
-    poisoned = state["poisoned"] | overflow
-    pois_keys = jnp.full((L, K), jnp.uint32(0xFFFFFFFF)).at[:, 0].set(
-        jnp.zeros(L, dtype=jnp.uint32))  # encode(b"") == all-zero limbs
-    pois_vals = jnp.full(K, NEG, jnp.int32).at[0].set(vnew)
-    out_keys = jnp.where(poisoned, pois_keys, out_keys)
-    out_vals = jnp.where(poisoned, pois_vals, out_vals)
-    n2 = jnp.where(poisoned, 1, n2)
-    new_table = state["table"] if ablate == "no_table" else _build_table(out_vals)
+        # Overflow poisons the state (sticky): truncation would drop the
+        # highest-key history segments and cause FALSE COMMITS for batches
+        # already enqueued behind this one (detect_async pipelines without a
+        # host sync). Instead the whole keyspace collapses to one segment at
+        # vnew, so every later stale read conflicts — conservative-only — until
+        # the owner sees info["overflow"] and reconstructs (clearConflictSet
+        # semantics, SkipList.cpp:957). This batch's own statuses are computed
+        # pre-merge and remain exact.
+        poisoned = state["poisoned"] | overflow
+        pois_keys = jnp.full((L, K), jnp.uint32(0xFFFFFFFF)).at[:, 0].set(
+            jnp.zeros(L, dtype=jnp.uint32))  # encode(b"") == all-zero limbs
+        pois_vals = jnp.full(K, NEG, jnp.int32).at[0].set(vnew)
+        out_keys = jnp.where(poisoned, pois_keys, out_keys)
+        out_vals = jnp.where(poisoned, pois_vals, out_vals)
+        n2 = jnp.where(poisoned, 1, n2)
+    with jax.named_scope("table"):
+        new_table = state["table"] if ablate == "no_table" else _build_table(out_vals)
 
     new_state = {
         "bkeys": out_keys,
@@ -777,6 +823,16 @@ def init_state(shapes: ConflictShapes, oldest: int = 0):
 # host wrapper: the ConflictSet a Resolver instantiates
 # ---------------------------------------------------------------------------
 
+def _named(fn, name: str):
+    """`fn` under a name of its own: jax.jit calls the program
+    `jit_<__name__>`, and a functools.partial has none (`jit__unknown` in a
+    profile)."""
+    def named(*args):
+        return fn(*args)
+    named.__name__ = named.__qualname__ = name
+    return named
+
+
 def _donate_state_argnums() -> tuple:
     """Donate the state operand (bkeys + table dominate HBM) on accelerator
     backends: the update is written in place of the old state instead of
@@ -791,9 +847,9 @@ def _compiled_step(shapes: ConflictShapes, max_write_life: int,
                    intra_mode: str = "scan", intra_rounds: int = 0):
     """One compiled program per (shapes, window, intra config) — shared
     across instances."""
-    return jax.jit(functools.partial(
+    return jax.jit(_named(functools.partial(
         conflict_step, shapes=shapes, max_write_life=max_write_life,
-        intra_mode=intra_mode, intra_rounds=intra_rounds),
+        intra_mode=intra_mode, intra_rounds=intra_rounds), "conflict_step"),
         donate_argnums=_donate_state_argnums())
 
 
@@ -832,9 +888,9 @@ def conflict_scan(state: dict, stacked: dict, *, shapes: ConflictShapes,
 @functools.lru_cache(maxsize=32)
 def _compiled_scan(shapes: ConflictShapes, max_write_life: int,
                    intra_mode: str = "scan", intra_rounds: int = 0):
-    return jax.jit(functools.partial(
+    return jax.jit(_named(functools.partial(
         conflict_scan, shapes=shapes, max_write_life=max_write_life,
-        intra_mode=intra_mode, intra_rounds=intra_rounds),
+        intra_mode=intra_mode, intra_rounds=intra_rounds), "conflict_scan"),
         donate_argnums=_donate_state_argnums())
 
 
@@ -1096,6 +1152,10 @@ def detect_async_impl(engine, txns: list[TxnConflictInfo],
     pre_batch_oldest = engine.oldest_version
     base = enc.base_version
     chunks = []
+    # the resolver's ident for this batch, and its loop's clock (virtual
+    # under the simulator); an engine driven directly stamps time.monotonic
+    vid = f"v{commit_version}"
+    clock = getattr(engine, "trace_clock", None) or time.monotonic
     for i, sub in enumerate(subs):
         # TOO_OLD when below the MVCC floor, AND when the snapshot's device
         # offset would saturate at the NEG sentinel (a >2^30-stale snapshot
@@ -1112,36 +1172,43 @@ def detect_async_impl(engine, txns: list[TxnConflictInfo],
         nw = sum(len(t.write_ranges) for t, old in zip(sub, host_too_old)
                  if not old)
         shapes, step = engine.plan_chunk(nr, nw)
-        batch = enc.encode_batch(sub, commit_version, skip=host_too_old,
-                                 shapes=shapes)
-        # the MVCC floor advances once per logical batch (last chunk), so
-        # every chunk's too-old check uses the pre-batch floor
-        batch["advance_floor"] = np.bool_(i == len(subs) - 1)
-        _kernel_dispatches.increment()
-        new_state, statuses, info = step(engine._state, batch)
-        engine._state = new_state
-        # statuses + intra-eligibility + overflow + convergence fused into
-        # ONE fixed-shape device array (enqueue-only): every chunk is read
-        # back as a single transfer
-        combined = _combine_status(statuses, info["eligible"],
-                                   info["overflow"], info["converged"])
-        enc.mark_in_flight(combined)
-        # double-buffering: the D2H copy starts NOW, overlapped with the
-        # NEXT chunk's/batch's encode + dispatch, so a later drain (or
-        # result()) finds the bytes already on the host instead of starting
-        # the transfer under a sync. CONFLICT_READBACK_OVERLAP=False keeps
-        # the fully synchronous pre-overlap shape as a measurable ablation
-        # (decisions are identical either way — only timing shifts).
-        if (KNOBS.CONFLICT_READBACK_OVERLAP
-                and hasattr(combined, "copy_to_host_async")):
-            combined.copy_to_host_async()
+        with g_trace_batch.section("CommitSpan", vid, "Resolver.Encode",
+                                   now=clock):
+            batch = enc.encode_batch(sub, commit_version, skip=host_too_old,
+                                     shapes=shapes)
+            # the MVCC floor advances once per logical batch (last chunk),
+            # so every chunk's too-old check uses the pre-batch floor
+            batch["advance_floor"] = np.bool_(i == len(subs) - 1)
+        with g_trace_batch.section("CommitSpan", vid, "Resolver.Enqueue",
+                                   now=clock):
+            _kernel_dispatches.increment()
+            # the encoded batch crosses to the device inside the jit call
+            jaxenv.count_device_put(batch)
+            new_state, statuses, info = step(engine._state, batch)
+            engine._state = new_state
+            # statuses + intra-eligibility + overflow + convergence fused
+            # into ONE fixed-shape device array (enqueue-only): every chunk
+            # is read back as a single transfer
+            combined = _combine_status(statuses, info["eligible"],
+                                       info["overflow"], info["converged"])
+            enc.mark_in_flight(combined)
+            # double-buffering: the D2H copy starts NOW, overlapped with the
+            # NEXT chunk's/batch's encode + dispatch, so a later drain (or
+            # result()) finds the bytes already on the host instead of
+            # starting the transfer under a sync.
+            # CONFLICT_READBACK_OVERLAP=False keeps the fully synchronous
+            # pre-overlap shape as a measurable ablation (decisions are
+            # identical either way — only timing shifts).
+            if (KNOBS.CONFLICT_READBACK_OVERLAP
+                    and hasattr(combined, "copy_to_host_async")):
+                combined.copy_to_host_async()
         chunks.append((sub, host_too_old, combined))
     # the kernel's floor advance is replicated host-side exactly
     # (floor = commit_version - window on the last chunk, monotonic max)
     engine.oldest_version = max(
         engine.oldest_version,
         commit_version - KNOBS.MAX_WRITE_TRANSACTION_LIFE_VERSIONS)
-    return DetectHandle(chunks)
+    return DetectHandle(chunks, vid, clock)
 
 
 class DeviceConflictSet:
@@ -1158,6 +1225,7 @@ class DeviceConflictSet:
                  reads_per_txn: int | None = None, writes_per_txn: int | None = None,
                  oldest_version: int = 0, key_bytes: int | None = None,
                  strided: bool = False):
+        install_profiler_annotator()
         self.shapes = _resolve_shapes(capacity, txns, reads_per_txn,
                                       writes_per_txn, key_bytes, strided)
         self.encoder = BatchEncoder(self.shapes, base_version=oldest_version)
@@ -1199,22 +1267,45 @@ class DeviceConflictSet:
         return shapes, _compiled_step(
             shapes, KNOBS.MAX_WRITE_TRANSACTION_LIFE_VERSIONS, *self._intra)
 
-    def warmup(self):
-        """Compile every serving bucket now (boot-time cost, served-path
-        savings; the persistent compile cache makes it once per machine)."""
+    def _bucket_programs(self):
+        """(shapes, compiled step, an empty batch) of every serving bucket."""
         sh = self.shapes
-        if sh.strided:
-            self.detect([], self.encoder.base_version + 1)
-            return
         combos = {(r, w)
                   for r in (0, sh.reads) for w in (0, sh.writes)}
-        for nr, nw in combos:
+        for nr, nw in sorted(combos):
             shapes, step = self.plan_chunk(nr, nw)
             batch = self.encoder.encode_batch(
                 [], self.encoder.base_version + 1, shapes=shapes)
+            yield shapes, step, batch
+
+    def warmup(self):
+        """Compile every serving bucket now (boot-time cost, served-path
+        savings; the persistent compile cache makes it once per machine)."""
+        if self.shapes.strided:
+            self.detect([], self.encoder.base_version + 1)
+            return
+        for _shapes, step, batch in self._bucket_programs():
             new_state, statuses, _info = step(self._state, batch)
             self._state = new_state
             statuses.block_until_ready()
+
+    def write_scope_maps(self, directory: str) -> None:
+        """One `scopes.conflict_step.<reads>x<writes>.json` per bucket
+        program: which scope each instruction of the compiled module runs
+        under. The profiler's trace of this chip names an operation by its
+        instruction and carries no op_name, so a reader of a profile needs
+        this beside it. Compiles each program once more (from the persistent
+        cache where there is one): for a traced run's warm-up only."""
+        if self.shapes.strided:
+            return
+        os.makedirs(directory, exist_ok=True)
+        for shapes, step, batch in self._bucket_programs():
+            text = step.lower(self._state, batch).compile().as_text()
+            path = os.path.join(
+                directory,
+                f"scopes.conflict_step.{shapes.reads}x{shapes.writes}.json")
+            with open(path, "w") as f:
+                json.dump({"scopes": scope_map(text)}, f)
 
     def clear(self, oldest_version: int = 0):
         """clearConflictSet (SkipList.cpp:957): state is soft/reconstructable."""
@@ -1229,13 +1320,24 @@ def _combine_fn():
     # overflow/converged scalars — the fixed output layout
     # [statuses | eligible | overflow | converged] makes every chunk
     # readback a single transfer
-    return jax.jit(lambda s, g, o, c: jnp.concatenate(
-        [s.astype(jnp.int32), g.astype(jnp.int32),
-         jnp.asarray(o, jnp.int32)[None], jnp.asarray(c, jnp.int32)[None]]))
+    def combine_status(s, g, o, c):
+        return jnp.concatenate(
+            [s.astype(jnp.int32), g.astype(jnp.int32),
+             jnp.asarray(o, jnp.int32)[None], jnp.asarray(c, jnp.int32)[None]])
+    return jax.jit(combine_status)
 
 
 def _combine_status(statuses, eligible, overflow, converged):
     return _combine_fn()(statuses, eligible, overflow, converged)
+
+
+def _status_to_host(combined) -> np.ndarray:
+    """Materialise a chunk's combined status array, counted once: this is
+    where the served path's bytes come back from the device."""
+    if isinstance(combined, np.ndarray):
+        return combined  # a drain already brought it over
+    jaxenv.count_device_get(combined)
+    return np.asarray(combined)
 
 
 def drain_handles(handles: list["DetectHandle"]) -> None:
@@ -1257,7 +1359,7 @@ def drain_handles(handles: list["DetectHandle"]) -> None:
             if hasattr(a, "copy_to_host_async"):
                 a.copy_to_host_async()
     for h in pend:
-        h._chunks = [(sub, too_old, np.asarray(a))
+        h._chunks = [(sub, too_old, _status_to_host(a))
                      for sub, too_old, a in h._chunks]
 
 
@@ -1278,16 +1380,22 @@ def drain_and_collect(
     materialization ("collect_seconds") halves are recorded separately so
     the caller can attribute them to distinct spans (the sharded path bills
     the verdict unpack as Resolver.ShardCombine)."""
-    import time
+    # one device sync serves the whole group: its section carries the first
+    # batch's ident, and each batch's unpack its own
+    first = handles[0] if handles else DetectHandle([])
     t0 = time.perf_counter()
-    drain_handles(handles)
+    with g_trace_batch.section("CommitSpan", first.ident,
+                               "Resolver.Readback", now=first.clock):
+        drain_handles(handles)
     t1 = time.perf_counter()
     out: list[tuple[list[int] | None, FDBError | None]] = []
     for h in handles:
-        try:
-            out.append((h.result(), None))
-        except FDBError as e:
-            out.append((None, e))
+        with g_trace_batch.section("CommitSpan", h.ident,
+                                   "Resolver.Collect", now=h.clock):
+            try:
+                out.append((h.result(), None))
+            except FDBError as e:
+                out.append((None, e))
     t2 = time.perf_counter()
     if timing is not None:
         timing["drain_seconds"] = t1 - t0
@@ -1333,15 +1441,16 @@ class DetectHandle:
     Each chunk is (sub_txns, host_too_old, combined) where combined is the
     device readback [statuses(T) | eligible(T) | overflow | converged]."""
 
-    def __init__(self, chunks):
+    def __init__(self, chunks, ident: str = "", clock=time.monotonic):
         self._chunks = chunks
         self._result: list[int] | None = None
+        self.ident, self.clock = ident, clock  # for drain_and_collect's sections
 
     def result(self) -> list[int]:
         if self._result is None:
             out: list[int] = []
             for sub, host_too_old, combined in self._chunks:
-                arr = np.asarray(combined)
+                arr = _status_to_host(combined)
                 n = len(sub)
                 tc = (len(arr) - 2) // 2
                 if arr[2 * tc]:

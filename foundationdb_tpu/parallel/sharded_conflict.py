@@ -269,7 +269,9 @@ class ShardedDeviceConflictSet:
                  txns: int | None = None, reads_per_txn: int | None = None,
                  writes_per_txn: int | None = None, oldest_version: int = 0,
                  cut_bytes: list[bytes] | None = None):
-        from foundationdb_tpu.ops.conflict import BatchEncoder, _resolve_shapes
+        from foundationdb_tpu.ops.conflict import (
+            BatchEncoder, _resolve_shapes, install_profiler_annotator)
+        install_profiler_annotator()
         self.mesh = mesh or make_resolver_mesh()
         self.n_shards = self.mesh.devices.size
         self.shapes = _resolve_shapes(capacity, txns, reads_per_txn, writes_per_txn)

@@ -13,6 +13,8 @@ reference (ops/conflict_oracle.py). Both make identical decisions (tested).
 
 from __future__ import annotations
 
+import os
+
 from foundationdb_tpu.core.future import settle_failed
 from foundationdb_tpu.core.notified import AsyncTrigger, NotifiedVersion
 from foundationdb_tpu.core.sim import SimProcess
@@ -116,6 +118,15 @@ class Resolver:
             t0 = time.perf_counter()
             self.conflict_set.warmup()
             self._warmup_seconds = round(time.perf_counter() - t0, 3)
+            # the engine's own sections (Resolver.Encode/Enqueue/Readback/
+            # Collect) stamp this loop's time: virtual under the simulator
+            self.conflict_set.trace_clock = process.net.loop.now
+            # a server that writes span files also says which scope each
+            # instruction of its step programs runs under (a profile of the
+            # chip names operations by instruction alone)
+            trace_dir = os.environ.get("FDBTPU_TRACE_DIR")
+            if trace_dir and hasattr(self.conflict_set, "write_scope_maps"):
+                self.conflict_set.write_scope_maps(trace_dir)
         self._recent_replies: dict[int, ResolveTransactionBatchReply] = {}
         # retained state (metadata) transactions for other proxies' catch-up
         # (Resolver.actor.cpp:59-62,170-224): version -> [(locally_committed,
@@ -234,20 +245,16 @@ class Resolver:
             # dispatch in version order, so the NEXT batch may dispatch as
             # soon as version advances; the verdict readback happens in the
             # drain loop without ever blocking dispatch.
-            g_trace_batch.span_begin("CommitSpan", vid, "Resolver.Dispatch",
-                                     at=loop.now())
-            handle = cs.detect_async(req.transactions, req.version)
-            g_trace_batch.span_end("CommitSpan", vid, "Resolver.Dispatch",
-                                   at=loop.now())
+            with g_trace_batch.section("CommitSpan", vid, "Resolver.Dispatch",
+                                       now=loop.now):
+                handle = cs.detect_async(req.transactions, req.version)
             self.version.set(req.version)
             self._drain_pending.append((req, reply, handle))
             self._drain_wake.trigger()
             return
-        g_trace_batch.span_begin("CommitSpan", vid, "Resolver.Dispatch",
-                                 at=loop.now())
-        statuses = cs.detect(req.transactions, req.version)
-        g_trace_batch.span_end("CommitSpan", vid, "Resolver.Dispatch",
-                               at=loop.now())
+        with g_trace_batch.section("CommitSpan", vid, "Resolver.Dispatch",
+                                   now=loop.now):
+            statuses = cs.detect(req.transactions, req.version)
         self.version.set(req.version)
         self._finish_batch(req, reply, statuses)
 

@@ -76,9 +76,10 @@ def enable_compile_cache() -> str:
 from foundationdb_tpu.utils.stats import CounterCollection
 
 # Process-wide transfer gauges, fed by the choke points below and merged
-# into the resolver's RESOLVER_METRICS snapshot. Counting here (rather
-# than at call sites) means no transfer can escape accounting without
-# also escaping the DEV007 discipline.
+# into the resolver's RESOLVER_METRICS snapshot. Explicit transfers count
+# themselves (device_put/device_get); the served path's two implicit ones
+# (the batch into the jit call, the status array out through np.asarray)
+# are counted where they happen, through count_device_put/count_device_get.
 transfer_metrics = CounterCollection("JaxTransfers")
 _put_count = transfer_metrics.counter("DevicePuts")
 _put_bytes = transfer_metrics.counter("DevicePutBytes")
@@ -95,11 +96,25 @@ def _nbytes(x) -> int:
         return 0
 
 
+def count_device_put(x) -> None:
+    """Count host arrays that cross to the device inside a jit call (the
+    served path hands the encoded batch to the step program: the transfer is
+    implicit and never passes device_put)."""
+    _put_count.increment()
+    _put_bytes.increment(_nbytes(x))
+
+
+def count_device_get(x) -> None:
+    """Count device arrays materialised on the host by np.asarray (the
+    served path's status readback never passes device_get)."""
+    _get_count.increment()
+    _get_bytes.increment(_nbytes(x))
+
+
 def device_put(x, sharding=None):
     """jax.device_put through the counting choke point."""
     import jax
-    _put_count.increment()
-    _put_bytes.increment(_nbytes(x))
+    count_device_put(x)
     return jax.device_put(x, sharding) if sharding is not None \
         else jax.device_put(x)
 
@@ -107,6 +122,5 @@ def device_put(x, sharding=None):
 def device_get(x):
     """jax.device_get through the counting choke point."""
     import jax
-    _get_count.increment()
-    _get_bytes.increment(_nbytes(x))
+    count_device_get(x)
     return jax.device_get(x)

@@ -185,6 +185,9 @@ KNOBS.init("TRANSACTION_SIZE_LIMIT", 10_000_000)
 
 # --- Transport / simulation (flow/Knobs.cpp:51-52, fdbrpc/sim2.actor.cpp) ---
 KNOBS.init("CONNECTION_MONITOR_TIMEOUT", 2.0, (0.1,))
+# a real event loop whose heartbeat is this late is being held: its thread's
+# stack is sampled and a SlowTask event logged (Net2's SLOWTASK_PROFILING)
+KNOBS.init("SLOW_TASK_THRESHOLD", 0.25)
 KNOBS.init("SIM_RPC_TIMEOUT_SECONDS", 5.0)  # dropped-packet visibility bound
 KNOBS.init("SIM_MIN_LATENCY", 0.0001)
 KNOBS.init("SIM_MAX_LATENCY", 0.002, (0.05,))

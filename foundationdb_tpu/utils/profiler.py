@@ -8,14 +8,17 @@ analogue samples the TARGET THREAD's frame stack from a background thread
 (function, file, line) counts and flame-style stacks, and dumps the top
 entries through a TraceEvent on stop.
 
-Enable in a server with FDBTPU_SAMPLING_PROFILE=1 (server_main) or
-programmatically:
+Use it programmatically:
 
     p = SamplingProfiler(interval=0.005)
     p.start()
     ...
     report = p.stop()       # [(frames_tuple, count)] hottest first
     p.trace_report()        # emits ProfilerReport trace events
+
+SlowTaskWatch is the always-on use of the same stack walk: Net2's SlowTask
+(flow/Net2.actor.cpp), a sample of the loop thread's stack taken only while
+the loop is being held.
 """
 
 from __future__ import annotations
@@ -23,6 +26,21 @@ from __future__ import annotations
 import sys
 import threading
 import time
+import weakref
+
+
+def thread_stack(thread_id: int, max_depth: int = 40) -> tuple | None:
+    """The thread's running stack, outermost frame first, as
+    (function, file, line) triples; None when the thread is gone."""
+    f = sys._current_frames().get(thread_id)
+    if f is None:
+        return None
+    stack = []
+    while f is not None and len(stack) < max_depth:
+        code = f.f_code
+        stack.append((code.co_name, code.co_filename, f.f_lineno))
+        f = f.f_back
+    return tuple(reversed(stack))
 
 
 class SamplingProfiler:
@@ -46,16 +64,8 @@ class SamplingProfiler:
 
     def _sample_loop(self):
         while self._running:
-            frames = sys._current_frames()
-            frame = frames.get(self.target_thread)
-            if frame is not None:
-                stack = []
-                f = frame
-                while f is not None and len(stack) < self.max_depth:
-                    code = f.f_code
-                    stack.append((code.co_name, code.co_filename, f.f_lineno))
-                    f = f.f_back
-                key = tuple(reversed(stack))
+            key = thread_stack(self.target_thread, self.max_depth)
+            if key is not None:
                 self.samples[key] = self.samples.get(key, 0) + 1
                 self.total_samples += 1
             time.sleep(self.interval)
@@ -86,3 +96,43 @@ class SamplingProfiler:
                 .detail("Samples", n) \
                 .detail("Fraction", round(n / max(1, self.total_samples), 4)) \
                 .log()
+
+
+class SlowTaskWatch(threading.Thread):
+    """Samples a RealEventLoop's thread while the loop is being held.
+
+    The loop stamps `beat` from a timer that re-arms itself; this thread
+    wakes every `interval` and, when the stamp is older than the
+    SLOW_TASK_THRESHOLD knob although the loop is running, leaves the loop
+    thread's stack in `loop.held`. The loop itself reports the stretch when
+    its heartbeat fires again (net/transport.RealEventLoop._heartbeat), so
+    every trace record and counter is still written on the loop thread. A
+    loop that is not running (between two run_future calls) is idle, not
+    held. The thread ends with its loop: it keeps a weak reference only."""
+
+    def __init__(self, loop, interval: float):
+        super().__init__(name="fdbtpu-slowtask", daemon=True)
+        self._loop = weakref.ref(loop)
+        self._thread_id = threading.get_ident()  # built on the loop thread
+        self.interval = interval
+
+    def run(self):
+        from foundationdb_tpu.utils.knobs import KNOBS
+        idle_at = 0.0  # when the loop was last seen not running
+        while True:
+            time.sleep(self.interval)
+            loop = self._loop()
+            if loop is None or loop.aio.is_closed():
+                return
+            now = time.monotonic()
+            if not loop.aio.is_running():
+                idle_at = now
+            else:
+                beat = loop.beat
+                since = max(beat, idle_at)
+                if (loop.held is None
+                        and now - since > KNOBS.SLOW_TASK_THRESHOLD):
+                    stack = thread_stack(self._thread_id)
+                    if stack is not None:
+                        loop.held = (beat, since, stack)
+            del loop

@@ -7,6 +7,8 @@ TraceEvent with every counter's value and rate since the last dump).
 
 from __future__ import annotations
 
+import os
+
 from foundationdb_tpu.utils.trace import TraceEvent
 
 
@@ -66,16 +68,34 @@ class CounterCollection:
         ev.log()
 
 
+# Process-wide: stretches in which the real event loop did not tick (fed by
+# net/transport.RealEventLoop's heartbeat, the reference's Net2 SlowTask).
+process_metrics = CounterCollection("Process")
+loop_stalls = process_metrics.counter("LoopStalls")
+loop_stall_seconds = process_metrics.counter("LoopStallSeconds")
+loop_stall_max_seconds = process_metrics.counter("LoopStallMaxSeconds")
+
+
+def process_counters() -> dict:
+    """The stall counters and the CPU seconds (user + system) this process
+    has used so far, read now."""
+    t = os.times()
+    return dict(process_metrics.as_dict(),
+                ProcessCpuSeconds=round(t.user + t.system, 6))
+
+
 def fold_transport_counters(process, snap: dict) -> dict:
-    """Merge the process transport's counters (FramesIn/Out, BytesIn/Out,
-    ChecksumRejects, NativeFastPathHits, PySlowPathFalls, ...) into a role's
-    metrics snapshot. The transport is process-wide, so co-hosted roles
-    report the same tallies — the rollup dedupes by process address. A sim
-    network has no transport counters; the snapshot passes through."""
+    """Merge what belongs to the whole process into a role's metrics
+    snapshot: the transport's counters (FramesIn/Out, BytesIn/Out,
+    ChecksumRejects, NativeFastPathHits, PySlowPathFalls, ...) and
+    process_counters(). Co-hosted roles report the same tallies — the
+    rollup dedupes by process address. A sim network has no transport and
+    its processes share one interpreter; the snapshot passes through."""
     tc = getattr(getattr(process, "net", None), "transport_counters", None)
     if tc is not None:
         for k, v in tc().items():
             snap["Transport" + k] = v
+        snap.update(process_counters())
     return snap
 
 

@@ -5,13 +5,17 @@ logging with severities and rolling files) and flow/Stats.h (Counter /
 CounterCollection periodically dumped into the trace log).
 
 We log JSON lines. The global sink is swappable so the simulator can timestamp
-events with virtual time and tests can capture them.
+events with virtual time and tests can capture them. Span, attach and probe
+records (TraceBatch) exist only while a sink is installed; TraceEvents and
+counter dumps go to stderr without one.
 """
 
 from __future__ import annotations
 
+import contextlib
 import json
 import sys
+import threading
 import time
 from typing import Callable
 
@@ -20,6 +24,7 @@ SevDebug, SevInfo, SevWarn, SevWarnAlways, SevError = 5, 10, 20, 30, 40
 _now: Callable[[], float] = time.time
 _sink: Callable[[dict], None] | None = None
 _min_severity = SevInfo
+_annotator = None
 
 
 def set_clock(fn: Callable[[], float]):
@@ -28,8 +33,21 @@ def set_clock(fn: Callable[[], float]):
 
 
 def set_sink(fn: Callable[[dict], None] | None):
+    """Install the record sink. It is also the switch of the process-wide
+    span buffer: with no sink nobody listens, and g_trace_batch builds no
+    record at all."""
     global _sink
     _sink = fn
+    g_trace_batch.enabled = fn is not None
+
+
+def set_annotator(fn):
+    """Hook for sections: `fn(span, ident, mono_us)` returns a context
+    manager the section's body runs inside. ops/conflict.py installs one
+    that opens a jax.profiler.TraceAnnotation, which puts the section on the
+    profiler's clock; this module never imports JAX."""
+    global _annotator
+    _annotator = fn
 
 
 def set_min_severity(sev: int):
@@ -80,7 +98,12 @@ def __getattr__(name):
 class RollingTraceFile:
     """Rolling trace sink (flow/Trace.h:260 openTraceFile): JSON lines into
     `path`, rolled to `path.<n>` when `roll_bytes` is exceeded, keeping the
-    newest `keep` rolls. Install with set_sink(rt.write)."""
+    newest `keep` rolls. Install with set_sink(rt.write).
+
+    An event or a counter dump reaches the file at once. Span, attach and
+    probe records ride the file's buffer: they arrive thousands at a time
+    (TraceBatch.dump, on the loop thread), and a system call a record held
+    the core's loop for 120–170 ms a flush (chip run, PR 26)."""
 
     def __init__(self, path: str, roll_bytes: int = 10_000_000, keep: int = 10):
         import os
@@ -88,12 +111,24 @@ class RollingTraceFile:
         self.roll_bytes = roll_bytes
         self.keep = keep
         os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
-        self._f = open(path, "a", buffering=1)
+        self._f = self._open()
+        # sections on a blocking-pool thread may flush the span buffer while
+        # the loop thread logs an event
+        self._lock = threading.Lock()
+
+    def _open(self):
+        # binary, so that tell() counts the buffered bytes without a flush
+        return open(self.path, "ab", buffering=1 << 16)
 
     def write(self, fields: dict):
-        self._f.write(json.dumps(fields, default=str) + "\n")
-        if self._f.tell() >= self.roll_bytes:
-            self.roll()
+        line = (json.dumps(fields, default=str) + "\n").encode()
+        with self._lock:
+            self._f.write(line)
+            if not ("Span" in fields or "To" in fields
+                    or "Location" in fields):
+                self._f.flush()
+            if self._f.tell() >= self.roll_bytes:
+                self.roll()
 
     def roll(self):
         import os
@@ -103,7 +138,7 @@ class RollingTraceFile:
             if os.path.exists(src):
                 os.replace(src, f"{self.path}.{i + 1}")
         os.replace(self.path, f"{self.path}.1")
-        self._f = open(self.path, "a", buffering=1)
+        self._f = self._open()
 
     def close(self):
         self._f.close()
@@ -169,18 +204,54 @@ def disable_suppression():
     _suppression = None
 
 
+# what a section costs when nobody listens (reusable, holds nothing)
+_NULL_SECTION = contextlib.nullcontext()
+
+
+class _Section:
+    """One synchronous section on one thread: Begin/End records, and the
+    annotator's context round the body."""
+
+    __slots__ = ("_batch", "_kind", "_ident", "_span", "_now", "_annotation")
+
+    def __init__(self, batch, kind, ident, span, now):
+        self._batch, self._kind, self._ident = batch, kind, ident
+        self._span, self._now = span, now
+        self._annotation = None
+
+    def __enter__(self):
+        self._batch._span(self._kind, self._ident, self._span, "Begin",
+                          self._now())
+        self._annotation = self._batch.annotate(self._span, self._ident)
+        self._annotation.__enter__()
+
+    def __exit__(self, *exc):
+        self._annotation.__exit__(*exc)
+        # written whatever the body did: every Begin gets an End
+        self._batch._span(self._kind, self._ident, self._span, "End",
+                          self._now())
+        return False
+
+
 class TraceBatch:
     """g_traceBatch (flow/Trace.h): micro-timing attach/event records that
     stitch ONE transaction's timeline across processes — the commit path
     emits `addEvent("CommitDebug", id, "Proxy.commitBatch.Before")`-style
     probes (NativeAPI.actor.cpp:2689, MasterProxyServer.actor.cpp:356,
-    Resolver.actor.cpp:83). Buffered; dump() flushes to the trace log."""
+    Resolver.actor.cpp:83). Buffered; dump() flushes to the sink.
 
-    def __init__(self, max_buffer: int = 4096):
+    `enabled` is the off switch: while it is False every recording method
+    returns after testing it. The process-wide g_trace_batch follows the
+    sink (set_sink); a batch built by hand records from the start."""
+
+    def __init__(self, max_buffer: int = 4096, enabled: bool = True):
         self.max_buffer = max_buffer
+        self.enabled = enabled
         self._events: list[dict] = []
 
     def add_event(self, kind: str, ident, location: str, at: float | None = None):
+        if not self.enabled:
+            return
         self._events.append({"Type": kind,
                              "Time": round(_now() if at is None else at, 6),
                              "ID": str(ident), "Location": location})
@@ -189,6 +260,8 @@ class TraceBatch:
 
     def add_attach(self, kind: str, ident, to: str, at: float | None = None):
         """Link two ids (e.g. a transaction to its commit batch)."""
+        if not self.enabled:
+            return
         self._events.append({"Type": kind,
                              "Time": round(_now() if at is None else at, 6),
                              "ID": str(ident), "To": str(to)})
@@ -199,10 +272,33 @@ class TraceBatch:
         """Begin a named stage span for one id. Pass `at=loop.now()` so sim
         roles stamp virtual time (the global clock is per-interpreter and a
         process never owns it)."""
+        if not self.enabled:
+            return
         self._span(kind, ident, span, "Begin", at)
 
     def span_end(self, kind: str, ident, span: str, at: float | None = None):
+        if not self.enabled:
+            return
         self._span(kind, ident, span, "End", at)
+
+    def section(self, kind: str, ident, span: str,
+                now: Callable[[], float] = time.monotonic):
+        """Context manager for a synchronous section on the calling thread:
+        the same Begin/End pair as span_begin/span_end, stamped by `now`
+        (pass `loop.now`, so that the simulator stamps virtual time), and
+        the body inside the annotator's context when one is installed. The
+        End is written even when the body raises."""
+        if not self.enabled:
+            return _NULL_SECTION
+        return _Section(self, kind, ident, span, now)
+
+    def annotate(self, span: str, ident):
+        """The annotator's context alone, for a section whose records are
+        written elsewhere. `mono_us` is time.monotonic at entry, which makes
+        every annotation one reading of both clocks."""
+        if not self.enabled or _annotator is None:
+            return _NULL_SECTION
+        return _annotator(span, str(ident), int(time.monotonic() * 1e6))
 
     def _span(self, kind: str, ident, span: str, phase: str, at: float | None):
         self._events.append({"Type": kind,
@@ -212,19 +308,46 @@ class TraceBatch:
             self.dump()
 
     def dump(self):
+        """Hand the buffered records to the sink; with none they are dropped
+        (nothing of a span is ever printed to stderr)."""
         events, self._events = self._events, []
-        for e in events:
-            if _sink is not None:
+        if _sink is not None:
+            for e in events:
                 _sink(e)
-            else:
-                print(json.dumps(e, default=str), file=sys.stderr)
 
     def timeline(self, ident) -> list[dict]:
         """Buffered records for one id (tests/debugging)."""
         return [e for e in self._events if e.get("ID") == str(ident)]
 
 
-g_trace_batch = TraceBatch()
+# flushed a thousand records at a time: a flush runs on the loop thread, and
+# 4096 records of JSON held it for 20 ms with the file's writes buffered
+g_trace_batch = TraceBatch(max_buffer=1024, enabled=False)
+
+
+def span_full_collections():
+    """Write a `Loop.FullGC` span pair round every full (generation 2)
+    garbage collection of this process: it holds the GIL, so for its length
+    no thread of the process runs Python — the loop does not tick and a
+    blocking thread cannot return. Returns the callback (gc.callbacks), so
+    that a test can take it out again."""
+    import gc
+    began = [0.0]
+
+    def on_gc(phase: str, info: dict) -> None:
+        if info.get("generation") != 2:
+            return
+        if phase == "start":
+            began[0] = time.monotonic()
+        elif g_trace_batch.enabled:
+            ident = f"gc{gc.get_stats()[2]['collections']}"
+            g_trace_batch.span_begin("LoopSpan", ident, "Loop.FullGC",
+                                     at=began[0])
+            g_trace_batch.span_end("LoopSpan", ident, "Loop.FullGC",
+                                   at=time.monotonic())
+
+    gc.callbacks.append(on_gc)
+    return on_gc
 
 
 class LatencyBands:

@@ -201,25 +201,30 @@ def test_proxy_emits_bands_and_probes():
     from foundationdb_tpu.utils.knobs import KNOBS
 
     KNOBS.set("CONFLICT_BACKEND", "oracle")
-    c = SimCluster(seed=2, n_proxies=1, n_resolvers=1, n_tlogs=1, n_storage=1)
-    db = c.database()
+    got: list[dict] = []
+    T.set_sink(got.append)  # probes are recorded only while someone listens
+    try:
+        c = SimCluster(seed=2, n_proxies=1, n_resolvers=1, n_tlogs=1,
+                       n_storage=1)
+        db = c.database()
 
-    async def t():
-        for i in range(5):
-            tr = db.create_transaction()
-            await tr.get(b"k%d" % i)  # forces a GRV
-            tr.set(b"k%d" % i, b"v")
-            await tr.commit()
-    c.run(c.loop.spawn(t()), max_time=600.0)
-    p = c.proxies[0]
-    assert p.commit_bands.total >= 5
-    assert p.grv_bands.total >= 1
-    probes = [e for e in T.g_trace_batch._events
-              if e["Type"] == "CommitDebug"]
+        async def t():
+            for i in range(5):
+                tr = db.create_transaction()
+                await tr.get(b"k%d" % i)  # forces a GRV
+                tr.set(b"k%d" % i, b"v")
+                await tr.commit()
+        c.run(c.loop.spawn(t()), max_time=600.0)
+        p = c.proxies[0]
+        assert p.commit_bands.total >= 5
+        assert p.grv_bands.total >= 1
+        T.g_trace_batch.dump()
+    finally:
+        T.set_sink(None)
+        KNOBS.reset()
+    probes = [e for e in got if e["Type"] == "CommitDebug"]
     assert any(e["Location"] == "Proxy.commitBatch.AfterLogPush"
                for e in probes)
-    T.g_trace_batch.dump()
-    KNOBS.reset()
 
 
 def test_sim_validation_oracles():
