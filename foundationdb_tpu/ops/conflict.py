@@ -11,15 +11,18 @@ device-resident sorted boundary keys (fixed-width uint32 limbs) + per-segment
 version offsets + a sparse-table (power-of-two window) max pyramid — the dense
 analogue of the skiplist's per-level max-version annotations (:324-357).
 
-detect = ONE jitted function built around ONE lax.sort of
-[state boundaries | read begins | read ends | write begins | write ends]
-(multi-limb binary searches lose to a single wide sort on TPU: each bisection
-step is a latency-bound multi-limb gather, while the sort runs at bandwidth):
+detect = ONE jitted function built around ONE order of
+[state boundaries | read begins | read ends | write begins | write ends].
+The state's boundaries are kept sorted from step to step, so the order is
+built, not found: the batch's endpoints alone are sorted, each is ranked in
+the state by a fixed-trip bisection, and the two sorted runs are interleaved
+by those ranks (_merged_order; the chip figures are in conflict_step's
+docstring):
   1. too-old filter (SkipList.cpp:985 semantics)
   2. history check: each read endpoint's rank among state boundaries comes
-     from the sort; O(1) sparse-table range-max over the segment versions,
+     from that order; O(1) sparse-table range-max over the segment versions,
      compare against each txn's read snapshot (replaces CheckMax :755-837)
-  3. intra-batch: endpoint ranks from the same sort feed a dyadic
+  3. intra-batch: endpoint ranks from the same order feed a dyadic
      sort/scan evaluator for "earlier txns win" semantics — each fixpoint
      sweep is O(n log n) prefix scans over per-level sorted write endpoints
      instead of the old dense (NW, NR) overlap matrix mat-vec, and the
@@ -27,7 +30,7 @@ step is a latency-bound multi-limb gather, while the sort runs at bandwidth):
      never an unbounded while_loop); unconverged batches fall back to an
      exact host-side pass (replaces MiniConflictSet :1028-1130; see
      docs/conflict_kernel.md)
-  4. merge of surviving writes into the step function: the sorted array IS
+  4. merge of surviving writes into the step function: the ordered array IS
      the union; slots, coverage, and values are carved out with prefix scans
      and one compaction scatter (replaces mergeWriteConflictRanges :1260)
   5. window GC by clamp + coalesce (replaces removeBefore :665)
@@ -213,6 +216,70 @@ def _lex_sort_perm(keys):
         return lax.sort([row[perm], perm], num_keys=1, is_stable=True)[1]
 
     return lax.fori_loop(0, nk, one_pass, jnp.arange(n, dtype=jnp.int32))
+
+
+def _rank_in_sorted(skeys, q, strict):
+    """For each column of `q` ((NK, M)): how many columns of `skeys`
+    ((NK, K), non-decreasing) precede it — those < q where `strict`, those
+    <= q elsewhere (lower / upper bound; one search serves both).
+
+    A fixed-trip bisection: ceil(log2(K + 1)) rounds in a fori_loop (a scan
+    in the jaxpr, never an unbounded while), one (NK, M) column gather a
+    round. A query whose interval has closed rides the remaining rounds
+    unchanged."""
+    K = skeys.shape[1]
+    m = q.shape[1]
+
+    def one_round(_, lohi):
+        lo, hi = lohi
+        mid = (lo + hi) >> 1
+        km = skeys[:, jnp.minimum(mid, K - 1)]
+        before = jnp.where(strict, _key_lt(km, q), ~_key_lt(q, km))
+        go = lo < hi
+        return (jnp.where(go & before, mid + 1, lo),
+                jnp.where(go & ~before, mid, hi))
+
+    lo, _hi = lax.fori_loop(
+        0, int(K).bit_length(), one_round,
+        (jnp.zeros(m, jnp.int32), jnp.full(m, K, jnp.int32)))
+    return lo
+
+
+def _merged_order(bkeys, bk, bcls):
+    """The stable sort of [state | batch] by (key, class, index), built
+    without sorting the state: `bkeys` ((NK, K)) is already non-decreasing
+    and all of class 1, so only the M batch columns `bk` (classes `bcls`,
+    0 or 2) are sorted, each is ranked in the state, and the two sorted runs
+    are interleaved by those ranks. Element for element what
+    _lex_sort_perm([bkeys | bk] + class row) returns:
+
+      sidx    (K + M,) original index of the element at each sorted position
+      bpos    (M,)     sorted position of each batch element (the inverse
+                       permutation past the state's K entries)
+      cum_b   (K + M,) inclusive count of batch elements (sidx >= K) up to
+                       each position
+
+    Ties: equal batch elements keep index order (the M-wide sort is stable);
+    class 0 ranks by lower bound and so lands before equal state keys,
+    class 2 by upper bound and lands after them; the state's own equal keys
+    (its all-ones padding) keep slot order."""
+    K = bkeys.shape[1]
+    M = bk.shape[1]
+    bperm = _lex_sort_perm(
+        jnp.concatenate([bk, bcls.astype(jnp.uint32)[None]]))
+    rank = _rank_in_sorted(bkeys, bk[:, bperm], bcls[bperm] == 0)
+    # sorted batch element j has `rank` state keys and j batch elements
+    # before it: strictly increasing positions
+    p = rank + jnp.arange(M, dtype=jnp.int32)
+    bpos = jnp.zeros(M, jnp.int32).at[bperm].set(p, unique_indices=True)
+    src_b = jnp.full(K + M, -1, jnp.int32).at[p].set(
+        K + bperm, indices_are_sorted=True, unique_indices=True)
+    is_batch = src_b >= 0
+    cum_b = jnp.cumsum(is_batch.astype(jnp.int32))
+    # a state slot keeps its index less the batch elements before it
+    sidx = jnp.where(
+        is_batch, src_b, jnp.arange(K + M, dtype=jnp.int32) - cum_b)
+    return sidx, bpos, cum_b
 
 
 # ---------------------------------------------------------------------------
@@ -431,8 +498,9 @@ def conflict_step(state: dict, batch: dict, *, shapes: ConflictShapes,
     sandwich rounds (0 = auto, see _auto_rounds).
 
     state:
-      bkeys (L,K) uint32 sorted; bval (K,) i32; nb () i32; oldest () i32;
-      table (LEVELS,K) i32
+      bkeys (L,K) uint32, non-decreasing over ALL K slots (nb live
+      boundaries, then all-0xFFFFFFFF padding, the largest key);
+      bval (K,) i32; nb () i32; oldest () i32; table (LEVELS,K) i32
     batch:
       txn_valid (T,) bool; snapshot (T,) i32 (version offsets)
       rb, re (L,NR) u32; rtxn (NR,) i32 (= T for padding);
@@ -441,14 +509,19 @@ def conflict_step(state: dict, batch: dict, *, shapes: ConflictShapes,
       advance_floor () bool — advance the MVCC window after this chunk
       (False for all but the last chunk of a logical batch)
 
-    Layout: ONE lax.sort of [state boundaries | rb | re | wb | we] per step
-    feeds everything — history positions (instead of a 19-step multi-limb
-    bisection whose per-step gathers dominated the profile), intra-batch
-    endpoint ranks (instead of a second sort), and the merged union of state
-    with committed write endpoints (instead of a second bisection plus a
-    scatter-built union). On TPU a 330k-wide multi-operand sort costs ~2ms
-    while each bisection costs ~6.4ms in gathers, so the sort is the cheapest
-    way to position queries in the state.
+    Layout: ONE order of [state boundaries | rb | re | wb | we] per step
+    feeds everything — history positions, intra-batch endpoint ranks (instead
+    of a second sort), and the merged union of state with committed write
+    endpoints (instead of a scatter-built union). The order is the stable
+    sort by (key, class, index), and it is constructed (_merged_order): the
+    M = 2NR + 2NW batch rows are sorted by _lex_sort_perm and ranked in the
+    state, which is sorted already — init_state, the gc scope's compaction,
+    the poison branch, rebase_state (keys untouched) and the sharded engine's
+    re-cut all leave `bkeys` non-decreasing over every slot. On one v5e at
+    capacity 2^18 (PERF.md, PR 27) the M-wide sort costs 0.3-0.7 ms and the
+    19 bisection rounds 0.1-2.3 ms (M = 640 .. 10,240), where sorting all
+    K + M rows in eight passes cost 18 ms of a 41 ms step; what is left of
+    the `sort` scope is the two K + M wide gathers into that order.
     """
     T, NR, NW, K = shapes.txns, shapes.reads, shapes.writes, shapes.capacity
     L = shapes.limbs
@@ -476,29 +549,33 @@ def conflict_step(state: dict, batch: dict, *, shapes: ConflictShapes,
             has_reads = (jnp.zeros(T + 1, bool).at[rtxn].max(rvalid))[:T]
 
     with jax.named_scope("sort"):
-        # ---- 0. THE sort: [state | rb | re | wb | we] ----
+        # ---- 0. THE order of [state | rb | re | wb | we] ----
         # Class tiebreak at equal keys: re(0) < state(1) < rb/wb/we(2).
         #  - rb after equal state keys  -> #state<=rb = upper bound (segment of rb)
         #  - re before equal state keys -> #state<re  = lower bound
         #  - wb/we after equal state keys -> duplicate endpoint lands in the SAME
         #    union slot as the state boundary it equals
-        N_ALL = K + 2 * NR + 2 * NW
-        allk = jnp.concatenate([bkeys, rb, re, wb, we], axis=1)  # (L, N_ALL)
-        cls = jnp.concatenate([
-            jnp.ones(K, jnp.int32),
+        # The state's K rows are in order already (precondition), so the
+        # order is built from the batch's M = 2NR + 2NW rows alone
+        # (_merged_order), not found by sorting K + M rows.
+        M = 2 * NR + 2 * NW
+        N_ALL = K + M
+        bk = jnp.concatenate([rb, re, wb, we], axis=1)  # (L, M)
+        bcls = jnp.concatenate([
             jnp.full(NR, 2, jnp.int32), jnp.zeros(NR, jnp.int32),
             jnp.full(2 * NW, 2, jnp.int32)])
-        vpay = jnp.concatenate([bval, jnp.full(2 * NR + 2 * NW, NEG, jnp.int32)])
-        sidx = _lex_sort_perm(jnp.concatenate(
-            [allk, cls.astype(jnp.uint32)[None]]))  # original element index
+        # sidx: original element index; spos_b: sorted position of each
+        # batch element (the inverse permutation's entries K..N_ALL)
+        sidx, spos_b, cum_b = _merged_order(bkeys, bk, bcls)
+        allk = jnp.concatenate([bkeys, bk], axis=1)  # (L, N_ALL)
+        vpay = jnp.concatenate([bval, jnp.full(M, NEG, jnp.int32)])
         skeys = allk[:, sidx]                   # (L, N_ALL) sorted
-        scls = cls[sidx]
         sval = vpay[sidx]                       # state values in sorted order
-        # inverse permutation: sorted position of each original element
-        spos = jnp.zeros(N_ALL, jnp.int32).at[sidx].set(
-            jnp.arange(N_ALL, dtype=jnp.int32))
-        is_state = scls == 1
-        cum_state = jnp.cumsum(is_state.astype(jnp.int32))  # inclusive
+        is_batch = sidx >= K
+        is_re = (sidx >= K + NR) & (sidx < K + 2 * NR)
+        scls = jnp.where(is_batch, jnp.where(is_re, 0, 2), 1)
+        cum_state = (jnp.arange(1, N_ALL + 1, dtype=jnp.int32)
+                     - cum_b)                   # inclusive
 
     with jax.named_scope("history"):
         # ---- 1. too-old (only txns with read ranges expire: SkipList.cpp:985) ----
@@ -508,8 +585,8 @@ def conflict_step(state: dict, batch: dict, *, shapes: ConflictShapes,
         if ablate in ("no_hist", "only_merge"):
             hist_conflict = jnp.zeros(T, bool)
         else:
-            ub_rb = cum_state[spos[K:K + NR]]        # #state keys <= rb
-            lb_re = cum_state[spos[K + NR:K + 2 * NR]]  # #state keys < re
+            ub_rb = cum_state[spos_b[:NR]]        # #state keys <= rb
+            lb_re = cum_state[spos_b[NR:2 * NR]]  # #state keys < re
             i0 = jnp.maximum(ub_rb - 1, 0)  # segment containing begin
             i1 = lb_re  # first boundary >= end
             nonempty = _key_lt(rb, re)
@@ -531,11 +608,11 @@ def conflict_step(state: dict, batch: dict, *, shapes: ConflictShapes,
         statuses = jnp.where(txn_valid, statuses, COMMITTED)
         return _merge_phase(state, batch, statuses, commit, shapes,
                             max_write_life, ablate, sort_products=(
-                                skeys, scls, sval, sidx, spos, cum_state),
+                                skeys, scls, sval, sidx, spos_b, cum_state),
                             eligible=g0)
     with jax.named_scope("intra"):
         # ---- 3. intra-batch: endpoint ranks -> overlap queries -> fixpoint ----
-        # Endpoint ranks come from the big sort: rank = number of distinct
+        # Endpoint ranks come from the step's order: rank = number of distinct
         # batch-endpoint key groups at-or-before this element, which is
         # order-isomorphic to the keys over batch endpoints (state elements
         # interleave but contribute no rank). The default "scan" evaluator
@@ -544,16 +621,15 @@ def conflict_step(state: dict, batch: dict, *, shapes: ConflictShapes,
         # (geometry built once per step, _intra_scan_levels) — O(n log n) per
         # sweep with no n×n matrix materialized; the "legacy" evaluator is the
         # pre-overhaul dense (NW, NR) int8 matvec + unbounded while_loop.
-        is_batch = ~is_state
         newgrp = jnp.concatenate(
             [jnp.ones(1, bool), ~_key_eq(skeys[:, 1:], skeys[:, :-1])])
-        cum_b_excl = jnp.cumsum(is_batch.astype(jnp.int32)) - is_batch
+        cum_b_excl = cum_b - is_batch
         grp_start_b = lax.cummax(jnp.where(newgrp, cum_b_excl, -1))
         first_b = is_batch & (cum_b_excl == grp_start_b)
         rank_grp = jnp.cumsum(first_b.astype(jnp.int32)) - 1
         # carry each group's first-batch rank forward (monotone -> cummax)
         rank_carried = lax.cummax(jnp.where(first_b, rank_grp, -1))
-        qranks = rank_carried[spos[K:]]          # ranks of [rb | re | wb | we]
+        qranks = rank_carried[spos_b]           # ranks of [rb | re | wb | we]
         rbr, rer = qranks[:NR], qranks[NR:2 * NR]
         wbr, wer = qranks[2 * NR:2 * NR + NW], qranks[2 * NR + NW:]
 
@@ -635,7 +711,7 @@ def conflict_step(state: dict, batch: dict, *, shapes: ConflictShapes,
         statuses = jnp.where(txn_valid, statuses, COMMITTED)
     return _merge_phase(state, batch, statuses, commit, shapes,
                         max_write_life, ablate, sort_products=(
-                            skeys, scls, sval, sidx, spos, cum_state),
+                            skeys, scls, sval, sidx, spos_b, cum_state),
                         merge_commit=merge_commit, converged=converged,
                         eligible=g)
 
@@ -671,15 +747,14 @@ def _merge_phase(state, batch, statuses, commit, shapes, max_write_life,
     with jax.named_scope("merge"):
         # ---- 4. merge surviving writes into the step function at vnew ----
         # The union of state boundaries and committed write endpoints is already
-        # IN the big sorted array (sort_products); dead elements — read
+        # IN the ordered array (sort_products); dead elements — read
         # endpoints, uncommitted/empty writes, dead state slots — are simply not
         # union slots, and the merged state is carved out with prefix scans + one
-        # compaction scatter. This replaces the previous incremental design's
-        # per-batch multi-limb bisection of candidates into the state (the single
-        # most expensive gather loop) with sort products that history and
-        # intra-batch checks already paid for (the device analogue of the
-        # reference's finger-merge, mergeWriteConflictRanges SkipList.cpp:1260).
-        skeys, scls, sval, sidx, spos, cum_state = sort_products
+        # compaction scatter: no second positioning of the writes in the state,
+        # the history and intra-batch checks already paid for the order (the
+        # device analogue of the reference's finger-merge,
+        # mergeWriteConflictRanges SkipList.cpp:1260).
+        skeys, scls, sval, sidx, spos_b, cum_state = sort_products
         N_ALL = K + 2 * NR + 2 * NW
         if shapes.strided:
             wvalid = wb[L - 1] != jnp.uint32(0xFFFFFFFF)
@@ -692,7 +767,7 @@ def _merge_phase(state, batch, statuses, commit, shapes, max_write_life,
         # coverage deltas at each write endpoint's sorted position: +1 at
         # committed begins, -1 at committed ends (positions are unique)
         delta_w = jnp.concatenate([cw.astype(jnp.int32), -(cw.astype(jnp.int32))])
-        pos_w = spos[K + 2 * NR:]
+        pos_w = spos_b[2 * NR:]
         delta_sorted = jnp.zeros(N_ALL, jnp.int32).at[pos_w].set(delta_w)
 
         # union slot sources: live state boundaries + committed write endpoints
@@ -709,9 +784,9 @@ def _merge_phase(state, batch, statuses, commit, shapes, max_write_life,
         rep = is_src & (cum_src_excl == grp_start_src)
 
         # value of each slot under the CURRENT step function: the last live state
-        # boundary's value at-or-before it, carried forward by scan (sorted-order
-        # values rode the sort as a payload operand; an N_ALL-wide scan is
-        # cheaper than the random bval gather it replaces)
+        # boundary's value at-or-before it, carried forward by scan (sval holds
+        # the values in sorted order; an N_ALL-wide scan is cheaper than a
+        # random bval gather per slot)
         val_u = _carry_last_flagged(jnp.where(live_state, sval, NEG), live_state)
 
         # coverage at a slot = total delta through the END of its key group

@@ -13,6 +13,7 @@ is given this file, once one of its tests has started.
 """
 
 import functools
+import re
 
 import numpy as np
 import pytest
@@ -65,26 +66,47 @@ def _step_args(shapes, sharding):
                    sharding))
 
 
-def _compile_donated_step(shapes, topo):
+@functools.lru_cache(maxsize=None)
+def _donated_step(shapes, device):
     step = jax.jit(functools.partial(
         C.conflict_step, shapes=shapes, max_write_life=WINDOW,
         intra_mode="scan", intra_rounds=0), donate_argnums=(0,))
-    one_chip = SingleDeviceSharding(topo.devices[0])
-    compiled = step.lower(*_step_args(shapes, one_chip)).compile()
+    return step.lower(
+        *_step_args(shapes, SingleDeviceSharding(device))).compile()
+
+
+def _compile_donated_step(shapes, topo):
+    compiled = _donated_step(shapes, topo.devices[0])
     mem = compiled.memory_analysis()
     assert mem.generated_code_size_in_bytes > 0
     # the donated state is written in place: the chip holds one copy of it
     assert mem.alias_size_in_bytes > 0
-    return mem
+    return compiled
 
 
 def test_conflict_step_donated_small_shape(topo):
     _compile_donated_step(SMALL, topo)
 
 
+def test_small_step_sorts_the_batch_not_the_state(topo):
+    """The step's order is built from the batch's M rows ranked in the
+    sorted state: the compiled module sorts M rows (the eight passes of
+    _lex_sort_perm) and nothing of width K + M — the wide sort is gone from
+    the program, not bypassed."""
+    M = 2 * SMALL.reads + 2 * SMALL.writes
+    widths = set()
+    compiled = _compile_donated_step(SMALL, topo)
+    for line in compiled.as_text().splitlines():
+        result, is_sort, _operands = line.partition(") sort(")
+        if is_sort:
+            widths.update(int(n) for n in re.findall(r"\[(\d+)\]", result))
+    assert M in widths, widths
+    assert SMALL.capacity + M not in widths, widths
+
+
 @pytest.mark.slow(reason="45-80 s to compile (PERF.md, compile times)")
 def test_conflict_step_donated_served_shape(topo):
-    mem = _compile_donated_step(SERVED, topo)
+    mem = _compile_donated_step(SERVED, topo).memory_analysis()
     assert mem.temp_size_in_bytes < 1 << 30  # 294 MB when written
 
 

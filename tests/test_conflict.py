@@ -506,6 +506,166 @@ def test_capped_rounds_fallback_parity():
 
 
 # ---------------------------------------------------------------------------
+# the step's order: built from the batch's ranks in the sorted state, equal
+# element for element to the stable sort of [state | rb | re | wb | we]
+# ---------------------------------------------------------------------------
+
+def _assert_constructed_order(state, batch, shapes):
+    """_merged_order against _lex_sort_perm of the concatenation and its
+    inverse, on one (state, encoded batch)."""
+    import jax.numpy as jnp
+    from foundationdb_tpu.ops import conflict as C
+    K, NR, NW = shapes.capacity, shapes.reads, shapes.writes
+    bk = np.concatenate([np.asarray(batch[f]) for f in ("rb", "re", "wb", "we")],
+                        axis=1)
+    bcls = np.concatenate([np.full(NR, 2), np.zeros(NR), np.full(2 * NW, 2)]
+                          ).astype(np.int32)
+    bkeys = np.asarray(state["bkeys"])
+    sidx, bpos, cum_b = C._merged_order(
+        jnp.asarray(bkeys), jnp.asarray(bk), jnp.asarray(bcls))
+    cls = np.concatenate([np.ones(K, np.uint32), bcls.astype(np.uint32)])
+    want = np.asarray(C._lex_sort_perm(jnp.asarray(np.concatenate(
+        [np.concatenate([bkeys, bk], axis=1), cls[None]]))))
+    inverse = np.empty_like(want)
+    inverse[want] = np.arange(want.size, dtype=want.dtype)
+    np.testing.assert_array_equal(np.asarray(sidx), want)
+    np.testing.assert_array_equal(np.asarray(bpos), inverse[K:])
+    np.testing.assert_array_equal(np.asarray(cum_b), np.cumsum(want >= K))
+
+
+def _order_engine(**kw):
+    """(shapes, encoder, jitted step, fresh state) at a small shape. The
+    step waits for its result: the encoder hands out the same host buffers
+    again, and a dispatch still reading them must not see the next batch."""
+    import jax
+    from foundationdb_tpu.ops import conflict as C
+    shapes = C._resolve_shapes(**kw)
+    compiled = C._compiled_step(shapes, 1000)
+
+    def step(state, batch):
+        return jax.block_until_ready(compiled(state, batch))
+
+    return shapes, C.BatchEncoder(shapes), step, C.init_state(shapes)
+
+
+def _writes_of(*ranges):
+    return [txn(0, writes=[r]) for r in ranges]
+
+
+def _order_case_live_boundary():
+    """Every class of endpoint equal to a live boundary of the state."""
+    shapes, enc, step, state = _order_engine(
+        capacity=64, txns=8, reads_per_txn=2, writes_per_txn=2)
+    state, _, _ = step(state, enc.encode_batch(
+        _writes_of((b"b", b"d"), (b"f", b"h")), 10))
+    batch = enc.encode_batch([
+        txn(5, reads=[(b"b", b"d"), (b"d", b"f")], writes=[(b"d", b"f")]),
+        txn(5, reads=[(b"a", b"b"), (b"h", b"i")], writes=[(b"h", b"z")]),
+    ], 20)
+    return state, batch, shapes
+
+
+def _order_case_equal_endpoints():
+    """rb, re, wb and we equal to each other within one batch (empty and
+    adjacent ranges), on a state that holds the same key."""
+    shapes, enc, step, state = _order_engine(
+        capacity=64, txns=8, reads_per_txn=2, writes_per_txn=2)
+    state, _, _ = step(state, enc.encode_batch(_writes_of((b"m", b"n")), 10))
+    batch = enc.encode_batch([
+        txn(5, reads=[(b"m", b"m"), (b"k", b"m")],
+            writes=[(b"m", b"m"), (b"m", b"p")]),
+        txn(5, reads=[(b"m", b"p")], writes=[(b"k", b"m")]),
+    ], 20)
+    return state, batch, shapes
+
+
+def _order_case_padding_only():
+    """An all-padding batch (0xFFFFFFFF limbs; class 2, and class 0 for re)
+    against the fresh state's padding."""
+    shapes, enc, _step, state = _order_engine(
+        capacity=32, txns=4, reads_per_txn=2, writes_per_txn=2)
+    return state, enc.encode_batch([], 10), shapes
+
+
+def _order_case_nb_one():
+    shapes, enc, _step, state = _order_engine(
+        capacity=32, txns=4, reads_per_txn=2, writes_per_txn=2)
+    assert int(state["nb"]) == 1
+    batch = enc.encode_batch([
+        txn(0, reads=[(b"", b"a")], writes=[(b"", b"\xff")]),
+        txn(0, reads=[(b"a", b"b")], writes=[(b"a", b"b")]),
+    ], 10)
+    return state, batch, shapes
+
+
+def _order_case_nb_full_and_m_over_k():
+    """The state exactly full (nb == K, no padding left) at a shape whose
+    batch is wider than the state (M = 16 > K = 4)."""
+    shapes, enc, step, state = _order_engine(
+        capacity=4, txns=4, reads_per_txn=1, writes_per_txn=1)
+    for v, (lo, hi) in ((1, (b"k10", b"k20")), (2, (b"k20", b"k30"))):
+        state, _, _ = step(state, enc.encode_batch(_writes_of((lo, hi)), v))
+    assert int(state["nb"]) == shapes.capacity
+    batch = enc.encode_batch([
+        txn(2, reads=[(b"k20", b"k30")], writes=[(b"k30", b"k40")]),
+        txn(2, reads=[(b"", b"k10")], writes=[(b"k05", b"k10")]),
+    ], 3)
+    return state, batch, shapes
+
+
+def _order_case_poisoned():
+    shapes, enc, step, state = _order_engine(
+        capacity=8, txns=8, reads_per_txn=1, writes_per_txn=1)
+    state, _, info = step(state, enc.encode_batch(
+        _writes_of(*[(b"%02d" % i, b"%02da" % i) for i in range(8)]), 10))
+    assert bool(info["overflow"]) and bool(state["poisoned"])
+    batch = enc.encode_batch([
+        txn(5, reads=[(b"", b"01")], writes=[(b"", b"\xff")]),
+        txn(5, reads=[(b"03", b"04")], writes=[(b"03", b"03a")]),
+    ], 20)
+    return state, batch, shapes
+
+
+@pytest.mark.parametrize("case", [
+    _order_case_live_boundary, _order_case_equal_endpoints,
+    _order_case_padding_only, _order_case_nb_one,
+    _order_case_nb_full_and_m_over_k, _order_case_poisoned,
+], ids=lambda f: f.__name__.removeprefix("_order_case_"))
+def test_constructed_order_tie_cases(case):
+    _assert_constructed_order(*case())
+
+
+@pytest.mark.parametrize("seed,kw", [
+    (41, {}), (42, {}), (43, {}),
+    (44, {"strided": True}), (45, {"strided": True}),
+    (46, {"key_bytes": 8}), (47, {"key_bytes": 8}),
+    (48, {"capacity": 16, "txns": 8}),  # M = 64 > K; overflows on the way
+], ids=["dynamic-41", "dynamic-42", "dynamic-43", "strided-44", "strided-45",
+        "key_bytes8-46", "key_bytes8-47", "m_over_k-48"])
+def test_constructed_order_random(seed, kw):
+    """Random states (whatever the step itself left behind: merged, window
+    collected, poisoned) and random batches: the constructed order is the
+    stable sort's, before every step."""
+    kw = {"capacity": 256, "txns": 16, "reads_per_txn": 2,
+          "writes_per_txn": 2, **kw}
+    shapes, enc, step, state = _order_engine(**kw)
+    rng = DeterministicRandom(seed)
+    space = [b"k%02d" % i for i in range(40)] + [b"", b"k07\x00", b"\xff"]
+    version = 0
+    for _ in range(12):
+        version += rng.randint(50, 300)
+        txns = [txn(max(0, version - rng.randint(0, 1500)),
+                    [_random_range(rng, space)
+                     for _ in range(rng.randint(0, 2))],
+                    [_random_range(rng, space)
+                     for _ in range(rng.randint(0, 2))])
+                for _ in range(rng.randint(0, kw["txns"]))]
+        batch = enc.encode_batch(txns, version)
+        _assert_constructed_order(state, batch, shapes)
+        state, _, _ = step(state, batch)
+
+
+# ---------------------------------------------------------------------------
 # CI smoke: the scan kernel vs the legacy fixpoint kernel (A/B on the knob),
 # and the serving jaxpr contains NO unbounded while_loop
 # ---------------------------------------------------------------------------
