@@ -13,7 +13,8 @@ in THIS process and replays a seeded stream of conflict batches through
 DeviceConflictSet and the independent OracleConflictSet at the served shapes.
 
 `--chips 4` runs only the mesh-sharded engine against per-shard oracles, in
-one process, over four real devices.
+one process, over four real devices: the benchmark's keys at the served shape
+from a cold start, with two moves of the cuts to whole keys in mid-stream.
 
 One process per chip: until the last child is reaped this parent imports the
 client stack only and never JAX. Any failed phase exits non-zero at once;
@@ -452,26 +453,49 @@ def _clipped(txns, lo: bytes, hi: bytes | None):
                             write_ranges=clip(t.write_ranges)) for t in txns]
 
 
+def _move_oracles(oracles, old_cuts, new_cuts, at_version: int) -> None:
+    """A move of the cuts as `rebalance_cuts` documents it, on the per-shard
+    oracles: what a shard keeps stays exact, what it acquires is filled at
+    the move's version."""
+    top = b"\xff" * 32
+    for o, old_lo, old_hi, lo, hi in zip(
+            oracles, old_cuts, old_cuts[1:] + [top], new_cuts,
+            new_cuts[1:] + [top]):
+        a, b = max(lo, old_lo), min(hi, old_hi)
+        if a < b:
+            o.add_range(lo, a, at_version)
+            o.add_range(b, hi, at_version)
+        else:
+            o.add_range(lo, hi, at_version)
+
+
 def phase_sharded(seed: int) -> dict:
     """ShardedDeviceConflictSet over the four real devices against the
     reference for its documented semantics: one OracleConflictSet per shard
     fed the shard-clipped ranges, verdicts combined with min (the proxy's
-    rule over resolvers). Keys are spread over the whole first-limb space
-    and ranges straddle all three cuts."""
+    rule over resolvers). The keys are the benchmark's (`b"%016d"`, which
+    all share their first eleven bytes), at the served shape, from a cold
+    start on the default cuts with every key on shard 0; twice in
+    mid-stream the cuts are moved to whole keys, and the oracles with them.
+    The engine's own balance is held off so that the only moves are these,
+    each at a version between two batches, which the oracles can follow."""
     import jax
+    import numpy as np
 
     from foundationdb_tpu.ops import conflict
     from foundationdb_tpu.ops.batch import TOO_OLD, TxnConflictInfo
     from foundationdb_tpu.ops.conflict_oracle import OracleConflictSet
     from foundationdb_tpu.parallel.sharded_conflict import (
-        ShardedDeviceConflictSet, make_resolver_mesh, shard_cut_bytes)
+        ShardedDeviceConflictSet, make_resolver_mesh)
+    from foundationdb_tpu.utils.knobs import KNOBS
     device = _attach(4)
     mesh = make_resolver_mesh(4)
-    cuts = shard_cut_bytes(4)
+    KNOBS.set("RESOLUTION_BALANCE_CHECK_BATCHES", 1 << 30)
     t0 = time.monotonic()
     cs = ShardedDeviceConflictSet(mesh=mesh, **SHARDED_SHAPE)
-    cs.detect([], 1)
+    cs.warmup()
     compile_seconds = time.monotonic() - t0
+    cuts = list(cs.cut_bytes)
 
     leaves = {}
     for name, leaf in cs._state.items():
@@ -486,25 +510,26 @@ def phase_sharded(seed: int) -> dict:
          leaves=leaves)
 
     n_keys = SHARDED_SHAPE["capacity"] // 8
-    stride = (1 << 32) // (n_keys + 16)  # range ends run a few keys past
-
-    def spread_key(i: int) -> bytes:
-        # first limb strides the whole uint32 space: every cut is crossed
-        return (i * stride).to_bytes(4, "big") + b"%012d" % i
-
+    # whole keys at the quartiles; then the eleven zeros every key shares
+    # (before all of them), a key's successor, and thirteen bytes that sort
+    # between two keys
+    moves = {16: [b"", key_of(n_keys // 4), key_of(n_keys // 2),
+                  key_of(3 * n_keys // 4)],
+             32: [b"", b"0" * 11, key_of(3000) + b"\x00", b"0" * 12 + b"5"]}
     oracles = [OracleConflictSet() for _ in cuts]
     got_all: list[int] = []
-    straddling = {c.hex(): 0 for c in cuts[1:]}
+    straddling: dict[str, int] = {}
     n_batches = 0
     t0 = time.monotonic()
     for txns, version in _conflict_stream(
             seed, n_batches=48, max_txns=600,
-            max_ranges=SHARDED_SHAPE["reads_per_txn"], key_fn=spread_key,
+            max_ranges=SHARDED_SHAPE["reads_per_txn"], key_fn=key_of,
             n_keys=n_keys):
         for t in txns:
             for b, e in t.read_ranges + t.write_ranges:
                 for c in cuts[1:]:
-                    straddling[c.hex()] += b < c < e
+                    straddling[c.hex()] = straddling.get(c.hex(), 0) + (
+                        b < c < e)
         got = cs.detect(txns, version)
         # the engine takes the too-old decision once, before the shards: a
         # txn with reads below the MVCC floor leaves no writes on any shard
@@ -524,13 +549,31 @@ def phase_sharded(seed: int) -> dict:
                  f"{n_batches} txn {t}: device={got[t]} oracle={want[t]}")
         got_all += got
         n_batches += 1
+        if n_batches in moves:
+            before = np.asarray(cs._state["nb"]).tolist()
+            t_move = time.monotonic()
+            cs.rebalance_cuts(moves[n_batches], version)
+            _move_oracles(oracles, cuts, moves[n_batches], version)
+            cuts = list(cs.cut_bytes)
+            emit("sharded_recut", after_batch=n_batches, version=version,
+                 cuts=[c.decode("latin-1") for c in cuts],
+                 boundaries_before=before,
+                 boundaries_after=np.asarray(cs._state["nb"]).tolist(),
+                 seconds=round(time.monotonic() - t_move, 4))
     counts = _status_counts(got_all)
-    check(all(counts.values()) and all(straddling.values()),
-          f"the stream did not exercise every status and every cut: "
-          f"{counts}, ranges straddling each cut: {straddling}")
+    check(cs.rebalances == len(moves),
+          f"{cs.rebalances} moves of the cuts, {len(moves)} were made here")
+    check(all(counts.values())
+          and all(any(straddling.get(c.hex()) for c in m[1:])
+                  for m in moves.values()),
+          f"the stream did not exercise every status and a cut of every "
+          f"move: {counts}, ranges straddling each cut: {straddling}")
     km = conflict.kernel_metrics.as_dict()
     emit("sharded", batches=n_batches, transactions=len(got_all), **counts,
          ranges_straddling_each_cut=straddling, identical_to_oracle=True,
+         recuts=cs.rebalances,
+         boundaries=np.asarray(cs._state["nb"]).tolist(),
+         ranges_offered=cs.ranges_offered, ranges_fullest=cs.ranges_fullest,
          shape=SHARDED_SHAPE, sandwich_rounds=SHARDED_SHAPE["txns"] // 2 + 1,
          compile_seconds=round(compile_seconds, 1),
          detect_seconds=round(time.monotonic() - t0, 1),

@@ -129,15 +129,26 @@ _HLO_INSTRUCTION = re.compile(
     r'^\s+(?:ROOT )?%([\w.\-]+) = .*\bop_name="([^"]*)"', re.MULTILINE)
 
 
-def scope_map(hlo_text: str) -> dict[str, str]:
+def scope_map(hlo_text: str, scopes=SCOPES) -> dict[str, str]:
     """{instruction: scope} for every instruction of a compiled module's
-    text whose op_name passes through one of SCOPES."""
+    text whose op_name passes through one of `scopes`."""
     out = {}
     for name, op_name in _HLO_INSTRUCTION.findall(hlo_text):
-        scope = next((p for p in op_name.split("/") if p in SCOPES), None)
+        scope = next((p for p in op_name.split("/") if p in scopes), None)
         if scope is not None:
             out[name] = scope
     return out
+
+
+def write_scope_map(directory: str, shapes, compiled_text: str,
+                    scopes=SCOPES) -> None:
+    """`scopes.conflict_step.<reads>x<writes>.json` of one compiled step
+    program: {"scopes": {instruction: scope}}."""
+    os.makedirs(directory, exist_ok=True)
+    path = os.path.join(
+        directory, f"scopes.conflict_step.{shapes.reads}x{shapes.writes}.json")
+    with open(path, "w") as f:
+        json.dump({"scopes": scope_map(compiled_text, scopes)}, f)
 
 
 def install_profiler_annotator() -> None:
@@ -1373,14 +1384,10 @@ class DeviceConflictSet:
         cache where there is one): for a traced run's warm-up only."""
         if self.shapes.strided:
             return
-        os.makedirs(directory, exist_ok=True)
         for shapes, step, batch in self._bucket_programs():
-            text = step.lower(self._state, batch).compile().as_text()
-            path = os.path.join(
-                directory,
-                f"scopes.conflict_step.{shapes.reads}x{shapes.writes}.json")
-            with open(path, "w") as f:
-                json.dump({"scopes": scope_map(text)}, f)
+            write_scope_map(
+                directory, shapes,
+                step.lower(self._state, batch).compile().as_text())
 
     def clear(self, oldest_version: int = 0):
         """clearConflictSet (SkipList.cpp:957): state is soft/reconstructable."""

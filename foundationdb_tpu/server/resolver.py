@@ -37,8 +37,17 @@ def new_conflict_set(oldest_version: int = 0,
     "device"  — single-device JAX kernel
     "sharded" — key-partitioned SPMD engine over the device mesh
                 (parallel/sharded_conflict.py): CONFLICT_NUM_SHARDS devices
-                (0 = every attached device), with resolutionBalancing
-                (load-sampled + conflict-mass cut moves) built in
+                (0 = every attached device). Its cuts are whole keys. It
+                starts on equal cuts of `key_range` and moves them itself
+                (resolutionBalancing): to quantiles of the keys it sampled,
+                when the ranges it counted per shard are skewed — looked at
+                every RESOLUTION_BALANCE_CHECK_BATCHES steps, and at once
+                while a shard nears its capacity — and to quantiles of the
+                conflict mass this role's sketch hands it every
+                RESOLUTION_BALANCE_EPOCH_SECONDS. A move shows as a
+                `Resolver.Recut` section and in `CutRebalances`;
+                `ShardRangesOffered`, `ShardRangesFullest` and
+                `ShardBoundariesFullest` say how even the partition is
     "oracle"  — pure-Python CPU reference
 
     `key_range` is the resolver's OWNED range from the outer ResolverMap
@@ -162,7 +171,6 @@ class Resolver:
         self._c_sampled = self.counters.counter("ConflictsSampled")
         # cross-epoch cut rebalancing (sharded engine only): the sketch's
         # decayed per-range conflict mass drives the inner-mesh recut
-        self._c_rebalances = self.counters.counter("CutRebalances")
         self._balance_task = (
             process.spawn(self._balance_loop(), "resolverBalance")
             if hasattr(self.conflict_set, "rebalance_from_conflicts")
@@ -195,6 +203,16 @@ class Resolver:
         if self._pipelined:
             snap.update(jaxenv.device_identity())
             snap["WarmupSeconds"] = self._warmup_seconds
+        cs = self.conflict_set
+        if hasattr(cs, "rebalance_from_conflicts"):
+            # the partition (parallel/sharded_conflict.py): every applied
+            # move of the cuts whatever planned it, the ranges that clipped
+            # non-empty summed over shards, a step's count on its busiest
+            # shard summed over steps, the fullest shard's boundaries
+            snap["CutRebalances"] = cs.rebalances
+            snap["ShardRangesOffered"] = cs.ranges_offered
+            snap["ShardRangesFullest"] = cs.ranges_fullest
+            snap["ShardBoundariesFullest"] = cs.fill_fullest
         snap.update(conflict.kernel_metrics.as_dict())
         snap.update(conflict.compile_cache_stats())
         snap.update(jaxenv.transfer_metrics.as_dict())
@@ -339,6 +357,10 @@ class Resolver:
                     # resolver so every later (already-dispatched or new)
                     # batch errors too; the proxy's pipeline failure then
                     # drives a recovery that builds a fresh conflict set
+                    if self._poisoned is None:
+                        from foundationdb_tpu.utils.trace import TraceEvent
+                        TraceEvent("ResolverPoisoned", self.process.address) \
+                            .detail("Version", req.version).error(err).log()
                     self._poisoned = err
                     reply.send_error(err)
                     continue
@@ -367,9 +389,8 @@ class Resolver:
             hot = self.hot_sketch.top_k(KNOBS.HOTSPOT_MAX_BUCKETS, now)
             if not hot:
                 continue
-            ranges = [(r.begin, r.end, r.rate) for r in hot]
-            if self.conflict_set.rebalance_from_conflicts(ranges):
-                self._c_rebalances.increment()
+            self.conflict_set.rebalance_from_conflicts(
+                [(r.begin, r.end, r.rate) for r in hot])
 
     def _advance_drained(self, seq: int):
         """Advance the drain-ordering gate to `seq` without ever moving it

@@ -114,11 +114,14 @@ KNOBS.init("CONFLICT_BACKEND", "device")  # "device" (JAX) | "sharded" (mesh) | 
 # and against the attached device count at engine construction.
 KNOBS.init("CONFLICT_NUM_SHARDS", 0, (1, 2))
 # resolutionBalancing analogue (masterserver.actor.cpp:955-1012): the sharded
-# engine re-cuts its key partition from sampled range begins when per-shard
-# load skews. Checked every N batches; rebalances when the hottest shard
-# carries > SKEW x the mean; needs MIN_SAMPLES sampled begins first.
+# engine re-cuts its key partition at quantiles of sampled whole begin keys
+# when per-shard load skews. Looked at every N steps (and at every step while
+# a shard nears its capacity); moves when the busiest shard is offered
+# > SKEW x the mean of the ranges counted since the last look, of which there
+# have to be MIN_SAMPLES. 1.25 on four shards is 31% for one shard: an even
+# partition reads 26-27% on its busiest shard by counting noise alone.
 KNOBS.init("RESOLUTION_BALANCE_CHECK_BATCHES", 64, (4,))
-KNOBS.init("RESOLUTION_BALANCE_SKEW", 2.0)
+KNOBS.init("RESOLUTION_BALANCE_SKEW", 1.25)
 KNOBS.init("RESOLUTION_BALANCE_MIN_SAMPLES", 2048, (32,))
 # Cross-epoch cut rebalancing: the resolver role feeds its HotRangeSketch
 # (per-range decayed conflict mass) into the sharded engine every EPOCH
