@@ -144,9 +144,9 @@ def _boot_cluster(tmp, backend="oracle", n_proxies=2, n_storage=2,
         # commit window directly divides conflict-engine load: 20ms windows
         # → ~50 steps/s ≈ 1.5 cores of XLA on a 1-core host (the r5
         # device-vs-oracle e2e inversion); 60ms windows → ~16 steps/s with
-        # 2-3 chunks each, which fits. The batcher is ADAPTIVE now: raising
-        # the MAX (not the MIN) lets it slide to 60ms windows only when the
-        # arrival rate saturates — light load still flushes at the fast MIN.
+        # 2-3 chunks each, which fits. MAX is only the cap on a batch's
+        # age: while a batch is at the resolver the next one fills, and it
+        # leaves when that one's verdicts are due (proxy.py:_flush_due).
         batch_knobs["COMMIT_TRANSACTION_BATCH_INTERVAL_MAX"] = 0.06
 
     p_core = f"127.0.0.1:{_free_port()}"

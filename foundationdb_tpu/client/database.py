@@ -1067,10 +1067,13 @@ class Database:
         if err is not None:
             return  # conflicts/timeouts say nothing about queueing
         floor = self._commit_lat_floor
-        # decaying min: snaps down to fast samples, drifts up slowly so a
-        # permanently shifted baseline (topology change) is re-learned
-        self._commit_lat_floor = latency if floor is None else min(
-            latency, floor + 0.02 * (latency - floor))
+        # the level commits have been running at: a smoothed mean that
+        # re-learns a shifted baseline (topology change) either way. Not the
+        # minimum: a commit that finds the resolver free skips a whole
+        # resolution, so the fastest one is several times below the rest
+        # with no queueing anywhere, and a ratio to it cut budgets on noise
+        self._commit_lat_floor = latency if floor is None else (
+            floor + 0.02 * (latency - floor))
         if (floor is not None
                 and latency > KNOBS.CLIENT_ADMISSION_LATENCY_RATIO * floor):
             self._cut_budget(now, latency)

@@ -153,6 +153,11 @@ class SimNetwork:
         self._next_token += 1
         return self._next_token
 
+    def input_waiting(self) -> bool:
+        """NetTransport parity: a simulated message is handled in the event
+        that delivers it, so none is ever here and unseen."""
+        return False
+
     # -- fault injection (sim2.actor.cpp:1190-1213, :133-179) --
     def clog_pair(self, a: str, b: str, seconds: float):
         until = self.loop.now() + seconds

@@ -30,6 +30,7 @@ See docs/native_transport.md for the token table and fallback contract.
 from __future__ import annotations
 
 import asyncio
+import select
 import struct
 import time
 
@@ -316,6 +317,10 @@ class NetTransport:
         # NEW connections, so these must be dropped explicitly or their
         # _on_connection read loops outlive the transport
         self._incoming: set[asyncio.StreamWriter] = set()
+        # what input_waiting() looks at: each incoming connection's reader,
+        # and its socket registered for a zero-timeout poll
+        self._in_readers: dict[int, asyncio.StreamReader] = {}
+        self._in_poll = select.poll()
         # transport counters (Python paths; the native plane keeps its own
         # and transport_counters() sums both)
         self._c_frames_in = 0
@@ -671,6 +676,11 @@ class NetTransport:
     async def _on_connection(self, reader: asyncio.StreamReader,
                              writer: asyncio.StreamWriter):
         self._incoming.add(writer)
+        # `reader` is rebound below when the native plane faults; what was
+        # registered is the stream's own reader, under the socket's number
+        fd, stream = writer.get_extra_info("socket").fileno(), reader
+        self._in_readers[fd] = stream
+        self._in_poll.register(fd, select.POLLIN)
         try:
             if self.tls is not None and not self._peer_ok(writer):
                 writer.close()
@@ -691,6 +701,9 @@ class NetTransport:
             return
         finally:
             self._incoming.discard(writer)
+            if self._in_readers.get(fd) is stream:  # not a later owner's
+                del self._in_readers[fd]
+                self._in_poll.unregister(fd)
             # the serve loop only exits on EOF or a protocol reject — in
             # both cases the drop decision must reach the TCP layer, or a
             # rejected peer hangs on recv() instead of seeing the close
@@ -751,6 +764,20 @@ class NetTransport:
                 # same decision as the Python loop — drop the connection.
                 # Replies queued earlier in this chunk were already written.
                 raise ConnectionError(err)
+
+    def input_waiting(self) -> bool:
+        """True while bytes a peer has sent are in this process and no
+        handler has seen them: read off a socket into a StreamReader whose
+        serve loop has not run yet (asyncio wakes it one iteration after
+        the read), or still in the kernel because the loop has not polled
+        since they came (a long callback). For a role that acts on a lull
+        in its requests: what its handlers have seen is what has arrived
+        only while this is False, whichever callback of an iteration runs
+        first. It answers for every incoming connection and for a frame
+        that is only partly here, so it can say True with nothing for the
+        asker in it; it never says False with a whole request unseen."""
+        return (any(r._buffer for r in self._in_readers.values())
+                or bool(self._in_poll.poll(0)))
 
     def transport_counters(self) -> dict:
         """Cumulative transport counters: Python paths + native plane."""

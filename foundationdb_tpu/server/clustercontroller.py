@@ -959,6 +959,8 @@ class ClusterController:
                 addrs.append(r.address)
                 self._incarnations[r.address] = r.incarnation
             except FDBError as e:
+                if e.name == "operation_cancelled":
+                    raise
                 raise FDBError("recruitment_failed",
                                f"{role} on {addr}: {e.name}") from None
         return addrs

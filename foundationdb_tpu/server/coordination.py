@@ -268,8 +268,9 @@ async def elect_leader(process: SimProcess, coordinators: list[str],
                                      lease_seconds=lease_seconds))
                 if r.leader == process.address:
                     votes += 1
-            except FDBError:
-                pass
+            except FDBError as e:
+                if e.name == "operation_cancelled":
+                    raise  # killed: a candidate must not outlive its process
         if votes >= quorum:
             return
         await net.loop.delay(poll_interval)

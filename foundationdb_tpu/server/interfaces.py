@@ -182,6 +182,11 @@ class ResolveTransactionBatchReply:
     # A proxy ANDs `locally_committed` across ALL resolvers' replies for the
     # global verdict (MasterProxyServer.actor.cpp:452-489).
     state_mutations: list = None
+    # host seconds the resolver spent on this batch before its step was
+    # launched on the device, which the step of the batch before can hide;
+    # 0 where the resolution is host work from end to end (the proxy's
+    # batcher reads it: server/proxy.py:_flush_due)
+    dispatch_s: float = 0.0
 
 
 # --- tlog ---

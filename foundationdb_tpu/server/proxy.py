@@ -45,6 +45,10 @@ from foundationdb_tpu.utils.trace import g_trace_batch
 from foundationdb_tpu.utils.types import (
     Mutation, MutationType, make_versionstamp, substitute_versionstamp)
 
+# a batcher deadline this close counts as reached: a timer may wake a
+# rounding error early, and virtual time does not advance by less
+_TIMER_SLACK = 1e-6
+
 
 @dataclass
 class ShardMap:
@@ -203,17 +207,24 @@ class Proxy:
         self.latest_logging = NotifiedVersion(0)
         self.committed_version = NotifiedVersion(recovery_version)
         self._pending: list[tuple[CommitTransactionRequest, object]] = []
-        self._batcher_armed = False
-        # adaptive batching state: smoothed commits-in rate keys the target
-        # flush interval; pending byte count feeds the BYTES_MIN trigger
+        # what closes the pending batch (_flush_due): its bytes so far, when
+        # the last commit request was handled, how many of this proxy's own
+        # batches are at the resolvers, how long the last one took from its
+        # flush to its verdicts and to the launch of its step, and the
+        # number of the pending batch's timer (an older timer wakes, finds
+        # it moved on, and ends)
         self._pending_bytes = 0
-        self._arrival_rate = 0.0
         self._last_arrival = self.loop.now()
+        self._resolving = 0
+        self._resolve_s: float | None = None
+        self._launch_s = 0.0
+        self._timer_gen = 0
         # bounded pipeline window: batches dispatched but not yet finished.
-        # _try_flush defers when the window is full; the draining batch
-        # re-flushes the deferred pending set when it completes.
+        # _try_flush defers when the window is full (and keeps the counter of
+        # the rule that closed the batch here); the draining batch re-flushes
+        # the deferred pending set when it completes.
         self._inflight_batches = 0
-        self._flush_blocked = False
+        self._flush_blocked = None
         self._master_last_seen = self.loop.now()
         self.stats = {"commits_in": 0, "committed": 0, "conflicts": 0, "too_old": 0}
         # latency bands + cross-process txn timeline probes (the reference's
@@ -229,6 +240,12 @@ class Proxy:
         self._c_grv_in = self.counters.counter("GRVIn")
         self._c_throttled = self.counters.counter("TxnThrottled")
         self._c_batches = self.counters.counter("CommitBatches")
+        # which rule closed each batch that carried requests (_flush_due)
+        self._c_flush_bytes = self.counters.counter("FlushBytes")
+        self._c_flush_count = self.counters.counter("FlushCount")
+        self._c_flush_idle = self.counters.counter("FlushIdle")
+        self._c_flush_drain = self.counters.counter("FlushDrain")
+        self._c_flush_cap = self.counters.counter("FlushCap")
         self._c_mutation_bytes = self.counters.counter("MutationBytes")
         self._assembly_t0: float | None = None
         self._infra_failures = 0
@@ -699,55 +716,112 @@ class Proxy:
                 f"{t.backoff:.6f} {t.begin.hex()} {t.end.hex()}"))
             return
         now_t = self.loop.now()
-        # smoothed commits-in rate (the commitBatcher's lastBatchIntervalRate
-        # feedback, collapsed to an explicit EWMA over interarrival gaps so
-        # the adaptive interval is a pure function of sim-deterministic state)
-        dt = max(now_t - self._last_arrival, 1e-6)
         self._last_arrival = now_t
-        alpha = KNOBS.COMMIT_BATCH_RATE_SMOOTHING
-        self._arrival_rate += alpha * (1.0 / dt - self._arrival_rate)
-        if not self._pending:
+        first = not self._pending
+        if first:
             self._assembly_t0 = now_t  # batch-assembly span start
         self._pending.append((req, reply, now_t))
         self._pending_bytes += sum(len(m.param1) + len(m.param2)
                                    for m in req.mutations)
-        if (len(self._pending) >= KNOBS.COMMIT_TRANSACTION_BATCH_COUNT_MAX
-                or self._pending_bytes
-                >= KNOBS.COMMIT_TRANSACTION_BATCH_BYTES_MIN):
-            self._try_flush()
-        elif not self._batcher_armed:
-            self._batcher_armed = True
-            self.process.spawn(self._batch_timer(), "commitBatcher")
+        if len(self._pending) >= KNOBS.COMMIT_TRANSACTION_BATCH_COUNT_MAX:
+            self._try_flush(self._c_flush_count)
+        elif self._pending_bytes >= KNOBS.COMMIT_TRANSACTION_BATCH_BYTES_MIN:
+            self._try_flush(self._c_flush_bytes)
+        elif first:
+            self._arm_timer()
 
-    def _target_interval(self) -> float:
-        """Arrival-rate-keyed flush interval: light load flushes at
-        INTERVAL_MIN (latency), and the interval slides linearly toward
-        INTERVAL_MAX as the smoothed rate approaches RATE_SATURATION
-        (amortizing per-batch pipeline cost under heavy load). The rate
-        is keyed CLUSTER-wide (per-proxy rate x pool size): the
-        per-batch downstream cost (master version fetch, resolver
-        dispatch, tlog push) lands on shared singleton roles, so a proxy
-        in a pool of n seeing 1/n of the load must batch as if it saw
-        the whole cluster's — otherwise fan-out re-fragments batches and
-        the shared roles pay n-fold per-batch overhead. BENCH_r08's
-        fan-out collapse (2 proxies, 0.53x writes) was exactly this.
-        The CAP stays at INTERVAL_MAX regardless of pool size: clients
-        run closed-loop against an admission budget, so commit
-        throughput is in-flight/latency and a stretched flush wait is
-        repaid as lost throughput, not saved work (measured in r10)."""
-        lo = KNOBS.COMMIT_TRANSACTION_BATCH_INTERVAL_MIN
-        hi = KNOBS.COMMIT_TRANSACTION_BATCH_INTERVAL_MAX
-        if hi <= lo:
-            return lo
-        n = max(1, self.n_proxies)
-        sat = max(1e-9, KNOBS.COMMIT_BATCH_RATE_SATURATION)
-        return lo + (hi - lo) * min(1.0, n * self._arrival_rate / sat)
+    def _flush_due(self):
+        """What time has to say about the pending batch: (0.0, the counter
+        of the rule that closes it now) or (seconds until there is reason
+        to look again, None). The byte and count rules are _on_commit's.
 
-    async def _batch_timer(self):
-        await self.loop.delay(self._target_interval())
-        self._batcher_armed = False
-        if self._pending:
-            self._try_flush()
+        A batch waits for two things, and the proxy can see both itself.
+        The resolver: a batch flushed while one of ours is still resolving
+        only queues behind that step, so while `_resolving` > 0 the batch
+        keeps filling, and _resolved() makes this test again the moment the
+        verdicts are back (FlushDrain): what arrived during a step leaves
+        as one batch. Its own companions: clients that were answered
+        together come back together, so with the resolver free the batch
+        lingers until no commit has arrived for INTERVAL_MIN (FlushIdle),
+        and no longer: waiting on a free resolver buys nothing. INTERVAL_MAX
+        since the batch's first arrival bounds the wait whatever the
+        resolver does (FlushCap).
+
+        Between a flush and the start of the step lie this proxy's version
+        fetch and the resolver's encode and launch, host work that the step
+        before can hide. So the last of our batches at the resolvers counts
+        as back that long before it is due (_resolver_free_in), and the
+        batch that leaves then (FlushDrain too) reaches the device as it
+        comes free instead of leaving it idle for a dispatch.
+
+        "Arrived" means handled by _on_commit, and after a long callback
+        requests can be in this process unhandled; a lull among the handled
+        ones is then no lull, so the pause test also asks the transport
+        (input_waiting), and looks again an INTERVAL_MIN later if it says so.
+
+        Every input is loop.now() or a count or a time this proxy keeps, so
+        the simulation stays deterministic. In a pool each proxy counts its
+        own batches only: a lower bound on the resolvers' queue (the
+        resolver's own depth, carried in its reply, is ROADMAP S2's
+        remainder)."""
+        now = self.loop.now()
+        cap_in = (self._assembly_t0
+                  + KNOBS.COMMIT_TRANSACTION_BATCH_INTERVAL_MAX - now)
+        if cap_in < _TIMER_SLACK:
+            return 0.0, self._c_flush_cap
+        free_in = self._resolver_free_in(now)
+        if free_in >= _TIMER_SLACK:
+            return min(free_in, cap_in), None
+        idle_in = (self._last_arrival
+                   + KNOBS.COMMIT_TRANSACTION_BATCH_INTERVAL_MIN - now)
+        if idle_in < _TIMER_SLACK:
+            if not self.process.net.input_waiting():
+                return 0.0, (self._c_flush_drain if self._resolving
+                             else self._c_flush_idle)
+            idle_in = KNOBS.COMMIT_TRANSACTION_BATCH_INTERVAL_MIN
+        return min(idle_in, cap_in), None
+
+    def _resolver_free_in(self, now: float) -> float:
+        """Seconds until a batch flushed now would no longer wait behind one
+        of ours: 0 with none at the resolvers; with one, the time its
+        verdicts are due (the last batch's flush-to-verdicts time after its
+        own flush) less the flush-to-launch time a new batch needs first;
+        unknown, so never, with two or more there or before the first
+        batch has been timed. A batch that is late is taken for back: the
+        cap bounds what that costs, as it bounds a resolver that is slow."""
+        if not self._resolving:
+            return 0.0
+        if self._resolving > 1 or self._resolve_s is None:
+            return float("inf")
+        return self._last_flush + self._resolve_s - self._launch_s - now
+
+    def _arm_timer(self):
+        self._timer_gen += 1
+        self.process.spawn(self._batch_timer(self._timer_gen), "commitBatcher")
+
+    async def _batch_timer(self, gen: int):
+        while gen == self._timer_gen and self._pending:
+            wait, rule = self._flush_due()
+            if rule is not None:
+                self._try_flush(rule)
+                return
+            await self.loop.delay(wait)
+
+    def _resolved(self):
+        """One of this proxy's batches is back from the resolvers, or failed
+        on the way: the pending batch is tested at once, and where it stays
+        a new timer takes over (the one armed at its first arrival sleeps
+        until what was due before this return)."""
+        self._resolving -= 1
+        if not self._pending:
+            return
+        _wait, rule = self._flush_due()
+        if rule is self._c_flush_idle:
+            rule = self._c_flush_drain
+        if rule is not None:
+            self._try_flush(rule)
+        else:
+            self._arm_timer()
 
     def _window(self) -> int:
         # COMMIT_PIPELINE_DEPTH bounds concurrent version batches through
@@ -758,22 +832,26 @@ class Proxy:
         # and tlogs.
         return max(1, KNOBS.COMMIT_PIPELINE_DEPTH // max(1, self.n_proxies))
 
-    def _try_flush(self):
-        """Flush unless the pipeline window is full; a deferred flush is
-        re-attempted when the draining batch completes."""
+    def _try_flush(self, rule):
+        """Flush, counting the rule that closed the batch, unless the
+        pipeline window is full; a deferred flush is re-attempted when the
+        draining batch completes."""
         if not self._pending:
             return
         if self._inflight_batches >= self._window():
-            self._flush_blocked = True
+            if self._flush_blocked is None:
+                self._flush_blocked = rule
             return
+        rule.increment()
         self._flush()
 
     def _flush(self):
         batch, self._pending = self._pending, []
         self._pending_bytes = 0
-        self._flush_blocked = False
+        self._flush_blocked = None
         self._batch_n += 1
         self._inflight_batches += 1
+        self._resolving += 1
         self._last_flush = self.loop.now()
         self._c_batches.increment()
         # the assembly span's begin time predates the batch id, so both
@@ -787,14 +865,15 @@ class Proxy:
                                    at=self._last_flush)
         self._assembly_t0 = None
         self.process.spawn(
-            self._commit_batch(self._batch_n, batch, t_arrival), "commitBatch")
+            self._commit_batch(self._batch_n, batch, t_arrival,
+                               self._last_flush), "commitBatch")
 
     def _batch_done(self):
         """Pipeline-window bookkeeping: a finished batch frees a slot and
         drains any flush that deferred while the window was full."""
         self._inflight_batches -= 1
-        if self._flush_blocked:
-            self._try_flush()
+        if self._flush_blocked is not None:
+            self._try_flush(self._flush_blocked)
 
     def _band_replies(self, t_ins):
         """Record commit latency per request, from RECEIPT (including the
@@ -807,10 +886,11 @@ class Proxy:
     # -- the 5-phase pipeline --
 
     async def _commit_batch(self, batch_n: int, batch,
-                            t_arrival: float | None = None):
+                            t_arrival: float | None, t_flush: float):
         requests = [req for req, _rep, _t in batch]
         replies = [rep for _req, rep, _t in batch]
         t_ins = [t for _req, _rep, t in batch]
+        at_resolver = True  # counted in _resolving since _flush
         resolution_started = False
         state_applied = False
         version_assigned = False
@@ -932,6 +1012,7 @@ class Proxy:
             # batch's phase 3 and resolution stays pipelined
             last_receive = self._last_applied_version
             _sb("Proxy.Resolve")
+            t_sent = now()
             resolve_futures = [
                 self.process.net.request(
                     self.process, self.resolvers.endpoints[r],
@@ -952,6 +1033,13 @@ class Proxy:
                 "Proxy.commitBatch.GettingCommitVersion", at=now())
             resolutions = await all_of(resolve_futures)
             _se("Proxy.Resolve")
+            if requests:
+                # what _resolver_free_in expects of the next batch
+                self._resolve_s = now() - t_flush
+                self._launch_s = t_sent - t_flush + max(
+                    r.dispatch_s for r in resolutions)
+            at_resolver = False
+            self._resolved()
             g_trace_batch.add_event(
                 "CommitDebug", bid,
                 "Proxy.commitBatch.AfterResolution", at=now())
@@ -1184,6 +1272,8 @@ class Proxy:
                     # transient TLog blip doesn't churn generations.
                     self.die(f"commit pipeline failing: {detail}")
         finally:
+            if at_resolver:
+                self._resolved()
             self._batch_done()
 
     def _substitute(self, m: Mutation, stamp: bytes) -> Mutation:

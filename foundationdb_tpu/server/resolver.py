@@ -263,11 +263,13 @@ class Resolver:
             # dispatch in version order, so the NEXT batch may dispatch as
             # soon as version advances; the verdict readback happens in the
             # drain loop without ever blocking dispatch.
+            t_dispatch = loop.now()
             with g_trace_batch.section("CommitSpan", vid, "Resolver.Dispatch",
                                        now=loop.now):
                 handle = cs.detect_async(req.transactions, req.version)
             self.version.set(req.version)
-            self._drain_pending.append((req, reply, handle))
+            self._drain_pending.append(
+                (req, reply, handle, loop.now() - t_dispatch))
             self._drain_wake.trigger()
             return
         with g_trace_batch.section("CommitSpan", vid, "Resolver.Dispatch",
@@ -296,7 +298,7 @@ class Resolver:
     async def _drain_group(self, seq: int, entries: list):
         from foundationdb_tpu.ops.conflict import drain_and_collect
         loop = self.process.net.loop
-        handles = [h for _req, _reply, h in entries]
+        handles = [h for _req, _reply, h, _d in entries]
         err = None
         results: list | None = None
         sharded = hasattr(self.conflict_set, "rebalance_from_conflicts")
@@ -326,7 +328,7 @@ class Resolver:
                     if wall > 0.0:
                         t_split = t_rb0 + (t_rb1 - t_rb0) * (
                             timing["drain_seconds"] / wall)
-                for req, _reply, _h in entries:
+                for req, _reply, _h, _d in entries:
                     vid = f"v{req.version}"
                     g_trace_batch.span_begin("CommitSpan", vid,
                                              "Resolver.ReadbackWait", at=t_rb0)
@@ -348,8 +350,8 @@ class Resolver:
             await self._drained_seq.when_at_least(seq - 1)
             if results is None:
                 results = [(None, None)] * len(entries)
-            for (req, reply, _handle), (statuses, herr) in zip(entries,
-                                                               results):
+            for (req, reply, _handle, dispatch_s), (statuses, herr) in zip(
+                    entries, results):
                 if err is None and herr is not None:
                     err = herr  # state overflow: fatal
                 if err is not None:
@@ -364,7 +366,7 @@ class Resolver:
                     self._poisoned = err
                     reply.send_error(err)
                     continue
-                self._finish_batch(req, reply, statuses)
+                self._finish_batch(req, reply, statuses, dispatch_s)
         finally:
             # The finally covers BOTH awaits: a cancel landing in
             # run_blocking or in the ordering wait must still advance the
@@ -403,7 +405,7 @@ class Resolver:
         self._drained_seq.when_at_least(seq - 1).add_callback(advance)
 
     def _finish_batch(self, req: ResolveTransactionBatchRequest, reply,
-                      statuses: list[int]):
+                      statuses: list[int], dispatch_s: float = 0.0):
         """Statuses-dependent bookkeeping + reply, strictly in version order
         (drain preserves dispatch order, so batch N's state txns are always
         recorded before batch N+1 assembles its catch-up window)."""
@@ -435,7 +437,8 @@ class Resolver:
                      for v, entries in sorted(self._recent_state_txns.items())
                      if req.last_receive_version < v < req.version]
         r = ResolveTransactionBatchReply(committed=statuses,
-                                         state_mutations=state_out)
+                                         state_mutations=state_out,
+                                         dispatch_s=dispatch_s)
         self._recent_replies[req.version] = r
         # prune: state txns below every proxy's received version; replies
         # outside the MVCC window (reference prunes by oldestProxyVersion,

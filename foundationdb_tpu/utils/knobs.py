@@ -84,6 +84,10 @@ KNOBS.init("MASTER_CSTATE_LEASE_SECONDS", 2.0)  # master self-deposition lease
 
 # --- Commit batching (fdbserver/Knobs.cpp:246-252, MasterProxyServer.actor.cpp:921) ---
 KNOBS.init("COMMIT_TRANSACTION_BATCH_COUNT_MAX", 32768, (1, 4))
+# What closes a commit batch is server/proxy.py:_flush_due. INTERVAL_MIN is
+# the linger: with no batch of the proxy's at the resolvers, a batch leaves
+# once no commit has arrived for this long. INTERVAL_MAX is the cap on a
+# batch's age whatever the resolvers are doing.
 KNOBS.init("COMMIT_TRANSACTION_BATCH_INTERVAL_MIN", 0.001, (0.1,))
 # INTERVAL_MAX sits deliberately ABOVE the time a saturated proxy takes to
 # fill a BYTES_MIN batch (~23ms at the e2e write mix), so under heavy load
@@ -95,13 +99,6 @@ KNOBS.init("COMMIT_TRANSACTION_BATCH_INTERVAL_MIN", 0.001, (0.1,))
 KNOBS.init("COMMIT_TRANSACTION_BATCH_INTERVAL_MAX", 0.025)
 KNOBS.init("COMMIT_TRANSACTION_BATCH_BYTES_MIN", 100_000)
 KNOBS.init("COMMIT_BATCH_IDLE_INTERVAL", 0.25)  # empty-batch keepalive
-# Adaptive batch sizing: the target flush interval slides from INTERVAL_MIN
-# toward INTERVAL_MAX as the smoothed commits-in rate approaches SATURATION
-# (MasterProxyServer.actor.cpp:921 commitBatcher's
-# COMMIT_TRANSACTION_BATCH_INTERVAL_* feedback, collapsed to an explicit
-# arrival-rate key so the sim stays deterministic).
-KNOBS.init("COMMIT_BATCH_RATE_SATURATION", 4000.0, (10.0,))  # commits/s at INTERVAL_MAX
-KNOBS.init("COMMIT_BATCH_RATE_SMOOTHING", 0.1)  # EWMA weight per arrival
 # Bounded window of concurrent version batches in the proxy commit pipeline:
 # resolve(N+1) overlaps tlog-push(N); 1 restores the serial pre-pipeline shape.
 KNOBS.init("COMMIT_PIPELINE_DEPTH", 4, (1,))
@@ -177,7 +174,7 @@ KNOBS.init("MAX_BACKOFF", 1.0)
 # Client-side commit admission control: AIMD budget on in-flight commits per
 # Database, so clients stop stuffing the proxy queue they are measuring.
 # Decrease fires on transaction_throttled and on commit latency inflating
-# past LATENCY_RATIO x the decaying-min baseline.
+# past LATENCY_RATIO x the level commits have been running at.
 KNOBS.init("CLIENT_COMMIT_MAX_IN_FLIGHT", 256)
 KNOBS.init("CLIENT_COMMIT_INITIAL_IN_FLIGHT", 32, (1,))
 KNOBS.init("CLIENT_ADMISSION_LATENCY_RATIO", 6.0)

@@ -145,3 +145,25 @@ def test_leader_election_converges_and_fails_over():
     loop.run_future(loop.spawn(observe()), max_time=120.0)
     assert seen["first"] == "worker:1"  # higher priority wins
     assert seen["second"] == "worker:2"  # failover after lease expiry
+
+
+def test_a_killed_candidate_stops_campaigning():
+    """A kill that lands while the candidate waits for a coordinator's
+    answer cancels it there; the campaign must end with its process and
+    not go on as an actor of no process, which a reboot would let win an
+    election with no lease-keeper beside it (a cluster controller that
+    locks every new generation's logs and is never deposed)."""
+    loop, net, coords = _mk()
+    w = net.new_process("worker:1")
+    for c in coords:  # the candidacy requests stay in flight
+        net.clog_pair("worker:1", c, 5.0)
+    task = w.spawn(elect_leader(w, coords, priority=1), "ccCandidate")
+
+    async def kill_then_wait():
+        await loop.delay(1.0)
+        net.kill("worker:1", KillType.KillProcess)
+        await loop.delay(10.0)
+
+    loop.run_future(loop.spawn(kill_then_wait()), max_time=60.0)
+    assert task.is_ready() and task.is_error()
+    assert task._result.name == "operation_cancelled"

@@ -455,3 +455,75 @@ def test_fail_pending_names_endpoint_and_cause():
     assert "10.0.0.8:4500" in err.detail
     assert "OSError" in err.detail and "connection refused" in err.detail
     assert 9 not in t._pending
+
+
+@pytest.mark.parametrize("native_on", ["0", "1", "fault"])
+def test_input_waiting_until_the_handler_has_seen_the_request(
+        monkeypatch, native_on):
+    """The commit batcher takes a lull in what it has handled for a lull in
+    what has arrived only if the transport holds nothing unseen. From the
+    moment a request's bytes are on this host until its handler runs —
+    first in the kernel, then in the connection's reader while the serve
+    loop waits for its turn — input_waiting() says so, on both serve
+    planes."""
+    from foundationdb_tpu.net import native_transport as nt
+    from foundationdb_tpu.net import transport as T
+    from foundationdb_tpu.net.transport import NetTransport, RealEventLoop
+    from foundationdb_tpu.utils import wire
+
+    if native_on == "fault":
+        # the plane faults on this connection's first bytes and the Python
+        # loop takes the stream over behind a _ResidueReader; what was
+        # registered for input_waiting() must still be taken out at the end
+        class Faulting:
+            def feed(self, chunk):
+                self.held = chunk
+                raise MemoryError("planted")
+
+            def residue(self):
+                return self.held
+        monkeypatch.setattr(nt, "new_conn", lambda table: Faulting())
+        native_on = "1"
+    monkeypatch.setenv("NET_NATIVE_TRANSPORT", native_on)
+    loop = RealEventLoop()
+    srv = NetTransport(loop, f"127.0.0.1:{free_port()}")
+    srv.start()
+    seen = []
+    srv.process.register(7, lambda payload, reply: seen.append(payload))
+
+    def turn():  # one iteration of the loop: poll, then what was ready
+        loop.aio.call_soon(loop.aio.stop)
+        loop.aio.run_forever()
+
+    host, port = srv.address.rsplit(":", 1)
+    sock = socket.create_connection((host, int(port)), timeout=5.0)
+    try:
+        sock.sendall(T._CONNECT)
+        for _ in range(20):
+            turn()
+            time.sleep(0.001)
+        assert not srv.input_waiting()
+        body = wire.dumps("x")
+        sock.sendall(T._HEADER.pack(len(body), 7, 1, T._REQUEST,
+                                    nt.crc32c(body)) + body)
+        time.sleep(0.02)  # the loopback's delivery
+        turns = 0
+        while not seen:
+            assert srv.input_waiting(), f"unseen after {turns} turns"
+            turn()
+            turns += 1
+            assert turns < 50
+        # the read and the serve loop's turn are separate iterations: both
+        # of the states named above were looked at
+        assert turns >= 2
+        assert seen == ["x"]
+        for _ in range(3):
+            turn()
+        assert not srv.input_waiting()
+    finally:
+        sock.close()
+        for _ in range(5):
+            turn()
+        srv.close()
+    assert not srv._in_readers
+    assert not srv.input_waiting()
