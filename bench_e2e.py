@@ -24,8 +24,7 @@ Topology (one OS process each):
 Latency percentiles are aggregated across workers by weighted averaging of
 per-worker percentiles (approximate, fine at bench granularity).
 
-Run standalone (`python bench_e2e.py [backend ...]`) for a JSON report, or
-via bench.py which folds the numbers into its one-line output.
+Run standalone (`python bench_e2e.py [backend ...]`) for a JSON report.
 """
 
 from __future__ import annotations

@@ -252,7 +252,7 @@ class _DevAnalysis:
         """Mark `info` as a direct trace target; traced params = positional
         params minus static ones. Keyword-only params count as static: in
         this codebase they are partial-bound or defaulted config (shapes,
-        intra_mode, ...), never runtime arrays."""
+        intra_rounds, ...), never runtime arrays."""
         args = info.node.args
         positional = [a.arg for a in args.posonlyargs + args.args]
         static = set(static_extra) | {a.arg for a in args.kwonlyargs}

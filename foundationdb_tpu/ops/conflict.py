@@ -100,11 +100,10 @@ jax.monitoring.register_event_listener(_on_jax_event)
 
 
 def compile_cache_stats() -> dict:
-    """In-process hits/misses across the jitted entry points (one miss per
-    distinct program this process asked for)."""
-    step, scan = _compiled_step.cache_info(), _compiled_scan.cache_info()
-    return {"CompileCacheHits": step.hits + scan.hits,
-            "CompileCacheMisses": step.misses + scan.misses}
+    """In-process hits/misses of the jitted step (one miss per distinct
+    program this process asked for)."""
+    step = _compiled_step.cache_info()
+    return {"CompileCacheHits": step.hits, "CompileCacheMisses": step.misses}
 
 L = keylib.NUM_LIMBS  # default key limbs (6 data + 1 length; see ConflictShapes.key_bytes)
 _NEG_INT = -(1 << 30)
@@ -116,6 +115,9 @@ _NEG_INT = -(1 << 30)
 # jnp expressions promote it exactly like the former device constant.
 NEG = _NEG_INT
 _REBASE_THRESHOLD = 1 << 29
+# host encode buffers kept per shape bucket (BatchEncoder._buffers): one being
+# encoded, up to three whose transfer or step may still be reading them
+ENCODE_RING = 4
 # the named_scopes inside conflict_step, in program order (what a profile's
 # operations are grouped by; docs/observability.md has the table)
 SCOPES = ("sort", "history", "intra", "merge", "gc", "table")
@@ -156,17 +158,6 @@ def install_profiler_annotator() -> None:
     device engines' constructors: only a process that builds one has a chip
     to profile, and utils/trace itself stays off JAX."""
     trace.set_annotator(_profiler_annotation)
-
-
-def _bulk_encode_at(keys: list[bytes], slots: list[int], out: np.ndarray, *,
-                    round_up: bool):
-    """Encode keys into out[:, slots[i]] (strided layout)."""
-    if not keys:
-        return
-    nl = out.shape[0]
-    tmp = np.empty((nl, len(keys)), dtype=np.uint32)
-    _bulk_encode(keys, tmp, round_up=round_up)
-    out[:, np.asarray(slots, dtype=np.int64)] = tmp[:, : len(keys)]
 
 
 def _bulk_encode(keys: list[bytes], out: np.ndarray, *, round_up: bool):
@@ -343,15 +334,6 @@ class ConflictShapes:
     reads: int  # NR: total read ranges per batch (flattened)
     writes: int  # NW: total write ranges per batch
     key_bytes: int = keylib.KEY_BYTES
-    # strided=True fixes the range->txn map at TRACE time: read slot j
-    # belongs to txn j // (reads//txns), write slot j to txn j // (writes//
-    # txns); unused slots are padded with empty ranges. Every per-txn fold
-    # (blocked reads -> txn, has_reads, commit -> writes) then compiles to a
-    # reshape-reduce instead of a data-dependent scatter/gather — the
-    # scatters cost ~0.5ms each on TPU and the intra-batch fixpoint pays one
-    # PER EVALUATION. Requires every txn to fit the stride (the encoder
-    # rejects oversized txns); the dynamic layout remains the default.
-    strided: bool = False
 
     def __post_init__(self):
         if self.key_bytes % 4 or not 4 <= self.key_bytes <= 64:
@@ -359,8 +341,6 @@ class ConflictShapes:
                 f"key_bytes must be a multiple of 4 in [4, 64], got "
                 f"{self.key_bytes} (the limb encoding is 4 bytes wide and "
                 f"the native encoder caps at 64)")
-        if self.strided and (self.reads % self.txns or self.writes % self.txns):
-            raise ValueError("strided layout needs reads/writes divisible by txns")
 
     @property
     def limbs(self) -> int:
@@ -469,8 +449,7 @@ def _run_sandwich(f, g, rounds: int):
     upper ⊇ truth ⊇ lower is invariant; each round tightens both by one
     dependency depth from each side, and rounds are skipped via lax.cond
     once the bounds pinch (so runtime tracks the batch's ACTUAL chain depth,
-    like the old while_loop, but the trip count — hence the jaxpr — is
-    bounded). rounds >= T//2 guarantees convergence for any batch; smaller
+    but the trip count — hence the jaxpr — is bounded). rounds >= T//2 guarantees convergence for any batch; smaller
     bounds report converged=False and the host wrapper finishes those txns
     exactly (DetectHandle.result). Returns (lower, upper, converged)."""
     upper = g
@@ -498,15 +477,11 @@ def _auto_rounds(T: int) -> int:
 
 
 def conflict_step(state: dict, batch: dict, *, shapes: ConflictShapes,
-                  max_write_life: int, ablate: str = "",
-                  intra_mode: str = "scan", intra_rounds: int = 0):
+                  max_write_life: int, intra_rounds: int = 0):
     """Pure function: (state, batch) -> (state', statuses, info). Jit-able.
 
-    intra_mode selects the intra-batch fixpoint evaluator: "scan" (default,
-    per-level sorted prefix scans, statically bounded sweeps) or "legacy"
-    (dense overlap matrix + unbounded while_loop — the pre-overhaul path,
-    kept for A/B verification). intra_rounds bounds the scan evaluator's
-    sandwich rounds (0 = auto, see _auto_rounds).
+    intra_rounds bounds the intra-batch evaluator's sandwich rounds (0 =
+    auto, see _auto_rounds).
 
     state:
       bkeys (L,K) uint32, non-decreasing over ALL K slots (nb live
@@ -547,17 +522,12 @@ def conflict_step(state: dict, batch: dict, *, shapes: ConflictShapes,
     # phase in every operation's metadata and changes nothing else: a
     # profile of the step program reads its device time by phase (SCOPES).
     with jax.named_scope("history"):
-        if shapes.strided:
-            # slot validity from the key itself: real keys never carry the
-            # 0xFFFFFFFF length limb the padding uses, so empty-but-real
-            # ranges (b == e) still count as "has reads" for the too-old rule
-            rvalid = rb[L - 1] != jnp.uint32(0xFFFFFFFF)
-            wvalid = wb[L - 1] != jnp.uint32(0xFFFFFFFF)
-            has_reads = rvalid.reshape(T, NR // T).any(axis=1)
-        else:
-            rvalid = rtxn < T
-            wvalid = wtxn < T
-            has_reads = (jnp.zeros(T + 1, bool).at[rtxn].max(rvalid))[:T]
+        # a slot's transaction number says whether it is used, so an
+        # empty-but-real range (b == e) still counts as "has reads" for the
+        # too-old rule
+        rvalid = rtxn < T
+        wvalid = wtxn < T
+        has_reads = (jnp.zeros(T + 1, bool).at[rtxn].max(rvalid))[:T]
 
     with jax.named_scope("sort"):
         # ---- 0. THE order of [state | rb | re | wb | we] ----
@@ -593,45 +563,27 @@ def conflict_step(state: dict, batch: dict, *, shapes: ConflictShapes,
         too_old = txn_valid & has_reads & (snapshot < oldest)
 
         # ---- 2. history check: range-max of step function vs snapshot ----
-        if ablate in ("no_hist", "only_merge"):
-            hist_conflict = jnp.zeros(T, bool)
-        else:
-            ub_rb = cum_state[spos_b[:NR]]        # #state keys <= rb
-            lb_re = cum_state[spos_b[NR:2 * NR]]  # #state keys < re
-            i0 = jnp.maximum(ub_rb - 1, 0)  # segment containing begin
-            i1 = lb_re  # first boundary >= end
-            nonempty = _key_lt(rb, re)
-            maxver = _range_max(table, i0, jnp.maximum(i1, i0 + 1))
-            rsnap = (jnp.repeat(snapshot, NR // T) if shapes.strided
-                     else snapshot[jnp.minimum(rtxn, T - 1)])
-            read_hits = rvalid & nonempty & (maxver > rsnap)
-            if shapes.strided:
-                hist_conflict = read_hits.reshape(T, NR // T).any(axis=1)
-            else:
-                hist_conflict = (jnp.zeros(T + 1, bool).at[rtxn].max(read_hits))[:T]
+        ub_rb = cum_state[spos_b[:NR]]        # #state keys <= rb
+        lb_re = cum_state[spos_b[NR:2 * NR]]  # #state keys < re
+        i0 = jnp.maximum(ub_rb - 1, 0)  # segment containing begin
+        i1 = lb_re  # first boundary >= end
+        nonempty = _key_lt(rb, re)
+        maxver = _range_max(table, i0, jnp.maximum(i1, i0 + 1))
+        rsnap = snapshot[jnp.minimum(rtxn, T - 1)]
+        read_hits = rvalid & nonempty & (maxver > rsnap)
+        hist_conflict = (jnp.zeros(T + 1, bool).at[rtxn].max(read_hits))[:T]
 
-        g0 = txn_valid & ~too_old & ~hist_conflict
-    if ablate in ("no_intra", "only_merge", "only_hist"):
-        commit = g0
-        statuses = jnp.where(
-            commit, COMMITTED,
-            jnp.where(too_old, TOO_OLD, CONFLICT)).astype(jnp.int32)
-        statuses = jnp.where(txn_valid, statuses, COMMITTED)
-        return _merge_phase(state, batch, statuses, commit, shapes,
-                            max_write_life, ablate, sort_products=(
-                                skeys, scls, sval, sidx, spos_b, cum_state),
-                            eligible=g0)
+        g = txn_valid & ~too_old & ~hist_conflict
     with jax.named_scope("intra"):
         # ---- 3. intra-batch: endpoint ranks -> overlap queries -> fixpoint ----
         # Endpoint ranks come from the step's order: rank = number of distinct
         # batch-endpoint key groups at-or-before this element, which is
         # order-isomorphic to the keys over batch endpoints (state elements
-        # interleave but contribute no rank). The default "scan" evaluator
-        # answers each sweep's "does a committed earlier txn's write overlap
-        # this read" with per-level prefix scans over sorted write endpoints
-        # (geometry built once per step, _intra_scan_levels) — O(n log n) per
-        # sweep with no n×n matrix materialized; the "legacy" evaluator is the
-        # pre-overhaul dense (NW, NR) int8 matvec + unbounded while_loop.
+        # interleave but contribute no rank). Each sweep's "does a committed
+        # earlier txn's write overlap this read" is answered with per-level
+        # prefix scans over sorted write endpoints (geometry built once per
+        # step, _intra_scan_levels) — O(n log n) per sweep with no n×n matrix
+        # materialized.
         newgrp = jnp.concatenate(
             [jnp.ones(1, bool), ~_key_eq(skeys[:, 1:], skeys[:, :-1])])
         cum_b_excl = cum_b - is_batch
@@ -647,89 +599,37 @@ def conflict_step(state: dict, batch: dict, *, shapes: ConflictShapes,
         # empty/inverted ranges (end <= begin) participate in neither side;
         # strict wtxn < rtxn = "earlier txns win" (checkIntraBatchConflicts
         # SkipList.cpp:1139-1152 processes in batch order)
-        g = g0
         wtxn_c = jnp.minimum(wtxn, T - 1)
         r_ok = rvalid & (rbr < rer)
         w_ok = wvalid & (wbr < wer)
+        levels = _intra_scan_levels(T, wtxn_c, rtxn, rbr, rer, wbr, wer)
 
-        def fold_reads(blocked_r):
-            if shapes.strided:
-                return blocked_r.reshape(T, NR // T).any(axis=1)
-            return (jnp.zeros(T + 1, bool).at[rtxn].max(blocked_r))[:T]
+        def _f_commit(c):
+            """f(c)[t] = g[t] and no committed-in-c earlier txn's write
+            overlaps any of t's reads."""
+            blocked_r = _intra_scan_blocked(c[wtxn_c] & w_ok, levels, rbr) & r_ok
+            return g & ~(jnp.zeros(T + 1, bool).at[rtxn].max(blocked_r))[:T]
 
-        if intra_mode == "legacy":
-            if shapes.strided:
-                order_ok = (
-                    (jnp.arange(NW, dtype=jnp.int32) // (NW // T))[:, None]
-                    < (jnp.arange(NR, dtype=jnp.int32) // (NR // T))[None, :])
-            else:
-                order_ok = wtxn[:, None] < rtxn[None, :]
-            overlap = ((wbr[:, None] < rer[None, :])
-                       & (rbr[None, :] < wer[:, None])
-                       & w_ok[:, None] & r_ok[None, :]
-                       & order_ok)  # (NW, NR)
-            # int8 halves the fixpoint's HBM traffic vs bf16 (the matrix read
-            # dominates each matvec); int8 x int8 -> int32 runs on the MXU
-            ovf = overlap.astype(jnp.int8)
-
-            def _f_commit(c):
-                """f(c)[t] = g[t] and no committed-in-c earlier txn's write
-                overlaps any of t's reads."""
-                cm = jnp.repeat(c, NW // T) if shapes.strided else c[wtxn_c]
-                cw = (cm & wvalid).astype(jnp.int8)
-                blocked_r = lax.dot_general(
-                    cw[None, :], ovf, (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.int32)[0] > 0
-                return g & ~fold_reads(blocked_r)
-
-            upper = g
-            lower = _f_commit(upper)
-
-            def cond(lu):
-                lower, upper = lu
-                return jnp.any(lower != upper)
-
-            def body(lu):
-                lower, upper = lu
-                upper2 = _f_commit(lower)
-                lower2 = _f_commit(upper2)
-                return lower2, upper2
-
-            lower, upper = body((lower, upper))
-            lower, upper = lax.while_loop(cond, body, (lower, upper))
-            commit = lower
-            merge_commit = commit
-            converged = jnp.asarray(True)
-        else:
-            levels = _intra_scan_levels(T, wtxn_c, rtxn, rbr, rer, wbr, wer)
-
-            def _f_commit(c):
-                cw = ((jnp.repeat(c, NW // T) if shapes.strided
-                       else c[wtxn_c]) & w_ok)
-                blocked_r = _intra_scan_blocked(cw, levels, rbr) & r_ok
-                return g & ~fold_reads(blocked_r)
-
-            rounds = intra_rounds if intra_rounds > 0 else _auto_rounds(T)
-            # statuses come from `lower` (⊆ truth: never a false commit) and the
-            # merge uses `upper` (⊇ truth: never a missing write in history);
-            # both are the truth itself whenever converged — always, for
-            # rounds >= T//2+1
-            commit, merge_commit, converged = _run_sandwich(_f_commit, g, rounds)
+        rounds = intra_rounds if intra_rounds > 0 else _auto_rounds(T)
+        # statuses come from `lower` (⊆ truth: never a false commit) and the
+        # merge uses `upper` (⊇ truth: never a missing write in history);
+        # both are the truth itself whenever converged — always, for
+        # rounds >= T//2+1
+        commit, merge_commit, converged = _run_sandwich(_f_commit, g, rounds)
 
         statuses = jnp.where(
             commit, COMMITTED,
             jnp.where(too_old, TOO_OLD, CONFLICT)).astype(jnp.int32)
         statuses = jnp.where(txn_valid, statuses, COMMITTED)
     return _merge_phase(state, batch, statuses, commit, shapes,
-                        max_write_life, ablate, sort_products=(
+                        max_write_life, sort_products=(
                             skeys, scls, sval, sidx, spos_b, cum_state),
                         merge_commit=merge_commit, converged=converged,
                         eligible=g)
 
 
 def _merge_phase(state, batch, statuses, commit, shapes, max_write_life,
-                 ablate="", sort_products=None, merge_commit=None,
-                 converged=None, eligible=None):
+                 sort_products, merge_commit, converged, eligible):
     T, NR, NW, K = shapes.txns, shapes.reads, shapes.writes, shapes.capacity
     L = shapes.limbs
     bkeys, bval, nb, oldest = (
@@ -738,22 +638,6 @@ def _merge_phase(state, batch, statuses, commit, shapes, max_write_life,
     vnew = batch["commit_version"]
     wvalid = wtxn < T
     wtxn_c = jnp.minimum(wtxn, T - 1)
-    if merge_commit is None:
-        merge_commit = commit
-    if converged is None:
-        converged = jnp.asarray(True)
-    if eligible is None:
-        eligible = commit
-
-    if ablate in ("no_merge", "only_hist"):
-        new_oldest = jnp.maximum(
-            oldest, jnp.where(batch["advance_floor"],
-                              vnew - jnp.int32(max_write_life), oldest))
-        new_state = dict(state, oldest=new_oldest.astype(jnp.int32))
-        info = {"overflow": state["poisoned"], "boundaries": nb,
-                "committed": jnp.sum(commit.astype(jnp.int32)),
-                "converged": converged, "eligible": eligible}
-        return new_state, statuses, info
 
     with jax.named_scope("merge"):
         # ---- 4. merge surviving writes into the step function at vnew ----
@@ -767,11 +651,7 @@ def _merge_phase(state, batch, statuses, commit, shapes, max_write_life,
         # mergeWriteConflictRanges SkipList.cpp:1260).
         skeys, scls, sval, sidx, spos_b, cum_state = sort_products
         N_ALL = K + 2 * NR + 2 * NW
-        if shapes.strided:
-            wvalid = wb[L - 1] != jnp.uint32(0xFFFFFFFF)
-            commit_w = jnp.repeat(merge_commit, NW // T)
-        else:
-            commit_w = merge_commit[wtxn_c]
+        commit_w = merge_commit[wtxn_c]
         # committed, non-empty writes only: an inverted range would inject a
         # reversed -1/+1 coverage delta and cancel other writes' coverage
         cw = wvalid & commit_w & _key_lt(wb, we)
@@ -859,7 +739,7 @@ def _merge_phase(state, batch, statuses, commit, shapes, max_write_life,
         out_vals = jnp.where(poisoned, pois_vals, out_vals)
         n2 = jnp.where(poisoned, 1, n2)
     with jax.named_scope("table"):
-        new_table = state["table"] if ablate == "no_table" else _build_table(out_vals)
+        new_table = _build_table(out_vals)
 
     new_state = {
         "bkeys": out_keys,
@@ -930,12 +810,12 @@ def _donate_state_argnums() -> tuple:
 
 @functools.lru_cache(maxsize=32)
 def _compiled_step(shapes: ConflictShapes, max_write_life: int,
-                   intra_mode: str = "scan", intra_rounds: int = 0):
-    """One compiled program per (shapes, window, intra config) — shared
+                   intra_rounds: int = 0):
+    """One compiled program per (shapes, window, sandwich rounds) — shared
     across instances."""
     return jax.jit(_named(functools.partial(
         conflict_step, shapes=shapes, max_write_life=max_write_life,
-        intra_mode=intra_mode, intra_rounds=intra_rounds), "conflict_step"),
+        intra_rounds=intra_rounds), "conflict_step"),
         donate_argnums=_donate_state_argnums())
 
 
@@ -949,40 +829,8 @@ def _compiled_rebase():
     return jax.jit(rebase_state, donate_argnums=_donate_state_argnums())
 
 
-def conflict_scan(state: dict, stacked: dict, *, shapes: ConflictShapes,
-                  max_write_life: int, intra_mode: str = "scan",
-                  intra_rounds: int = 0):
-    """Run M conflict batches in ONE device dispatch via lax.scan.
-
-    `stacked` has the same fields as a conflict_step batch with a leading
-    batch axis (M, ...). Returns (final_state, statuses (M, T) int8,
-    committed (M,) int32, overflow (M,) bool). Dispatch overhead (several ms
-    per program launch through the runtime) amortizes over M batches — the
-    device analogue of the proxy's pipelined commitBatch gating
-    (MasterProxyServer.actor.cpp:364-366).
-    """
-    def stepfn(st, batch):
-        st2, statuses, info = conflict_step(
-            st, batch, shapes=shapes, max_write_life=max_write_life,
-            intra_mode=intra_mode, intra_rounds=intra_rounds)
-        return st2, (statuses.astype(jnp.int8), info["committed"],
-                     info["overflow"])
-    final, (stat, comm, ovf) = lax.scan(stepfn, state, stacked)
-    return final, stat, comm, ovf
-
-
-@functools.lru_cache(maxsize=32)
-def _compiled_scan(shapes: ConflictShapes, max_write_life: int,
-                   intra_mode: str = "scan", intra_rounds: int = 0):
-    return jax.jit(_named(functools.partial(
-        conflict_scan, shapes=shapes, max_write_life=max_write_life,
-        intra_mode=intra_mode, intra_rounds=intra_rounds), "conflict_scan"),
-        donate_argnums=_donate_state_argnums())
-
-
 def _resolve_shapes(capacity=None, txns=None, reads_per_txn=None,
-                    writes_per_txn=None, key_bytes=None,
-                    strided=False) -> ConflictShapes:
+                    writes_per_txn=None, key_bytes=None) -> ConflictShapes:
     k = KNOBS
     t = txns or k.CONFLICT_BATCH_TXNS
     return ConflictShapes(
@@ -991,7 +839,6 @@ def _resolve_shapes(capacity=None, txns=None, reads_per_txn=None,
         reads=t * (reads_per_txn or k.CONFLICT_BATCH_READS_PER_TXN),
         writes=t * (writes_per_txn or k.CONFLICT_BATCH_WRITES_PER_TXN),
         key_bytes=key_bytes or keylib.KEY_BYTES,
-        strided=strided,
     )
 
 
@@ -1005,13 +852,6 @@ class BatchEncoder:
         self.base_version = base_version
         self._rings: dict = {}
         self._last_slot: dict | None = None
-        if shapes.strided:
-            self._strided_rtxn = jnp.asarray(
-                np.arange(shapes.reads, dtype=np.int32)
-                // (shapes.reads // shapes.txns))
-            self._strided_wtxn = jnp.asarray(
-                np.arange(shapes.writes, dtype=np.int32)
-                // (shapes.writes // shapes.txns))
 
     def _clamp_off(self, version: int) -> int:
         off = version - self.base_version
@@ -1023,8 +863,8 @@ class BatchEncoder:
         (its readback marker is_ready), so the encode output lands straight
         in long-lived host buffers instead of fresh allocations every batch
         — the host side of the dispatch/readback double-buffering. Slots are
-        created on demand up to CONFLICT_ENCODE_RING; if every slot is still
-        in flight the encode falls back to a fresh allocation (never blocks,
+        created on demand up to ENCODE_RING; if every slot is still in
+        flight the encode falls back to a fresh allocation (never blocks,
         never aliases an in-flight transfer)."""
         T = sh.txns
         ring = self._rings.setdefault((sh.reads, sh.writes), [])
@@ -1034,7 +874,7 @@ class BatchEncoder:
             if m is None or not hasattr(m, "is_ready") or m.is_ready():
                 slot = s
                 break
-        if slot is None and len(ring) < KNOBS.CONFLICT_ENCODE_RING:
+        if slot is None and len(ring) < ENCODE_RING:
             slot = {}
             ring.append(slot)
         if slot is None:
@@ -1046,16 +886,14 @@ class BatchEncoder:
             slot["we"] = np.empty((self.L, sh.writes), np.uint32)
             slot["snap"] = np.empty(T, np.int32)
             slot["valid"] = np.empty(T, bool)
-            if not sh.strided:
-                slot["rtxn"] = np.empty(sh.reads, np.int32)
-                slot["wtxn"] = np.empty(sh.writes, np.int32)
+            slot["rtxn"] = np.empty(sh.reads, np.int32)
+            slot["wtxn"] = np.empty(sh.writes, np.int32)
         for f in ("rb", "re", "wb", "we"):
             slot[f].fill(0xFFFFFFFF)
         slot["snap"].fill(0)
         slot["valid"].fill(False)
-        if not sh.strided:
-            slot["rtxn"].fill(T)
-            slot["wtxn"].fill(T)
+        slot["rtxn"].fill(T)
+        slot["wtxn"].fill(T)
         slot["marker"] = None
         self._last_slot = slot
         return slot
@@ -1097,11 +935,10 @@ class BatchEncoder:
         sh = shapes or self.shapes
         T = sh.txns
         assert len(txns) <= T
-        if not sh.strided:
-            from foundationdb_tpu import native
-            if native.available() and hasattr(native.mod,
-                                              "encode_conflict_ranges"):
-                return self._encode_batch_c(txns, commit_version, skip, sh)
+        from foundationdb_tpu import native
+        if native.available() and hasattr(native.mod,
+                                          "encode_conflict_ranges"):
+            return self._encode_batch_c(txns, commit_version, skip, sh)
         rkeys_b: list[bytes] = []
         rkeys_e: list[bytes] = []
         wkeys_b: list[bytes] = []
@@ -1110,7 +947,6 @@ class BatchEncoder:
         wt: list[int] = []
         buf = self._buffers(sh)
         snap, valid = buf["snap"], buf["valid"]
-        rpt, wpt = sh.reads // T, sh.writes // T
         for t, txn in enumerate(txns):
             if skip is not None and skip[t]:
                 continue  # host already decided TOO_OLD; not in this batch
@@ -1119,36 +955,20 @@ class BatchEncoder:
             # oversized txns were rejected by split_for_capacity (the gate on
             # the detect path — raising there happens before any chunk of the
             # logical batch touches device state)
-            for i, (b, e) in enumerate(txn.read_ranges):
+            for b, e in txn.read_ranges:
                 rkeys_b.append(b)
                 rkeys_e.append(e)
-                rt.append(t * rpt + i if sh.strided else t)
-            for i, (b, e) in enumerate(txn.write_ranges):
+                rt.append(t)
+            for b, e in txn.write_ranges:
                 wkeys_b.append(b)
                 wkeys_e.append(e)
-                wt.append(t * wpt + i if sh.strided else t)
+                wt.append(t)
 
         rb, re, wb, we = buf["rb"], buf["re"], buf["wb"], buf["we"]
         # Leaves stay HOST numpy (long-lived ring buffers, see _buffers):
         # the jitted step's implicit argument transfer is asynchronous and
         # batched (one enqueue), while an explicit device_put per leaf
         # costs a synchronous handshake each.
-        if sh.strided:
-            # ranges land at their txn's stride slots; rtxn/wtxn are implied
-            # by position and ignored by the kernel (cached device constants)
-            _bulk_encode_at(rkeys_b, rt, rb, round_up=False)
-            _bulk_encode_at(rkeys_e, rt, re, round_up=True)
-            _bulk_encode_at(wkeys_b, wt, wb, round_up=False)
-            _bulk_encode_at(wkeys_e, wt, we, round_up=True)
-            return {
-                "rb": rb, "re": re,
-                "rtxn": self._strided_rtxn,
-                "wb": wb, "we": we,
-                "wtxn": self._strided_wtxn,
-                "snapshot": snap, "txn_valid": valid,
-                "commit_version": np.int32(self._clamp_off(commit_version)),
-                "advance_floor": np.bool_(True),
-            }
         _bulk_encode(rkeys_b, rb, round_up=False)
         _bulk_encode(rkeys_e, re, round_up=True)
         _bulk_encode(wkeys_b, wb, round_up=False)
@@ -1190,19 +1010,6 @@ class BatchEncoder:
 
     def split_for_capacity(self, txns):
         sh = self.shapes
-        if sh.strided:
-            # capacity is per-txn (the stride); chunk by txn count only
-            rpt, wpt = sh.reads // sh.txns, sh.writes // sh.txns
-            for txn in txns:
-                if (len(txn.read_ranges) > rpt
-                        or len(txn.write_ranges) > wpt):
-                    raise FDBError(
-                        "transaction_too_large",
-                        f"{len(txn.read_ranges)} reads / "
-                        f"{len(txn.write_ranges)} writes exceed the strided "
-                        f"layout ({rpt}/{wpt} per txn)")
-            return [txns[i:i + sh.txns]
-                    for i in range(0, max(len(txns), 1), sh.txns)]
         subs, cur, nr, nw = [], [], 0, 0
         for txn in txns:
             tr, tw = len(txn.read_ranges), len(txn.write_ranges)
@@ -1281,12 +1088,9 @@ def detect_async_impl(engine, txns: list[TxnConflictInfo],
             # double-buffering: the D2H copy starts NOW, overlapped with the
             # NEXT chunk's/batch's encode + dispatch, so a later drain (or
             # result()) finds the bytes already on the host instead of
-            # starting the transfer under a sync.
-            # CONFLICT_READBACK_OVERLAP=False keeps the fully synchronous
-            # pre-overlap shape as a measurable ablation (decisions are
-            # identical either way — only timing shifts).
-            if (KNOBS.CONFLICT_READBACK_OVERLAP
-                    and hasattr(combined, "copy_to_host_async")):
+            # starting the transfer under a sync (a host-evaluated array has
+            # no such method).
+            if hasattr(combined, "copy_to_host_async"):
                 combined.copy_to_host_async()
         chunks.append((sub, host_too_old, combined))
     # the kernel's floor advance is replicated host-side exactly
@@ -1309,19 +1113,17 @@ class DeviceConflictSet:
 
     def __init__(self, capacity: int | None = None, txns: int | None = None,
                  reads_per_txn: int | None = None, writes_per_txn: int | None = None,
-                 oldest_version: int = 0, key_bytes: int | None = None,
-                 strided: bool = False):
+                 oldest_version: int = 0, key_bytes: int | None = None):
         install_profiler_annotator()
         self.shapes = _resolve_shapes(capacity, txns, reads_per_txn,
-                                      writes_per_txn, key_bytes, strided)
+                                      writes_per_txn, key_bytes)
         self.encoder = BatchEncoder(self.shapes, base_version=oldest_version)
         self.oldest_version = oldest_version
         self._state = init_state(self.shapes, oldest=0)
-        self._intra = (str(KNOBS.CONFLICT_INTRA_MODE),
-                       int(KNOBS.CONFLICT_INTRA_ROUNDS))
+        self._intra = int(KNOBS.CONFLICT_INTRA_ROUNDS)
         self._step = _compiled_step(self.shapes,
                                     KNOBS.MAX_WRITE_TRANSACTION_LIFE_VERSIONS,
-                                    *self._intra)
+                                    self._intra)
 
     @property
     def base_version(self) -> int:
@@ -1348,10 +1150,9 @@ class DeviceConflictSet:
         """(shapes, compiled step) for a chunk: bucketed padding keeps the
         transfer bytes and the device sort sized to the chunk, not to the
         configured maximum (see BatchEncoder.bucket_shapes)."""
-        shapes = (self.encoder.bucket_shapes(nr, nw)
-                  if not self.shapes.strided else self.shapes)
+        shapes = self.encoder.bucket_shapes(nr, nw)
         return shapes, _compiled_step(
-            shapes, KNOBS.MAX_WRITE_TRANSACTION_LIFE_VERSIONS, *self._intra)
+            shapes, KNOBS.MAX_WRITE_TRANSACTION_LIFE_VERSIONS, self._intra)
 
     def _bucket_programs(self):
         """(shapes, compiled step, an empty batch) of every serving bucket."""
@@ -1367,9 +1168,6 @@ class DeviceConflictSet:
     def warmup(self):
         """Compile every serving bucket now (boot-time cost, served-path
         savings; the persistent compile cache makes it once per machine)."""
-        if self.shapes.strided:
-            self.detect([], self.encoder.base_version + 1)
-            return
         for _shapes, step, batch in self._bucket_programs():
             new_state, statuses, _info = step(self._state, batch)
             self._state = new_state
@@ -1382,8 +1180,6 @@ class DeviceConflictSet:
         instruction and carries no op_name, so a reader of a profile needs
         this beside it. Compiles each program once more (from the persistent
         cache where there is one): for a traced run's warm-up only."""
-        if self.shapes.strided:
-            return
         for shapes, step, batch in self._bucket_programs():
             write_scope_map(
                 directory, shapes,
@@ -1429,17 +1225,15 @@ def drain_handles(handles: list["DetectHandle"]) -> None:
     enqueued first; the materializing np.asarray then finds the data already
     in flight, so N batches' readbacks cost ~one device round trip total
     instead of N. result() on each
-    handle afterwards touches no device state. This is the serving-path
-    analogue of conflict_scan's single-readback chaining: round-trip latency
-    is paid once per DRAIN, so resolver throughput is set by dispatch rate,
-    not round-trip time.
+    handle afterwards touches no device state: round-trip latency is paid
+    once per DRAIN, so resolver throughput is set by dispatch rate, not
+    round-trip time.
     """
     pend = [h for h in handles if h._result is None and h._chunks]
     arrs = [c[2] for h in pend for c in h._chunks]
-    if KNOBS.CONFLICT_READBACK_OVERLAP:
-        for a in arrs:
-            if hasattr(a, "copy_to_host_async"):
-                a.copy_to_host_async()
+    for a in arrs:
+        if hasattr(a, "copy_to_host_async"):
+            a.copy_to_host_async()
     for h in pend:
         h._chunks = [(sub, too_old, _status_to_host(a))
                      for sub, too_old, a in h._chunks]

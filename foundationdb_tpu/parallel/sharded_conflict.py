@@ -230,22 +230,18 @@ _STEP_CACHE: dict = {}
 
 
 def sharded_conflict_step(mesh: Mesh, shapes: ConflictShapes,  # noqa: C901
-                          max_write_life: int, intra_mode: str = "scan",
-                          intra_rounds: int = 0):
-    key = (tuple(mesh.devices.flat), shapes, max_write_life, intra_mode,
-           intra_rounds)
+                          max_write_life: int, intra_rounds: int = 0):
+    key = (tuple(mesh.devices.flat), shapes, max_write_life, intra_rounds)
     cached = _STEP_CACHE.get(key)
     if cached is not None:
         return cached
-    fn = _build_sharded_step(mesh, shapes, max_write_life, intra_mode,
-                             intra_rounds)
+    fn = _build_sharded_step(mesh, shapes, max_write_life, intra_rounds)
     _STEP_CACHE[key] = fn
     return fn
 
 
 def _build_sharded_step(mesh: Mesh, shapes: ConflictShapes,  # noqa: C901
-                        max_write_life: int, intra_mode: str = "scan",
-                        intra_rounds: int = 0):
+                        max_write_life: int, intra_rounds: int = 0):
     """Build the jitted SPMD step: (stacked_state, batch) -> (state', statuses, info).
 
     stacked_state: state pytree with a leading n_shards axis, sharded over the
@@ -273,7 +269,7 @@ def _build_sharded_step(mesh: Mesh, shapes: ConflictShapes,  # noqa: C901
                 batch["wb"], batch["we"], lo, hi)
         new_state, statuses, info = conflict_step(
             state, batch, shapes=shapes, max_write_life=max_write_life,
-            intra_mode=intra_mode, intra_rounds=intra_rounds)
+            intra_rounds=intra_rounds)
         new_state["lo"] = lo
         new_state["hi"] = hi
         with jax.named_scope("combine"):
@@ -380,11 +376,9 @@ class ShardedDeviceConflictSet:
         # "earlier txns win" + pmin does not reduce to, so the sharded
         # engine must always converge on device. The early-out cond makes
         # the unused rounds ~free once the bounds pinch.
-        intra_rounds = (self.shapes.txns // 2 + 1
-                        if str(KNOBS.CONFLICT_INTRA_MODE) == "scan" else 0)
         self._step = sharded_conflict_step(
             self.mesh, self.shapes, KNOBS.MAX_WRITE_TRANSACTION_LIFE_VERSIONS,
-            str(KNOBS.CONFLICT_INTRA_MODE), intra_rounds)
+            self.shapes.txns // 2 + 1)
         # resolutionBalancing's observations (module docstring)
         self._sample = np.zeros((LOAD_SAMPLE_ROWS, L), dtype=np.uint32)
         self._sample_rng = np.random.RandomState(0)

@@ -136,7 +136,7 @@ def readback_overlap_ratio(spans) -> float | None:
     hidden time, the resolver was dispatching other batches — while the
     ReadbackWait span itself is the exposed stall. hidden/(hidden+exposed)
     over all batches: 1.0 = readback fully overlapped with dispatch, 0.0 =
-    every copy is a synchronous stall (CONFLICT_READBACK_OVERLAP=False).
+    every copy is a synchronous stall.
     None when the trace carries no readback spans (oracle backend)."""
     dispatch_end: dict[str, float] = {}
     for s in spans:

@@ -12,8 +12,11 @@ candidate values, using the deterministic RNG so runs stay replayable.
 
 from __future__ import annotations
 
+import zlib
 from dataclasses import dataclass, field
 from typing import Any
+
+from foundationdb_tpu.utils.rng import DeterministicRandom
 
 
 @dataclass
@@ -54,10 +57,18 @@ class Knobs:
         knob draw is part of the environment a failing seed must replay
         (SimulatedCluster's per-seed knob randomization, flow/Knobs.cpp
         BUGGIFY pattern)."""
+        # One value from `rng`, then a stream of its own per knob, seeded
+        # from that value and the knob's name: what a seed does to one knob
+        # does not depend on which other knobs are registered. crc32 and not
+        # hash(), which differs from process to process.
+        base = rng.random_unique_id()
         drawn: dict[str, Any] = {}
         for k, d in sorted(self._defs.items()):
-            if d.extremes and rng.random() < probability:
-                drawn[k] = d.extremes[rng.randint(0, len(d.extremes) - 1)]
+            if not d.extremes:
+                continue
+            own = DeterministicRandom((base << 32) | zlib.crc32(k.encode()))
+            if own.random() < probability:
+                drawn[k] = own.random_choice(d.extremes)
         return drawn
 
     def buggify(self, rng, probability: float = 0.25) -> dict[str, Any]:
@@ -124,30 +135,14 @@ KNOBS.init("RESOLUTION_BALANCE_MIN_SAMPLES", 2048, (32,))
 # (per-range decayed conflict mass) into the sharded engine every EPOCH
 # seconds — conflict-mass-driven cuts on top of the load-sample path above.
 KNOBS.init("RESOLUTION_BALANCE_EPOCH_SECONDS", 5.0, (0.5,))
-# Double-buffered device readback (docs/conflict_kernel.md): batch N's D2H
-# verdict copy is started at dispatch and overlaps batch N+1's encode +
-# dispatch. False = fully synchronous readback (the pre-overlap shape, kept
-# as an ablation for the ReadbackWait residency bench and as a buggify axis:
-# decisions are identical, only timing shifts).
-KNOBS.init("CONFLICT_READBACK_OVERLAP", True, (False,))
 KNOBS.init("CONFLICT_STATE_CAPACITY", 1 << 16, (1 << 10,))  # boundary slots
 KNOBS.init("CONFLICT_BATCH_TXNS", 1024)  # static batch shape: txns
 KNOBS.init("CONFLICT_BATCH_READS_PER_TXN", 4)
 KNOBS.init("CONFLICT_BATCH_WRITES_PER_TXN", 4)
-# Intra-batch "earlier txns win" evaluator: "scan" = sorted per-level
-# prefix scans (O(n log n) per sweep, bounded sweep count, no while_loop in
-# the jaxpr); "legacy" = dense (NW, NR) overlap matrix + unbounded
-# while_loop fixpoint (kept for the CI A/B smoke test and as an escape
-# hatch). See docs/conflict_kernel.md.
-KNOBS.init("CONFLICT_INTRA_MODE", "scan", ("legacy",))
-# Sandwich sweep rounds for the scan evaluator; 0 = auto
+# Sandwich sweep rounds for the intra-batch evaluator; 0 = auto
 # (min(txns // 2 + 1, 32) — guaranteed-exact for txns <= 64, bounded with a
 # host-exact fallback beyond that; see conflict.py _run_sandwich).
 KNOBS.init("CONFLICT_INTRA_ROUNDS", 0, (1,))
-# Reusable host-side encode buffer ring (double-buffering the dispatch path:
-# batch N+1 encodes into a different slot than the one batch N's transfer may
-# still be reading). 0 disables pooling.
-KNOBS.init("CONFLICT_ENCODE_RING", 4, (0,))
 # What the device/sharded backend serves with when bound_device_discovery()
 # finds NO accelerator (probe timeout / JAX_PLATFORMS=cpu): "host" = the
 # exact host evaluator (ops/conflict_oracle.py, the semantic authority —

@@ -3,7 +3,8 @@
 Tests run on a virtual 8-device CPU mesh (multi-chip hardware is not available
 in CI): JAX_PLATFORMS=cpu + xla_force_host_platform_device_count=8 must be set
 before jax is imported anywhere, hence the env mutation at module import time.
-bench.py and __graft_entry__.py do NOT import this — they run on real TPU.
+chip_smoke.py, benchmark/run.py and __graft_entry__.py do NOT import this —
+they run on real TPU.
 """
 
 import os

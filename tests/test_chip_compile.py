@@ -70,7 +70,7 @@ def _step_args(shapes, sharding):
 def _donated_step(shapes, device):
     step = jax.jit(functools.partial(
         C.conflict_step, shapes=shapes, max_write_life=WINDOW,
-        intra_mode="scan", intra_rounds=0), donate_argnums=(0,))
+        intra_rounds=0), donate_argnums=(0,))
     return step.lower(
         *_step_args(shapes, SingleDeviceSharding(device))).compile()
 
@@ -135,8 +135,7 @@ def test_sharded_step_on_four_device_mesh(topo, monkeypatch):
     monkeypatch.setattr(C, "_donate_state_argnums", lambda: (0,))
     mesh = Mesh(np.asarray(topo.devices[:4]), (S.RESOLVER_AXIS,))
     shapes = ConflictShapes(capacity=1024, txns=16, reads=32, writes=32)
-    step = S._build_sharded_step(mesh, shapes, WINDOW, "scan",
-                                 shapes.txns // 2 + 1)
+    step = S._build_sharded_step(mesh, shapes, WINDOW, shapes.txns // 2 + 1)
     state = _specs(S.init_sharded_state(shapes, 4),
                    NamedSharding(mesh, P(S.RESOLVER_AXIS)))
     batch = _specs(BatchEncoder(shapes).encode_batch([], 1, shapes=shapes),

@@ -6,6 +6,8 @@ naive implementation): generate randomized batches, run both engines, assert
 byte-identical abort decisions.
 """
 
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -171,11 +173,20 @@ def _random_range(rng, space):
     return (min(a, b), max(a, b))
 
 
-@pytest.mark.parametrize("seed", [1, 2, 3, 4])
-def test_randomized_parity(seed):
+@pytest.mark.parametrize("seed,chunked", [
+    (1, False), (2, False), (3, False), (4, False),
+    (1, True), (2, True), (3, True),
+], ids=["1", "2", "3", "4", "chunked-1", "chunked-2", "chunked-3"])
+def test_randomized_parity(seed, chunked):
+    """Decisions identical to the oracle under heavy contention. The
+    `chunked` cases run an 8-transaction shape, so that a logical batch of up
+    to 20 is cut into several chunks, with transactions of zero ranges and
+    one read in ten an empty real range (b == e), which must still count
+    for too-old."""
     KNOBS.set("MAX_WRITE_TRANSACTION_LIFE_VERSIONS", 500)
     rng = DeterministicRandom(seed)
-    dev = small_device_set()
+    dev = (small_device_set(txns=8, reads_per_txn=3, writes_per_txn=3)
+           if chunked else small_device_set())
     oracle = OracleConflictSet()
     # small key space -> heavy contention
     space = [bytes([97 + i]) + bytes([97 + j]) for i in range(6) for j in range(6)]
@@ -183,48 +194,33 @@ def test_randomized_parity(seed):
     for _batch in range(25):
         version += rng.randint(1, 300)
         txns = []
-        for _ in range(rng.randint(1, 30)):
+        for _ in range(rng.randint(1, 20 if chunked else 30)):
             snap = max(0, version - rng.randint(0, 800))
             reads = [_random_range(rng, space) for _ in range(rng.randint(0, 3))]
             writes = [_random_range(rng, space) for _ in range(rng.randint(0, 3))]
-            txns.append(txn(snap, reads, writes))
-        check(dev, oracle, txns, version)
-
-
-@pytest.mark.parametrize("seed", [1, 2, 3])
-def test_randomized_parity_strided(seed):
-    """The strided layout (static range->txn map; bench.py's configuration)
-    must make decisions identical to the oracle — including txns with zero
-    ranges, empty (b == e) read ranges (which still count for too-old), and
-    chunking across multiple sub-batches."""
-    KNOBS.set("MAX_WRITE_TRANSACTION_LIFE_VERSIONS", 500)
-    rng = DeterministicRandom(seed)
-    dev = small_device_set(txns=8, reads_per_txn=3, writes_per_txn=3,
-                           strided=True)
-    oracle = OracleConflictSet()
-    space = [bytes([97 + i]) + bytes([97 + j]) for i in range(6) for j in range(6)]
-    version = 0
-    for _batch in range(25):
-        version += rng.randint(1, 300)
-        txns = []
-        for _ in range(rng.randint(1, 20)):  # > txns shape -> chunking
-            snap = max(0, version - rng.randint(0, 800))
-            reads = [_random_range(rng, space) for _ in range(rng.randint(0, 3))]
-            writes = [_random_range(rng, space) for _ in range(rng.randint(0, 3))]
-            if rng.randint(0, 9) == 0 and reads:
+            if chunked and rng.randint(0, 9) == 0 and reads:
                 reads[0] = (reads[0][0], reads[0][0])  # empty real range
             txns.append(txn(snap, reads, writes))
         check(dev, oracle, txns, version)
 
 
-def test_strided_rejects_oversized_txn():
+def test_oversized_txn_rejected():
+    """A transaction with more ranges than the whole batch shape is refused
+    by split_for_capacity before any chunk of its logical batch touches the
+    state: the write that came in the same batch never lands, and the set
+    answers the next batch as an oracle that never saw the refused one."""
     from foundationdb_tpu.utils.errors import FDBError
-    dev = small_device_set(txns=4, reads_per_txn=2, writes_per_txn=2,
-                           strided=True)
-    big = txn(0, reads=[(bytes([97 + i]), bytes([98 + i])) for i in range(3)])
-    with pytest.raises(FDBError) as ei:
-        dev.detect([big], 100)
-    assert ei.value.name == "transaction_too_large"
+    dev = small_device_set(txns=4, reads_per_txn=2, writes_per_txn=2)
+    oracle = OracleConflictSet()
+    check(dev, oracle, [txn(0, writes=[(b"a", b"b")])], 100)
+    ranges = [(bytes([97 + i]), bytes([98 + i])) for i in range(9)]
+    for big in (txn(0, reads=ranges), txn(0, writes=ranges)):
+        with pytest.raises(FDBError) as ei:
+            dev.detect([txn(0, writes=[(b"m", b"n")]), big], 200)
+        assert ei.value.name == "transaction_too_large"
+    s = check(dev, oracle, [txn(150, reads=[(b"m", b"n")]),
+                            txn(50, reads=[(b"a", b"b")])], 300)
+    assert s == [COMMITTED, CONFLICT]
 
 
 @pytest.mark.parametrize("seed", [11, 12])
@@ -468,17 +464,19 @@ def _fuzz_txn(rng, version):
     return txn(snap, reads, writes)
 
 
-@pytest.mark.parametrize("seed", [31, 32, 33, 34])
-def test_deep_parity_fuzz(seed):
-    """>= 1000 batches across the seed set (4 x 260), one long-lived engine
-    pair per seed (state carries across batches: history-vs-intra interplay
-    is the hard part of the scan kernel)."""
+@pytest.mark.parametrize("seed,batches", [
+    (31, 260), (32, 260), (33, 260), (34, 260), (55, 20)],
+    ids=["31", "32", "33", "34", "short-55"])
+def test_deep_parity_fuzz(seed, batches):
+    """>= 1000 batches across the seed set (4 x 260, and one short stream
+    of 20), one long-lived engine pair per seed (state carries across
+    batches: history-vs-intra interplay is the hard part of the kernel)."""
     KNOBS.set("MAX_WRITE_TRANSACTION_LIFE_VERSIONS", 600)
     rng = DeterministicRandom(seed)
     dev = small_device_set()
     oracle = OracleConflictSet()
     version = 0
-    for _batch in range(260):
+    for _batch in range(batches):
         version += rng.randint(1, 250)
         txns = [_fuzz_txn(rng, version) for _ in range(rng.randint(1, 24))]
         check(dev, oracle, txns, version)
@@ -533,13 +531,15 @@ def _assert_constructed_order(state, batch, shapes):
     np.testing.assert_array_equal(np.asarray(cum_b), np.cumsum(want >= K))
 
 
-def _order_engine(**kw):
-    """(shapes, encoder, jitted step, fresh state) at a small shape. The
-    step waits for its result: the encoder hands out the same host buffers
-    again, and a dispatch still reading them must not see the next batch."""
+def _order_engine(reads_div=1, **kw):
+    """(shapes, encoder, jitted step, fresh state) at a small shape, its
+    reads cut to a `reads_div`th (a one-sided bucket). The step waits for
+    its result: the encoder hands out the same host buffers again, and a
+    dispatch still reading them must not see the next batch."""
     import jax
     from foundationdb_tpu.ops import conflict as C
     shapes = C._resolve_shapes(**kw)
+    shapes = dataclasses.replace(shapes, reads=shapes.reads // reads_div)
     compiled = C._compiled_step(shapes, 1000)
 
     def step(state, batch):
@@ -637,10 +637,13 @@ def test_constructed_order_tie_cases(case):
 
 @pytest.mark.parametrize("seed,kw", [
     (41, {}), (42, {}), (43, {}),
-    (44, {"strided": True}), (45, {"strided": True}),
+    # a one-sided bucket (reads = full/16, writes = full), as bucket_shapes
+    # hands a write-only workload's batches
+    (44, {"reads_per_txn": 8, "bucket": True}),
+    (45, {"reads_per_txn": 8, "bucket": True}),
     (46, {"key_bytes": 8}), (47, {"key_bytes": 8}),
     (48, {"capacity": 16, "txns": 8}),  # M = 64 > K; overflows on the way
-], ids=["dynamic-41", "dynamic-42", "dynamic-43", "strided-44", "strided-45",
+], ids=["dynamic-41", "dynamic-42", "dynamic-43", "bucket-44", "bucket-45",
         "key_bytes8-46", "key_bytes8-47", "m_over_k-48"])
 def test_constructed_order_random(seed, kw):
     """Random states (whatever the step itself left behind: merged, window
@@ -648,7 +651,8 @@ def test_constructed_order_random(seed, kw):
     stable sort's, before every step."""
     kw = {"capacity": 256, "txns": 16, "reads_per_txn": 2,
           "writes_per_txn": 2, **kw}
-    shapes, enc, step, state = _order_engine(**kw)
+    reads_div = 16 if kw.pop("bucket", False) else 1
+    shapes, enc, step, state = _order_engine(reads_div=reads_div, **kw)
     rng = DeterministicRandom(seed)
     space = [b"k%02d" % i for i in range(40)] + [b"", b"k07\x00", b"\xff"]
     version = 0
@@ -660,39 +664,23 @@ def test_constructed_order_random(seed, kw):
                     [_random_range(rng, space)
                      for _ in range(rng.randint(0, 2))])
                 for _ in range(rng.randint(0, kw["txns"]))]
+        room = shapes.reads  # the bucket holds few reads; the rest go
+        for t in txns:
+            t.read_ranges = t.read_ranges[:room]
+            room -= len(t.read_ranges)
         batch = enc.encode_batch(txns, version)
         _assert_constructed_order(state, batch, shapes)
         state, _, _ = step(state, batch)
 
 
 # ---------------------------------------------------------------------------
-# CI smoke: the scan kernel vs the legacy fixpoint kernel (A/B on the knob),
-# and the serving jaxpr contains NO unbounded while_loop
+# the serving jaxpr contains NO unbounded while_loop
 # ---------------------------------------------------------------------------
-
-def test_scan_matches_legacy_kernel():
-    KNOBS.set("MAX_WRITE_TRANSACTION_LIFE_VERSIONS", 600)
-    KNOBS.set("CONFLICT_INTRA_MODE", "legacy")
-    legacy = small_device_set()
-    KNOBS.set("CONFLICT_INTRA_MODE", "scan")
-    scan = small_device_set()
-    oracle = OracleConflictSet()
-    rng = DeterministicRandom(55)
-    version = 0
-    for _batch in range(20):
-        version += rng.randint(1, 250)
-        txns = [_fuzz_txn(rng, version) for _ in range(rng.randint(1, 24))]
-        a = legacy.detect(txns, version)
-        b = scan.detect(txns, version)
-        want = oracle.detect(txns, version)
-        assert a == b == want, (a, b, want)
-
 
 def test_serving_jaxpr_has_no_while_loop():
     """The tentpole's structural guarantee: the serving detect path lowers to
     bounded control flow only (scan/cond) — an unbounded `while` primitive
-    would reintroduce the data-dependent fixpoint the overhaul removed. The
-    legacy escape hatch, by contrast, must still carry its while_loop."""
+    would reintroduce the data-dependent fixpoint the overhaul removed."""
     import jax
     from foundationdb_tpu.ops import conflict as C
     dev = small_device_set()
@@ -701,13 +689,8 @@ def test_serving_jaxpr_has_no_while_loop():
         [txn(0, reads=[(b"a", b"b")], writes=[(b"c", b"d")])], 100)
     life = KNOBS.MAX_WRITE_TRANSACTION_LIFE_VERSIONS
 
-    def step(mode):
-        return str(jax.make_jaxpr(
-            lambda s, b: C.conflict_step(s, b, shapes=dev.shapes,
-                                         max_write_life=life,
-                                         intra_mode=mode))(state, batch))
-
-    serving = step("scan")
+    serving = str(jax.make_jaxpr(
+        lambda s, b: C.conflict_step(s, b, shapes=dev.shapes,
+                                     max_write_life=life))(state, batch))
     assert "while[" not in serving, "unbounded fixpoint back in serving path"
     assert "scan[" in serving  # the bounded sandwich is there
-    assert "while[" in step("legacy")

@@ -1,5 +1,6 @@
 """The measured CPU baseline (native/skiplist_baseline.c) must keep
-building and producing sane numbers — bench.py divides by it."""
+building and producing sane numbers — a kernel-only cell of the benchmark
+would divide by it (PERF.md §7; no cell does yet)."""
 
 import json
 import os

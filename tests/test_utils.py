@@ -72,6 +72,31 @@ def test_knobs_buggify_deterministic():
     assert dict(KNOBS._values) == snap1
 
 
+def test_buggified_draw_ignores_the_rest_of_the_registry():
+    """What a seed does to one knob depends on the seed and the knob's name,
+    not on which other knobs are registered: deleting or adding a knob must
+    not re-roll every simulation seed."""
+    from foundationdb_tpu.utils.knobs import Knobs
+    names = [f"KNOB_{c}" for c in "BCDEFGHIJKLMNOPQ"]
+
+    def draws(extra: dict) -> list[dict]:
+        bank = Knobs()
+        for n in names:
+            bank.init(n, 0, (1, 2, 3))
+        for n, extremes in extra.items():
+            bank.init(n, 0, extremes)
+        return [bank.draw_buggified(DeterministicRandom(seed), 0.5)
+                for seed in range(40)]
+
+    alone = draws({})
+    assert any(alone) and len({tuple(sorted(d.items())) for d in alone}) > 20
+    # a knob that sorts before all of them, one in their middle, and one
+    # that declares no extremes
+    crowded = draws({"KNOB_A": (7, 8), "KNOB_HH": (9,), "KNOB_0": ()})
+    for was, now in zip(alone, crowded):
+        assert {k: v for k, v in now.items() if k in names} == was
+
+
 def test_rng_determinism():
     a, b = DeterministicRandom(42), DeterministicRandom(42)
     assert [a.randint(0, 100) for _ in range(10)] == [b.randint(0, 100) for _ in range(10)]
