@@ -16,8 +16,7 @@ detect = ONE jitted function built around ONE order of
 The state's boundaries are kept sorted from step to step, so the order is
 built, not found: the batch's endpoints alone are sorted, each is ranked in
 the state by a fixed-trip bisection, and the two sorted runs are interleaved
-by those ranks (_merged_order; the chip figures are in conflict_step's
-docstring):
+by those ranks (_merged_order):
   1. too-old filter (SkipList.cpp:985 semantics)
   2. history check: each read endpoint's rank among state boundaries comes
      from that order; O(1) sparse-table range-max over the segment versions,
@@ -258,8 +257,9 @@ def _merged_order(bkeys, bk, bcls):
       sidx    (K + M,) original index of the element at each sorted position
       bpos    (M,)     sorted position of each batch element (the inverse
                        permutation past the state's K entries)
-      cum_b   (K + M,) inclusive count of batch elements (sidx >= K) up to
-                       each position
+
+    and of the sorted batch, M wide: `bperm` the element at each place, `q`
+    their keys, `rank` the state keys before each, `p` its sorted position.
 
     Ties: equal batch elements keep index order (the M-wide sort is stable);
     class 0 ranks by lower bound and so lands before equal state keys,
@@ -269,7 +269,8 @@ def _merged_order(bkeys, bk, bcls):
     M = bk.shape[1]
     bperm = _lex_sort_perm(
         jnp.concatenate([bk, bcls.astype(jnp.uint32)[None]]))
-    rank = _rank_in_sorted(bkeys, bk[:, bperm], bcls[bperm] == 0)
+    q = bk[:, bperm]
+    rank = _rank_in_sorted(bkeys, q, bcls[bperm] == 0)
     # sorted batch element j has `rank` state keys and j batch elements
     # before it: strictly increasing positions
     p = rank + jnp.arange(M, dtype=jnp.int32)
@@ -277,11 +278,42 @@ def _merged_order(bkeys, bk, bcls):
     src_b = jnp.full(K + M, -1, jnp.int32).at[p].set(
         K + bperm, indices_are_sorted=True, unique_indices=True)
     is_batch = src_b >= 0
-    cum_b = jnp.cumsum(is_batch.astype(jnp.int32))
     # a state slot keeps its index less the batch elements before it
     sidx = jnp.where(
-        is_batch, src_b, jnp.arange(K + M, dtype=jnp.int32) - cum_b)
-    return sidx, bpos, cum_b
+        is_batch, src_b, jnp.arange(K + M, dtype=jnp.int32)
+        - jnp.cumsum(is_batch.astype(jnp.int32)))
+    return sidx, bpos, bperm, q, rank, p
+
+
+def _batch_key_ranks(q, bperm):
+    """Which sorted batch keys differ from the one before, and each batch
+    element's rank: the count of distinct batch keys below its own."""
+    q_new = jnp.concatenate(
+        [jnp.ones(1, bool), ~_key_eq(q[:, 1:], q[:, :-1])])
+    return q_new, jnp.zeros(q.shape[1], jnp.int32).at[bperm].set(
+        jnp.cumsum(q_new.astype(jnp.int32)) - 1, unique_indices=True)
+
+
+def _group_starts(bkeys, nb, sidx, q, q_new, rank, p):
+    """(K + M,) bool: the key at each merged position differs from the one
+    before it, without the keys in that order. Live state keys are distinct
+    and below the padding: behind a state row, slot s opens a group iff
+    s <= nb; only a batch row and the state row behind one compare keys."""
+    K = bkeys.shape[1]
+    pad_opens = ~jnp.all(  # (only a raw batch can make the padding's key live)
+        bkeys[:, jnp.maximum(nb - 1, 0)] == jnp.uint32(0xFFFFFFFF))
+    newgrp = (sidx < nb) | ((sidx == nb) & pad_opens)
+    # before a batch row: the one it shares a rank with, else slot rank - 1
+    same = jnp.concatenate([jnp.zeros(1, bool), rank[1:] == rank[:-1]])
+    b_open = jnp.where(
+        same, q_new,
+        (rank == 0) | ~_key_eq(q, bkeys[:, jnp.maximum(rank - 1, 0)]))
+    # state slot `rank` comes right behind the last batch row of that rank
+    last = (rank < K) & ~jnp.concatenate([same[1:], jnp.zeros(1, bool)])
+    s_open = ~_key_eq(q, bkeys[:, jnp.minimum(rank, K - 1)])
+    return newgrp.at[p].set(
+        b_open, indices_are_sorted=True, unique_indices=True
+    ).at[jnp.where(last, p + 1, p)].set(jnp.where(last, s_open, b_open))
 
 
 # ---------------------------------------------------------------------------
@@ -484,8 +516,8 @@ def conflict_step(state: dict, batch: dict, *, shapes: ConflictShapes,
     auto, see _auto_rounds).
 
     state:
-      bkeys (L,K) uint32, non-decreasing over ALL K slots (nb live
-      boundaries, then all-0xFFFFFFFF padding, the largest key);
+      bkeys (L,K) uint32: nb live boundaries, DISTINCT and increasing, then
+      all-0xFFFFFFFF padding (keys.MAX_LIMBS, above any encoded key);
       bval (K,) i32; nb () i32; oldest () i32; table (LEVELS,K) i32
     batch:
       txn_valid (T,) bool; snapshot (T,) i32 (version offsets)
@@ -496,27 +528,20 @@ def conflict_step(state: dict, batch: dict, *, shapes: ConflictShapes,
       (False for all but the last chunk of a logical batch)
 
     Layout: ONE order of [state boundaries | rb | re | wb | we] per step
-    feeds everything — history positions, intra-batch endpoint ranks (instead
-    of a second sort), and the merged union of state with committed write
-    endpoints (instead of a scatter-built union). The order is the stable
-    sort by (key, class, index), and it is constructed (_merged_order): the
-    M = 2NR + 2NW batch rows are sorted by _lex_sort_perm and ranked in the
-    state, which is sorted already — init_state, the gc scope's compaction,
-    the poison branch, rebase_state (keys untouched) and the sharded engine's
-    re-cut all leave `bkeys` non-decreasing over every slot. On one v5e at
-    capacity 2^18 (PERF.md, PR 27) the M-wide sort costs 0.3-0.7 ms and the
-    19 bisection rounds 0.1-2.3 ms (M = 640 .. 10,240), where sorting all
-    K + M rows in eight passes cost 18 ms of a 41 ms step; what is left of
-    the `sort` scope is the two K + M wide gathers into that order.
+    feeds everything — history positions, intra-batch endpoint ranks and the
+    merged union of state with committed write endpoints. It is the stable
+    sort by (key, class, index), constructed (_merged_order) from the batch's
+    M = 2NR + 2NW rows: init_state, the gc scope, the poison branch,
+    rebase_state and the sharded engine's re-cut all leave `bkeys` as above.
+    The keys are never gathered into it: key groups and ranks come from the
+    sorted batch (_group_starts, _batch_key_ranks; PERF.md, PR 31).
     """
     T, NR, NW, K = shapes.txns, shapes.reads, shapes.writes, shapes.capacity
-    L = shapes.limbs
-    bkeys, bval, nb, oldest, table = (
-        state["bkeys"], state["bval"], state["nb"], state["oldest"], state["table"])
+    bkeys, bval, oldest, table = (
+        state["bkeys"], state["bval"], state["oldest"], state["table"])
     rb, re, rtxn = batch["rb"], batch["re"], batch["rtxn"]
     wb, we, wtxn = batch["wb"], batch["we"], batch["wtxn"]
     snapshot, txn_valid = batch["snapshot"], batch["txn_valid"]
-    vnew = batch["commit_version"]
 
     # The numbered phases below run inside jax.named_scope, which names the
     # phase in every operation's metadata and changes nothing else: a
@@ -536,35 +561,25 @@ def conflict_step(state: dict, batch: dict, *, shapes: ConflictShapes,
         #  - re before equal state keys -> #state<re  = lower bound
         #  - wb/we after equal state keys -> duplicate endpoint lands in the SAME
         #    union slot as the state boundary it equals
-        # The state's K rows are in order already (precondition), so the
-        # order is built from the batch's M = 2NR + 2NW rows alone
-        # (_merged_order), not found by sorting K + M rows.
         M = 2 * NR + 2 * NW
-        N_ALL = K + M
         bk = jnp.concatenate([rb, re, wb, we], axis=1)  # (L, M)
         bcls = jnp.concatenate([
             jnp.full(NR, 2, jnp.int32), jnp.zeros(NR, jnp.int32),
             jnp.full(2 * NW, 2, jnp.int32)])
-        # sidx: original element index; spos_b: sorted position of each
-        # batch element (the inverse permutation's entries K..N_ALL)
-        sidx, spos_b, cum_b = _merged_order(bkeys, bk, bcls)
-        allk = jnp.concatenate([bkeys, bk], axis=1)  # (L, N_ALL)
-        vpay = jnp.concatenate([bval, jnp.full(M, NEG, jnp.int32)])
-        skeys = allk[:, sidx]                   # (L, N_ALL) sorted
-        sval = vpay[sidx]                       # state values in sorted order
-        is_batch = sidx >= K
-        is_re = (sidx >= K + NR) & (sidx < K + 2 * NR)
-        scls = jnp.where(is_batch, jnp.where(is_re, 0, 2), 1)
-        cum_state = (jnp.arange(1, N_ALL + 1, dtype=jnp.int32)
-                     - cum_b)                   # inclusive
+        sidx, spos_b, bperm, q, rank, p = _merged_order(bkeys, bk, bcls)
+        # the state keys before a batch row ARE its bound in the state
+        rank_b = jnp.zeros(M, jnp.int32).at[bperm].set(
+            rank, unique_indices=True)
+        # state values in sorted order (a batch row's is masked where read)
+        sval = bval[jnp.minimum(sidx, K - 1)]
 
     with jax.named_scope("history"):
         # ---- 1. too-old (only txns with read ranges expire: SkipList.cpp:985) ----
         too_old = txn_valid & has_reads & (snapshot < oldest)
 
         # ---- 2. history check: range-max of step function vs snapshot ----
-        ub_rb = cum_state[spos_b[:NR]]        # #state keys <= rb
-        lb_re = cum_state[spos_b[NR:2 * NR]]  # #state keys < re
+        ub_rb = rank_b[:NR]        # #state keys <= rb
+        lb_re = rank_b[NR:2 * NR]  # #state keys < re
         i0 = jnp.maximum(ub_rb - 1, 0)  # segment containing begin
         i1 = lb_re  # first boundary >= end
         nonempty = _key_lt(rb, re)
@@ -576,23 +591,13 @@ def conflict_step(state: dict, batch: dict, *, shapes: ConflictShapes,
         g = txn_valid & ~too_old & ~hist_conflict
     with jax.named_scope("intra"):
         # ---- 3. intra-batch: endpoint ranks -> overlap queries -> fixpoint ----
-        # Endpoint ranks come from the step's order: rank = number of distinct
-        # batch-endpoint key groups at-or-before this element, which is
-        # order-isomorphic to the keys over batch endpoints (state elements
-        # interleave but contribute no rank). Each sweep's "does a committed
-        # earlier txn's write overlap this read" is answered with per-level
-        # prefix scans over sorted write endpoints (geometry built once per
-        # step, _intra_scan_levels) — O(n log n) per sweep with no n×n matrix
-        # materialized.
-        newgrp = jnp.concatenate(
-            [jnp.ones(1, bool), ~_key_eq(skeys[:, 1:], skeys[:, :-1])])
-        cum_b_excl = cum_b - is_batch
-        grp_start_b = lax.cummax(jnp.where(newgrp, cum_b_excl, -1))
-        first_b = is_batch & (cum_b_excl == grp_start_b)
-        rank_grp = jnp.cumsum(first_b.astype(jnp.int32)) - 1
-        # carry each group's first-batch rank forward (monotone -> cummax)
-        rank_carried = lax.cummax(jnp.where(first_b, rank_grp, -1))
-        qranks = rank_carried[spos_b]           # ranks of [rb | re | wb | we]
+        # An endpoint's rank = the number of distinct batch-endpoint keys
+        # below it: order-isomorphic to the keys over batch endpoints. Each
+        # sweep's "does a committed earlier txn's write overlap this read" is
+        # answered with per-level prefix scans over sorted write endpoints
+        # (geometry built once per step, _intra_scan_levels) — O(n log n) per
+        # sweep with no n×n matrix materialized.
+        q_new, qranks = _batch_key_ranks(q, bperm)  # of [rb | re | wb | we]
         rbr, rer = qranks[:NR], qranks[NR:2 * NR]
         wbr, wer = qranks[2 * NR:2 * NR + NW], qranks[2 * NR + NW:]
 
@@ -623,7 +628,7 @@ def conflict_step(state: dict, batch: dict, *, shapes: ConflictShapes,
         statuses = jnp.where(txn_valid, statuses, COMMITTED)
     return _merge_phase(state, batch, statuses, commit, shapes,
                         max_write_life, sort_products=(
-                            skeys, scls, sval, sidx, spos_b, cum_state),
+                            sval, sidx, spos_b, q, q_new, rank, p),
                         merge_commit=merge_commit, converged=converged,
                         eligible=g)
 
@@ -632,8 +637,7 @@ def _merge_phase(state, batch, statuses, commit, shapes, max_write_life,
                  sort_products, merge_commit, converged, eligible):
     T, NR, NW, K = shapes.txns, shapes.reads, shapes.writes, shapes.capacity
     L = shapes.limbs
-    bkeys, bval, nb, oldest = (
-        state["bkeys"], state["bval"], state["nb"], state["oldest"])
+    bkeys, nb, oldest = state["bkeys"], state["nb"], state["oldest"]
     wb, we, wtxn = batch["wb"], batch["we"], batch["wtxn"]
     vnew = batch["commit_version"]
     wvalid = wtxn < T
@@ -649,8 +653,7 @@ def _merge_phase(state, batch, statuses, commit, shapes, max_write_life,
         # the history and intra-batch checks already paid for the order (the
         # device analogue of the reference's finger-merge,
         # mergeWriteConflictRanges SkipList.cpp:1260).
-        skeys, scls, sval, sidx, spos_b, cum_state = sort_products
-        N_ALL = K + 2 * NR + 2 * NW
+        sval, sidx, spos_b, q, q_new, rank, p = sort_products
         commit_w = merge_commit[wtxn_c]
         # committed, non-empty writes only: an inverted range would inject a
         # reversed -1/+1 coverage delta and cancel other writes' coverage
@@ -659,25 +662,22 @@ def _merge_phase(state, batch, statuses, commit, shapes, max_write_life,
         # committed begins, -1 at committed ends (positions are unique)
         delta_w = jnp.concatenate([cw.astype(jnp.int32), -(cw.astype(jnp.int32))])
         pos_w = spos_b[2 * NR:]
-        delta_sorted = jnp.zeros(N_ALL, jnp.int32).at[pos_w].set(delta_w)
+        delta_sorted = jnp.zeros_like(sidx).at[pos_w].set(delta_w)
 
         # union slot sources: live state boundaries + committed write endpoints
-        is_state = scls == 1
-        live_state = is_state & (sidx < nb)
+        live_state = sidx < nb  # a batch row's index is K or more
         is_src = live_state | (delta_sorted != 0)
         # one representative (slot) per distinct key among sources; the class
         # tiebreak sorted state before equal write endpoints, so a duplicate
         # endpoint joins the state boundary's slot
-        newgrp = jnp.concatenate(
-            [jnp.ones(1, bool), ~_key_eq(skeys[:, 1:], skeys[:, :-1])])
+        newgrp = _group_starts(bkeys, nb, sidx, q, q_new, rank, p)
         cum_src_excl = jnp.cumsum(is_src.astype(jnp.int32)) - is_src
         grp_start_src = lax.cummax(jnp.where(newgrp, cum_src_excl, -1))
         rep = is_src & (cum_src_excl == grp_start_src)
 
-        # value of each slot under the CURRENT step function: the last live state
-        # boundary's value at-or-before it, carried forward by scan (sval holds
-        # the values in sorted order; an N_ALL-wide scan is cheaper than a
-        # random bval gather per slot)
+        # value of each slot under the CURRENT step function: the last live
+        # state boundary's at-or-before it, carried forward by a scan over
+        # sval (cheaper than a random bval gather per slot)
         val_u = _carry_last_flagged(jnp.where(live_state, sval, NEG), live_state)
 
         # coverage at a slot = total delta through the END of its key group
@@ -709,17 +709,17 @@ def _merge_phase(state, batch, statuses, commit, shapes, max_write_life,
             [jnp.full(1, NEG, jnp.int32), rep_val_carried[:-1]])
         keep2 = rep & ((cum_rep == 1) | (newval != prev_rep_val))
         n2 = jnp.sum(keep2.astype(jnp.int32))
-        # compact kept slots to the front: one int32 source scatter, then gather
-        # keys/values from the sorted arrays (indices are monotone)
+        # compact kept slots to the front: each kept row's element index and
+        # value scattered to its slot (slot K takes the rest), the state's
+        # keys gathered through the indices, the new boundaries' (<= 2NW
+        # committed write endpoints) written over their slots further down
         cpos = jnp.cumsum(keep2.astype(jnp.int32)) - 1
         cpos = jnp.where(keep2, jnp.minimum(cpos, K - 1), K)
-        csrc = jnp.full(K + 1, -1, jnp.int32).at[cpos].set(
-            jnp.arange(N_ALL, dtype=jnp.int32))[:K]
-        kept = csrc >= 0
-        csrc_c = jnp.clip(csrc, 0, N_ALL - 1)
-        out_keys = jnp.where(kept[None, :], skeys[:, csrc_c],
+        csrc = jnp.full(K + 1, -1, jnp.int32).at[cpos].set(sidx)[:K]
+        out_vals = jnp.full(K + 1, NEG, jnp.int32).at[cpos].set(newval)[:K]
+        out_keys = jnp.where((csrc >= 0)[None, :],
+                             bkeys[:, jnp.clip(csrc, 0, K - 1)],
                              jnp.uint32(0xFFFFFFFF))
-        out_vals = jnp.where(kept, newval[csrc_c], NEG)
 
         overflow = n2 > K
 
@@ -736,6 +736,9 @@ def _merge_phase(state, batch, statuses, commit, shapes, max_write_life,
             jnp.zeros(L, dtype=jnp.uint32))  # encode(b"") == all-zero limbs
         pois_vals = jnp.full(K, NEG, jnp.int32).at[0].set(vnew)
         out_keys = jnp.where(poisoned, pois_keys, out_keys)
+        out_keys = out_keys.at[
+            :, jnp.where(poisoned, K, cpos[spos_b[2 * NR:]])
+        ].set(jnp.concatenate([wb, we], axis=1), mode="drop")
         out_vals = jnp.where(poisoned, pois_vals, out_vals)
         n2 = jnp.where(poisoned, 1, n2)
     with jax.named_scope("table"):

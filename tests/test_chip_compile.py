@@ -104,6 +104,54 @@ def test_small_step_sorts_the_batch_not_the_state(topo):
     assert SMALL.capacity + M not in widths, widths
 
 
+_HLO_RESULT = re.compile(
+    r"^\s*(?:ROOT )?%([\w.\-]+) = (\w+)\[([\d,]*)\]\S* ([\w\-]+)\((.*)$")
+
+
+def _wide_moves(hlo_text, widths):
+    """The module's irregular moves whose data is as wide as the state:
+    gathers whose result, and scatters whose updates, have a dimension in
+    `widths`. (An M-row scatter into a K + M wide array, or an M-row gather
+    from a K-row table, is not one: what it moves is M rows.)"""
+    dims, moves = {}, []
+    for line in hlo_text.splitlines():
+        m = _HLO_RESULT.match(line)
+        if not m:
+            continue
+        name, _dtype, shape, op, rest = m.groups()
+        dims[name] = {int(n) for n in shape.split(",") if n}
+        if op in ("gather", "scatter"):
+            moves.append((name, op, re.findall(r"%([\w.\-]+)",
+                                               rest.partition(")")[0])))
+    wide = []
+    for name, op, operands in moves:
+        # scatter(operand.., indices, updates..): the last is an update
+        moved = dims[name] if op == "gather" else dims[operands[-1]]
+        if moved & widths:
+            wide.append(name)
+    return wide
+
+
+def test_small_step_never_holds_the_keys_in_merged_order(topo):
+    """The step finds its key groups from the batch's M sorted rows and the
+    order's own arithmetic (_group_starts, _batch_key_ranks), and the
+    compaction gathers the kept keys from the state itself: no (L, K + M)
+    key array exists in the compiled module, and at most four moves are as
+    wide as the state. Up to PR 30 there were five, for three arrays: the
+    keys gathered into the merged order (`skeys`, (L, K + M)), the values
+    gathered into it (`sval`), the compaction's scatter of kept positions,
+    and its gathers of keys (from `skeys`) and of values through them. Now:
+    `sval`, the compaction's scatters of kept element indices and of kept
+    values, and one gather of kept keys from the state."""
+    K, L = SMALL.capacity, SMALL.limbs
+    N = K + 2 * SMALL.reads + 2 * SMALL.writes
+    text = _compile_donated_step(SMALL, topo).as_text()
+    assert not re.search(rf"= u32\[({L},{N}|{N},{L})\]", text)
+    assert re.search(rf"= u32\[{L},{K}\]", text)  # the pattern does match
+    wide = _wide_moves(text, {K, N})
+    assert 1 <= len(wide) <= 4, wide
+
+
 @pytest.mark.slow(reason="45-80 s to compile (PERF.md, compile times)")
 def test_conflict_step_donated_served_shape(topo):
     mem = _compile_donated_step(SERVED, topo).memory_analysis()

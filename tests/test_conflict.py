@@ -508,27 +508,67 @@ def test_capped_rounds_fallback_parity():
 # element for element to the stable sort of [state | rb | re | wb | we]
 # ---------------------------------------------------------------------------
 
+def _order_inputs(state, batch, shapes):
+    """(bkeys, bk, bcls) as conflict_step hands them to _merged_order."""
+    NR, NW = shapes.reads, shapes.writes
+    bk = np.concatenate([np.asarray(batch[f]) for f in ("rb", "re", "wb", "we")],
+                        axis=1)
+    bcls = np.concatenate([np.full(NR, 2), np.zeros(NR), np.full(2 * NW, 2)]
+                          ).astype(np.int32)
+    return np.asarray(state["bkeys"]), bk, bcls
+
+
 def _assert_constructed_order(state, batch, shapes):
     """_merged_order against _lex_sort_perm of the concatenation and its
     inverse, on one (state, encoded batch)."""
     import jax.numpy as jnp
     from foundationdb_tpu.ops import conflict as C
-    K, NR, NW = shapes.capacity, shapes.reads, shapes.writes
-    bk = np.concatenate([np.asarray(batch[f]) for f in ("rb", "re", "wb", "we")],
-                        axis=1)
-    bcls = np.concatenate([np.full(NR, 2), np.zeros(NR), np.full(2 * NW, 2)]
-                          ).astype(np.int32)
-    bkeys = np.asarray(state["bkeys"])
-    sidx, bpos, cum_b = C._merged_order(
-        jnp.asarray(bkeys), jnp.asarray(bk), jnp.asarray(bcls))
+    K = shapes.capacity
+    bkeys, bk, bcls = _order_inputs(state, batch, shapes)
+    sidx, bpos, bperm, q, rank, p = map(np.asarray, C._merged_order(
+        jnp.asarray(bkeys), jnp.asarray(bk), jnp.asarray(bcls)))
     cls = np.concatenate([np.ones(K, np.uint32), bcls.astype(np.uint32)])
     want = np.asarray(C._lex_sort_perm(jnp.asarray(np.concatenate(
         [np.concatenate([bkeys, bk], axis=1), cls[None]]))))
     inverse = np.empty_like(want)
     inverse[want] = np.arange(want.size, dtype=want.dtype)
-    np.testing.assert_array_equal(np.asarray(sidx), want)
-    np.testing.assert_array_equal(np.asarray(bpos), inverse[K:])
-    np.testing.assert_array_equal(np.asarray(cum_b), np.cumsum(want >= K))
+    np.testing.assert_array_equal(sidx, want)
+    np.testing.assert_array_equal(bpos, inverse[K:])
+    # the sorted batch, M wide: the batch elements in the order's order
+    np.testing.assert_array_equal(K + bperm, want[want >= K])
+    np.testing.assert_array_equal(q, bk[:, bperm])
+    np.testing.assert_array_equal(p, np.flatnonzero(want >= K))
+    # state keys before each: the history check's bounds
+    np.testing.assert_array_equal(rank, np.cumsum(want < K)[p])
+
+
+def _assert_built_groups(state, batch, shapes):
+    """The key groups the step builds M wide (_group_starts,
+    _batch_key_ranks) against the ones read off the keys gathered into the
+    merged order: the expressions the step used while it materialised
+    `skeys = allk[:, sidx]`, kept here as the reference."""
+    import jax.numpy as jnp
+    from foundationdb_tpu.ops import conflict as C
+    K = shapes.capacity
+    bkeys, bk, bcls = _order_inputs(state, batch, shapes)
+    sidx, bpos, bperm, q, rank, p = C._merged_order(
+        jnp.asarray(bkeys), jnp.asarray(bk), jnp.asarray(bcls))
+    q_new, ranks = C._batch_key_ranks(q, bperm)
+    newgrp = C._group_starts(jnp.asarray(bkeys), state["nb"], sidx, q, q_new,
+                             rank, p)
+
+    sidx, bpos = np.asarray(sidx), np.asarray(bpos)
+    skeys = np.concatenate([bkeys, bk], axis=1)[:, sidx]
+    want_newgrp = np.concatenate(
+        [[True], (skeys[:, 1:] != skeys[:, :-1]).any(axis=0)])
+    is_batch = sidx >= K
+    cum_b_excl = np.cumsum(is_batch) - is_batch
+    grp_start_b = np.maximum.accumulate(np.where(want_newgrp, cum_b_excl, -1))
+    first_b = is_batch & (cum_b_excl == grp_start_b)
+    rank_grp = np.cumsum(first_b) - 1
+    rank_carried = np.maximum.accumulate(np.where(first_b, rank_grp, -1))
+    np.testing.assert_array_equal(np.asarray(newgrp), want_newgrp)
+    np.testing.assert_array_equal(np.asarray(ranks), rank_carried[bpos])
 
 
 def _order_engine(reads_div=1, **kw):
@@ -598,14 +638,21 @@ def _order_case_nb_one():
     return state, batch, shapes
 
 
-def _order_case_nb_full_and_m_over_k():
-    """The state exactly full (nb == K, no padding left) at a shape whose
-    batch is wider than the state (M = 16 > K = 4)."""
+def _full_order_engine():
+    """_order_engine at K = 4 with the state exactly full: "", k10, k20, k30
+    (nb == K, no padding left; M = 16 > K)."""
     shapes, enc, step, state = _order_engine(
         capacity=4, txns=4, reads_per_txn=1, writes_per_txn=1)
     for v, (lo, hi) in ((1, (b"k10", b"k20")), (2, (b"k20", b"k30"))):
         state, _, _ = step(state, enc.encode_batch(_writes_of((lo, hi)), v))
     assert int(state["nb"]) == shapes.capacity
+    return shapes, enc, state
+
+
+def _order_case_nb_full_and_m_over_k():
+    """The state exactly full (nb == K, no padding left) at a shape whose
+    batch is wider than the state (M = 16 > K = 4)."""
+    shapes, enc, state = _full_order_engine()
     batch = enc.encode_batch([
         txn(2, reads=[(b"k20", b"k30")], writes=[(b"k30", b"k40")]),
         txn(2, reads=[(b"", b"k10")], writes=[(b"k05", b"k10")]),
@@ -626,13 +673,99 @@ def _order_case_poisoned():
     return state, batch, shapes
 
 
-@pytest.mark.parametrize("case", [
+def _order_case_last_live_boundary():
+    """All four classes of endpoint on the state's LAST live boundary, with
+    every read slot used: no padding `re` stands between that boundary and
+    the first padding slot, whose predecessor in the order is then a state
+    row, a `re` equal to it before both."""
+    shapes, enc, step, state = _order_engine(
+        capacity=32, txns=2, reads_per_txn=2, writes_per_txn=2)
+    state, _, _ = step(state, enc.encode_batch(_writes_of((b"b", b"t")), 10))
+    batch = enc.encode_batch([
+        txn(5, reads=[(b"t", b"u"), (b"b", b"t")], writes=[(b"t", b"u")]),
+        txn(5, reads=[(b"a", b"t"), (b"t", b"t")], writes=[(b"a", b"t")]),
+    ], 20)
+    assert (batch["rtxn"] < shapes.txns).all()
+    return state, batch, shapes
+
+
+def _order_case_rank_zero_and_rank_k():
+    """A `re` of b"" (rank 0: a batch row at position 0 of the order, before
+    the state's first key) and, on a full state, keys above every boundary
+    (rank K: batch rows behind the state's last slot)."""
+    shapes, enc, state = _full_order_engine()
+    batch = enc.encode_batch([
+        txn(2, reads=[(b"", b"")], writes=[(b"", b"k10")]),
+        txn(2, reads=[(b"k30", b"k50")], writes=[(b"k40", b"k50")]),
+        txn(2, reads=[(b"k50", b"k60")], writes=[(b"k50", b"k60")]),
+    ], 3)
+    return state, batch, shapes
+
+
+def _with_max_ends(batch, txn_no):
+    """`batch` with one more read and one more write, both of transaction
+    `txn_no` and both ending at MAX_LIMBS, the padding's key: no byte string
+    encodes to it, so the rows are written in limbs."""
+    from foundationdb_tpu.utils import keys as keylib
+    batch = {k: np.array(v) for k, v in batch.items()}
+    for b, e, t in (("rb", "re", "rtxn"), ("wb", "we", "wtxn")):
+        slot = int(np.argmax(batch[t] >= batch["txn_valid"].size))
+        batch[b][:, slot] = keylib.encode_key(b"x")
+        batch[e][:, slot] = keylib.MAX_LIMBS
+        batch[t][slot] = txn_no
+    return batch
+
+
+def _order_case_max_limbs():
+    """Valid ranges that END at MAX_LIMBS, the key of the padding: equal to
+    every padding slot of the state and to the batch's own padding rows."""
+    shapes, enc, step, state = _order_engine(
+        capacity=32, txns=4, reads_per_txn=2, writes_per_txn=2)
+    state, _, _ = step(state, enc.encode_batch(_writes_of((b"b", b"d")), 10))
+    batch = _with_max_ends(enc.encode_batch(
+        [txn(15, reads=[(b"c", b"x")], writes=[(b"d", b"x")])], 20), 0)
+    return state, batch, shapes
+
+
+def _order_case_live_max_limbs():
+    """A state whose last LIVE key is MAX_LIMBS (the step before committed a
+    write that ends there): the first padding slot then opens no group."""
+    state, batch, shapes = _order_case_max_limbs()
+    from foundationdb_tpu.ops import conflict as C
+    from foundationdb_tpu.utils import keys as keylib
+    state, _, _ = C._compiled_step(shapes, 1000)(state, batch)
+    nb = int(state["nb"])
+    np.testing.assert_array_equal(
+        np.asarray(state["bkeys"])[:, nb - 1], keylib.MAX_LIMBS)
+    batch = _with_max_ends(C.BatchEncoder(shapes).encode_batch([
+        txn(25, reads=[(b"d", b"y")], writes=[(b"y", b"z")]),
+        txn(25, reads=[(b"a", b"b")]),
+    ], 30), 1)
+    return state, batch, shapes
+
+
+_ORDER_SPACE = [b"k%02d" % i for i in range(40)] + [b"", b"k07\x00", b"\xff"]
+ORDER_CASES = [
     _order_case_live_boundary, _order_case_equal_endpoints,
     _order_case_padding_only, _order_case_nb_one,
     _order_case_nb_full_and_m_over_k, _order_case_poisoned,
-], ids=lambda f: f.__name__.removeprefix("_order_case_"))
+    _order_case_last_live_boundary, _order_case_rank_zero_and_rank_k,
+    _order_case_max_limbs, _order_case_live_max_limbs,
+]
+
+
+def _case_id(f):
+    return f.__name__.removeprefix("_order_case_")
+
+
+@pytest.mark.parametrize("case", ORDER_CASES, ids=_case_id)
 def test_constructed_order_tie_cases(case):
     _assert_constructed_order(*case())
+
+
+@pytest.mark.parametrize("case", ORDER_CASES, ids=_case_id)
+def test_built_groups_tie_cases(case):
+    _assert_built_groups(*case())
 
 
 @pytest.mark.parametrize("seed,kw", [
@@ -648,13 +781,14 @@ def test_constructed_order_tie_cases(case):
 def test_constructed_order_random(seed, kw):
     """Random states (whatever the step itself left behind: merged, window
     collected, poisoned) and random batches: the constructed order is the
-    stable sort's, before every step."""
+    stable sort's and the built key groups are the gathered keys', before
+    every step."""
     kw = {"capacity": 256, "txns": 16, "reads_per_txn": 2,
           "writes_per_txn": 2, **kw}
     reads_div = 16 if kw.pop("bucket", False) else 1
     shapes, enc, step, state = _order_engine(reads_div=reads_div, **kw)
     rng = DeterministicRandom(seed)
-    space = [b"k%02d" % i for i in range(40)] + [b"", b"k07\x00", b"\xff"]
+    space = _ORDER_SPACE
     version = 0
     for _ in range(12):
         version += rng.randint(50, 300)
@@ -670,7 +804,78 @@ def test_constructed_order_random(seed, kw):
             room -= len(t.read_ranges)
         batch = enc.encode_batch(txns, version)
         _assert_constructed_order(state, batch, shapes)
+        _assert_built_groups(state, batch, shapes)
         state, _, _ = step(state, batch)
+
+
+# ---------------------------------------------------------------------------
+# the precondition the built key groups lean on: whatever writes a state
+# leaves its live keys DISTINCT and in order, and all-ones padding behind
+# ---------------------------------------------------------------------------
+
+def assert_state_keys_distinct(bkeys, nb):
+    """`bkeys` ((L, K)): the first `nb` keys strictly increasing, every later
+    slot MAX_LIMBS."""
+    from foundationdb_tpu.utils import keys as keylib
+    bkeys, nb = np.asarray(bkeys), int(nb)
+    assert 1 <= nb <= bkeys.shape[1]
+    live = [tuple(int(x) for x in col) for col in bkeys[:, :nb].T]
+    assert all(a < b for a, b in zip(live, live[1:])), "live keys not distinct"
+    assert (bkeys[:, nb:] == keylib.MAX_LIMBS[:len(bkeys), None]).all()
+
+
+def _after_random_steps(dev, rng):
+    space, version = _ORDER_SPACE, 0
+    for _ in range(12):
+        version += rng.randint(50, 300)
+        dev.detect([txn(max(0, version - rng.randint(0, 1500)),
+                        [_random_range(rng, space)], [_random_range(rng, space)])
+                    for _ in range(rng.randint(1, 16))], version)
+        yield version
+
+
+def _after_overflow(dev, rng):
+    try:
+        for v in range(1, 8):
+            dev.detect([txn(0, writes=[(b"%04d" % (v * 40 + j),
+                                        b"%04da" % (v * 40 + j))])
+                        for j in range(16)], v * 10)
+            yield v * 10
+    except Exception as e:  # noqa: BLE001 — the overflow the case is after
+        assert "capacity exceeded" in str(e)
+    assert bool(dev._state["poisoned"])
+    yield 100
+
+
+def _after_rebase(dev, rng):
+    *_, version = _after_random_steps(dev, rng)
+    base = dev.base_version
+    dev.detect([txn(version, writes=[(b"k01", b"k02")])], version + (1 << 30))
+    assert dev.base_version > base
+    yield version + (1 << 30)
+
+
+def _after_clear(dev, rng):
+    list(_after_random_steps(dev, rng))
+    dev.clear(oldest_version=5)
+    yield 5
+    dev.detect([txn(5, writes=[(b"a", b"b")])], 50)
+    yield 50
+
+
+@pytest.mark.parametrize("events,capacity", [
+    (_after_random_steps, 256), (_after_overflow, 64),
+    (_after_rebase, 256), (_after_clear, 256),
+], ids=["random_steps", "overflow", "rebase", "clear"])
+def test_state_keys_stay_distinct_and_padded(events, capacity):
+    """After every step of each history (merges with window collection, the
+    poisoning overflow, a rebase of the versions, a clear) the state is what
+    conflict_step's docstring asks of its input."""
+    KNOBS.set("MAX_WRITE_TRANSACTION_LIFE_VERSIONS", 1000)
+    dev = small_device_set(capacity=capacity, txns=16)
+    assert_state_keys_distinct(dev._state["bkeys"], dev._state["nb"])
+    for _version in events(dev, DeterministicRandom(61)):
+        assert_state_keys_distinct(dev._state["bkeys"], dev._state["nb"])
 
 
 # ---------------------------------------------------------------------------

@@ -179,6 +179,33 @@ def test_parity_with_clipped_oracles_across_whole_key_moves():
     assert counts[COMMITTED] > 50 and counts[CONFLICT] > 50, counts
 
 
+def test_moves_leave_every_shard_distinct_and_padded():
+    """What the step's built key groups lean on, shard by shard on a
+    four-device mesh: after steps and after every move of the cuts
+    (rebalance_cuts rewrites the keys on the host) a shard's live keys are
+    strictly increasing and the slots behind them hold MAX_LIMBS."""
+    from test_conflict import assert_state_keys_distinct
+    keys = _decimal_keys(200)
+    cs = ShardedDeviceConflictSet(mesh=make_resolver_mesh(4), capacity=1024,
+                                  txns=16, reads_per_txn=4, writes_per_txn=4)
+
+    def check():
+        for bkeys, nb in zip(np.asarray(cs._state["bkeys"]),
+                             np.asarray(cs._state["nb"])):
+            assert_state_keys_distinct(bkeys, nb)
+
+    version = 100
+    check()
+    for step, new_cuts in enumerate(MOVES + MOVES[:1]):
+        for txns, version in _stream(90 + step, keys, 4, version):
+            cs.detect(txns, version)
+        check()
+        version += 3
+        cs.rebalance_cuts(new_cuts, version)
+        check()
+    assert cs.rebalances >= len(MOVES) + 1
+
+
 def test_a_move_adds_conflicts_and_never_a_commit():
     """Readers and blind writers: every writer commits in both engines, so
     their histories are the same writes, and whatever the key-partitioned
