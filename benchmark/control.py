@@ -2,6 +2,7 @@
 one guarantee the configuration states broken, has to come out NOT correct.
 
     python benchmark/control.py --workload <cell> --seed <n> [--txns N]
+        [--bench-file <a BENCHMARK.json with a cell that the checkout's lacks>]
 
 Drives the cell's own traffic (its mix, its data, as many actors) through
 reference.RefCluster by the same actor loop as the program's clients, then
@@ -62,9 +63,11 @@ def main() -> int:
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seed", type=int, required=True)
     ap.add_argument("--txns", type=int, default=60000)
+    ap.add_argument("--bench-file")
     args = ap.parse_args()
     from run import load_cell
-    _bench, _cell, _entry, config, mix = load_cell(ROOT, args.workload)
+    _bench, _cell, _entry, config, mix = load_cell(ROOT, args.workload,
+                                                   args.bench_file)
     ok = True
     for broken in (None, "isolation", "durability"):
         correct, compared, notes = run_control(config, mix, args.seed,

@@ -20,10 +20,16 @@ when a server stood still) is a failed operation, not a wrong answer: run.py
 counts it under `failed` and as missing every latency, and here the records it
 may have written are left out of the comparison, since nobody knows whether
 it committed. No control and no planted fault raises that count, so it has no
-upper reading and is not among the numbers compared. Two blind writes of one key at one commit
-version (one commit batch) may be serialized either way, and a client cannot
-tell which: then either value is accepted, and the count of such ties is
-reported beside the numbers.
+upper reading and is not among the numbers compared. Two blind writes of one
+key at one commit version (one commit batch) by two transactions may be
+serialized either way, and a client cannot tell which: then either value is
+accepted, and the count of such ties is reported beside the numbers.
+
+A plan is replayed as the client API runs it (read-your-writes): a read of a
+record the same plan has written before is answered from that write, with no
+read version, no read of the database and no read conflict, so it goes into
+the checksum with the plan's own value and can be no violation; of two writes
+of one record in one plan the later one stands.
 
 `RefCluster` is the same semantics as a system, for control.py: it stands in
 the program's place, with one stated guarantee broken.
@@ -33,6 +39,7 @@ from __future__ import annotations
 
 import bisect
 import itertools
+import math
 import zlib
 
 from actor import ACKNOWLEDGED
@@ -60,6 +67,16 @@ def _norm(alts: set):
     if len(alts) == 1:
         return next(iter(alts))
     return tuple(sorted(alts, key=lambda v: (v is None, v))[:MAX_ALTERNATIVES])
+
+
+def _written(traffic, pool: bytes, op: int, a: int, b: int, read) -> tuple:
+    """The values a `set` or an `rmw` may write, the latter given those its
+    read (of the database, or of the plan's own earlier write) may have
+    returned."""
+    fresh = traffic.fresh(pool, op, a, b)
+    if op == SET:
+        return (fresh,)
+    return tuple({traffic.modify(old, fresh, b) for old in read})
 
 
 def check_history(traffic, seed: int, pool: bytes, initial: list[bytes],
@@ -107,23 +124,23 @@ def check_history(traffic, seed: int, pool: bytes, initial: list[bytes],
         new = {}  # record -> set of values it may hold after this version
         rmw_writers = {}
         for _cv, _w, _a, rv, plan in group:
+            own = {}  # record -> what this plan has written to it so far
             for op, k, a, b in plan:
-                if op != SET and last_version[k] > rv:
+                mine = own.get(k)
+                if op != SET and mine is None and last_version[k] > rv:
                     violations += 1  # read k at rv; k was written before cv
                 if op == READ:
                     continue
-                fresh = traffic.fresh(pool, op, a, b)
-                if op == RMW:
+                if op == RMW and mine is None:  # it read the database's value
                     rmw_writers[k] = rmw_writers.get(k, 0) + 1
-                    vals = {traffic.modify(old, fresh, b)
-                            for old in _alts(latest[k])}
-                else:
-                    vals = {fresh}
+                    mine = _alts(latest[k])
+                own[k] = _written(traffic, pool, op, a, b, mine)
+            for k, vals in own.items():
                 if k in new:
                     ties += 1
-                    new[k] |= vals
+                    new[k].update(vals)
                 else:
-                    new[k] = vals
+                    new[k] = set(vals)
         # two transactions of one batch that both read and wrote k: the
         # second one's read was stale whichever came first
         violations += sum(n - 1 for n in rmw_writers.values() if n > 1)
@@ -134,50 +151,42 @@ def check_history(traffic, seed: int, pool: bytes, initial: list[bytes],
                 hist_v.setdefault(k, []).append(cv)
                 hist_x.setdefault(k, []).append(latest[k])
 
-    # every read of every acknowledged transaction, as of its read version
-    crc_of = {}
+    # every read of every acknowledged transaction: a record the plan wrote
+    # before, from the plan; any other, from the history as of the read version
     read_mismatches = reads_compared = ambiguous = 0
     for rv, crc, plan in reading:
-        alts = []
-        skip = False
-        for op, k, _a, _b in plan:
-            if op == SET:
-                continue
-            if k in tainted:
-                skip = True
-                break
-            vs = hist_v.get(k)
-            j = bisect.bisect_right(vs, rv) - 1 if vs else -1
-            alts.append((k, j))
-        if skip:
-            continue
-        reads_compared += 1
-        if len(alts) == 1:
-            k, j = alts[0]
-            got = crc_of.get((k, j))
-            if got is None:
-                x = initial[k] if j < 0 else hist_x[k][j]
-                got = crc_of[(k, j)] = tuple(
-                    zlib.crc32(MISSING if v is None else v) for v in _alts(x))
-            ok = crc in got
+        seen = []  # for each read in order, the values it may have returned
+        own = {}
+        unsure = False
+        for op, k, a, b in plan:
+            mine = own.get(k)
+            if op != SET:
+                if mine is None:
+                    if k in tainted:
+                        break
+                    vs = hist_v.get(k)
+                    j = bisect.bisect_right(vs, rv) - 1 if vs else -1
+                    mine = _alts(initial[k] if j < 0 else hist_x[k][j])
+                elif len(mine) > 1:
+                    # its own write over a base a tie left open: which of the
+                    # alternatives goes with which of the base's is not kept
+                    unsure = True
+                seen.append(mine)
+            if op != READ:
+                own[k] = _written(traffic, pool, op, a, b, mine)
         else:
-            choices = [_alts(initial[k] if j < 0 else hist_x[k][j])
-                       for k, j in alts]
-            n_combos = 1
-            for c in choices:
-                n_combos *= len(c)
-            if n_combos > 64:
+            reads_compared += 1
+            if unsure or math.prod(len(x) for x in seen) > 64:
                 ambiguous += 1
                 continue
-            ok = False
-            for combo in itertools.product(*choices):
+            for combo in itertools.product(*seen):
                 c = 0
                 for v in combo:
                     c = zlib.crc32(MISSING if v is None else v, c)
                 if c == crc:
-                    ok = True
                     break
-        read_mismatches += not ok
+            else:
+                read_mismatches += 1
 
     readback_mismatches = 0
     if readback is not None:
@@ -239,6 +248,8 @@ class RefTransaction:
         return self._rv
 
     async def get(self, key: bytes):
+        """Read-your-writes, as `check_history` judges it: the transaction's
+        own `set` of the key answers, and adds no read conflict."""
         if key in self._writes:
             return self._writes[key]
         rv = await self.get_read_version()

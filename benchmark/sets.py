@@ -1,7 +1,10 @@
 """Several runs of cells in one call on the chip, with what the bounds are set
-from: for each metric of each cell the runs' values, their median and their
-spread (the distance between the first and third quartile, by
-`statistics.quantiles(values, n=4)`, as a share of the median).
+from: for each metric of each cell the runs' values, their median and two
+spreads, each as a share of the median: `spread`, the distance between the first
+and third quartile by `statistics.quantiles(values, n=4)`, and `spread_driver`,
+the driver's reading of a set as its refusals state it (PERF_LEDGER.jsonl,
+PR 32 `reason`): the range of the values once the run farthest from the
+median is left out. `commit_p90_ms`' bound is set from the second (PERF.md §2).
 
     python benchmark/sets.py --out chiprun_out/<tag> --seconds 20 \
         --runs fdb-write:0:11,12,13 ycsb-f:1:21
@@ -33,6 +36,16 @@ def spread(values: list[float]) -> float | None:
         return None
     q1, _q2, q3 = statistics.quantiles(values, n=4)
     return (q3 - q1) / abs(statistics.median(values))
+
+
+def spread_driver(values: list[float]) -> float | None:
+    median = statistics.median(values) if values else 0
+    if len(values) < 2 or median == 0:
+        return None
+    kept = sorted(values)
+    if len(kept) > 2:
+        kept.remove(max(kept, key=lambda v: abs(v - median)))
+    return (kept[-1] - kept[0]) / abs(median)
 
 
 def keep_rows(run_dir: str, dst: str) -> None:
@@ -118,7 +131,7 @@ def main() -> int:
                 r.get(name), (int, float))]
             if not values:
                 continue
-            sp = spread(values)
+            sp, spd = spread(values), spread_driver(values)
             later = values[1:] if name == "setup_s" and len(values) > 2 \
                 else values
             print(json.dumps({
@@ -126,7 +139,9 @@ def main() -> int:
                 "n": len(values), "median": statistics.median(values),
                 "median_after_first": statistics.median(later),
                 "min": min(values), "max": max(values),
-                "spread": sp if sp is None else round(sp, 5)}), flush=True)
+                "spread": sp if sp is None else round(sp, 5),
+                "spread_driver": spd if spd is None else round(spd, 5)}),
+                flush=True)
     return 1 if bad else 0
 
 

@@ -23,6 +23,11 @@ def _load(config: str, mix: str) -> tuple[dict, dict]:
     ("fdb-bench-1chip.tiny.json", "write10.json", None, None),
     ("fdb-bench-1chip.tiny.json", "write10.json", "durability",
      "readback_mismatches"),
+    ("fdb-bench-1chip.tiny.json", "mixed-90-10.json", None, None),
+    ("fdb-bench-1chip.tiny.json", "mixed-90-10.json", "isolation",
+     "conflict_violations"),
+    ("fdb-bench-1chip.tiny.json", "mixed-90-10.json", "durability",
+     "readback_mismatches"),
     ("ycsb-1chip.tiny.json", "ycsb-f.json", None, None),
     ("ycsb-1chip.tiny.json", "ycsb-f.json", "isolation",
      "conflict_violations"),

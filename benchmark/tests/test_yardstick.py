@@ -287,6 +287,71 @@ def test_a_stale_or_altered_read_and_a_lost_write_are_counted():
     assert _judge(plans, sound, stray)[0]["readback_mismatches"] == 1
 
 
+def test_a_read_of_the_plans_own_write_is_taken_from_the_plan():
+    # read-your-writes: the client answers `get` of a record the transaction
+    # has set from its write map, at no read version
+    plans = {(0, 0): [[(tr.SET, 2, 7, 0), (tr.READ, 2, 0, 0),
+                       (tr.READ, 1, 0, 0)],
+                      [(tr.RMW, 0, 3, 0), (tr.READ, 0, 0, 0)]]}
+    readback = {0: b"a0+fresh3", 1: b"b0", 2: b"fresh7"}
+    sound = {0: _log([_row(0, 0, rv=100, cv=200, crc=_crc(b"fresh7", b"b0")),
+                      _row(0, 1, rv=250, cv=300,
+                           crc=_crc(b"a0", b"a0+fresh3"))])}
+    numbers, notes, ok = _judge(plans, sound, readback)
+    assert ok and not any(numbers.values()) and notes["reads_compared"] == 2
+    # a broken system: the own-write read answered from storage
+    for at, from_storage in ((0, _crc(b"c0", b"b0")), (1, _crc(b"a0", b"a0"))):
+        broken = {0: sound[0].copy()}
+        broken[0]["crc"][at] = from_storage
+        numbers, _notes, ok = _judge(plans, broken, readback)
+        assert not ok and numbers["read_mismatches"] == 1
+
+
+def test_a_read_of_the_plans_own_write_is_no_read_of_the_database():
+    # record 2 is written at 150, between the second one's read and commit
+    # versions; after its own set the second one's read of 2 adds no read
+    # conflict, so both may commit. Before its own set, it does.
+    logs = {0: _log([_row(0, 0, rv=0, cv=150),
+                     _row(1, 0, rv=100, cv=200)])}
+    after = {(0, 0): [[(tr.SET, 2, 1, 0)]],
+             (0, 1): [[(tr.SET, 2, 7, 0), (tr.READ, 2, 0, 0),
+                       (tr.RMW, 2, 8, 0)]]}
+    logs[0]["crc"][1] = _crc(b"fresh7", b"fresh7")
+    numbers, _notes, ok = _judge(after, logs, {0: b"a0", 1: b"b0",
+                                               2: b"fresh7+fresh8"})
+    assert ok and not any(numbers.values())
+    before = {(0, 0): after[(0, 0)],
+              (0, 1): [[(tr.READ, 2, 0, 0), (tr.SET, 2, 7, 0)]]}
+    logs[0]["crc"][1] = _crc(b"c0")
+    numbers, _notes, ok = _judge(before, logs, {0: b"a0", 1: b"b0",
+                                                2: b"fresh7"})
+    assert not ok and numbers["conflict_violations"] == 1
+    assert numbers["read_mismatches"] == 0  # it read what stood at 100
+
+
+def test_of_two_writes_of_one_record_in_one_plan_the_later_stands():
+    plans = {(0, 0): [[(tr.SET, 1, 1, 0), (tr.SET, 1, 2, 0),
+                       (tr.SET, 0, 3, 0), (tr.RMW, 0, 4, 0)]]}
+    logs = {0: _log([_row(0, 0, rv=100, cv=200, crc=_crc(b"fresh3"))])}
+    numbers, notes, ok = _judge(plans, logs, {0: b"fresh3+fresh4",
+                                              1: b"fresh2", 2: b"c0"})
+    assert ok and notes["same_version_ties"] == 0
+    numbers, _notes, ok = _judge(plans, logs, {0: b"fresh3+fresh4",
+                                               1: b"fresh1", 2: b"c0"})
+    assert not ok and numbers["readback_mismatches"] == 1
+    # over a base that a tie of two transactions left open, what the plan
+    # then reads of its own write is not judged, and either end is accepted
+    plans = {(0, 0): [[(tr.SET, 1, 1, 0)]], (0, 1): [[(tr.SET, 1, 2, 0)]],
+             (0, 2): [[(tr.RMW, 1, 3, 0), (tr.READ, 1, 0, 0)]]}
+    logs = {0: _log([_row(0, 0, rv=0, cv=200), _row(1, 0, rv=0, cv=200),
+                     _row(2, 0, rv=250, cv=300, crc=12345)])}
+    for last in (b"fresh1+fresh3", b"fresh2+fresh3"):
+        numbers, notes, ok = _judge(plans, logs, {0: b"a0", 1: last,
+                                                  2: b"c0"})
+        assert ok and notes["ambiguous_reads"] == 1
+    assert not _judge(plans, logs, {0: b"a0", 1: b"fresh1", 2: b"c0"})[2]
+
+
 def test_blind_writes_of_one_batch_may_land_either_way():
     plans = {(0, 0): [[(tr.SET, 1, 1, 0)]], (0, 1): [[(tr.SET, 1, 2, 0)]]}
     logs = {0: _log([_row(0, 0, rv=0, cv=200), _row(1, 0, rv=0, cv=200)])}
