@@ -12,6 +12,11 @@ batch. After every server child is reaped, phase `kernel` attaches the chip
 in THIS process and replays a seeded stream of conflict batches through
 DeviceConflictSet and the independent OracleConflictSet at the served shapes.
 
+`--config <file>` takes the key count, the kernel's shapes and the loader's
+shape from a benchmark configuration (benchmark/configs/*.json, 16-byte
+decimal keys) instead of the defaults below: the same run at that
+deployment's scale.
+
 `--chips 4` runs only the mesh-sharded engine against per-shard oracles, in
 one process, over four real devices: the benchmark's keys at the served shape
 from a cold start, with two moves of the cuts to whole keys in mid-stream.
@@ -38,6 +43,7 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 PAIRS = 100_000
 OPS_PER_TXN = 10
 LOADERS = 64
+LOAD_SETS_PER_TXN = OPS_PER_TXN
 ACTORS = 16
 RMW_PER_ACTOR = 10
 BUILT_PAIRS_PER_ACTOR = 4  # of each kind: conflicting and disjoint
@@ -59,6 +65,21 @@ PLATFORM = "tpu"  # what the core and this process must report
 # compiles for the four-chip mesh in ~36 s (PERF.md "compile times").
 SHARDED_SHAPE = {"capacity": 1 << 16, "txns": 256, "reads_per_txn": 10,
                  "writes_per_txn": 10}
+
+
+def use_config(path: str) -> None:
+    """Serve and replay at the scale a benchmark configuration states: its
+    record count, its conflict knobs and its loader's shape."""
+    global PAIRS, LOADERS, LOAD_SETS_PER_TXN
+    with open(path) as f:
+        config = json.load(f)
+    key = config["data"]["key"]
+    check(key == {"kind": "decimal", "bytes": 16},
+          f"{path}: this script writes 16-byte decimal keys, not {key}")
+    PAIRS = int(config["data"]["records"])
+    SERVED_KNOBS.update({k: config["knobs"][k] for k in SERVED_KNOBS})
+    LOADERS = int(config["load"]["loaders"])
+    LOAD_SETS_PER_TXN = int(config["load"]["sets_per_txn"])
 
 
 def emit(phase: str, **fields) -> None:
@@ -83,7 +104,9 @@ SERVED_EVIDENCE = ("Backend", "Platform", "DeviceKind", "DeviceCount",
                    "Poisoned", "BatchesIn", "TxnResolved", "KernelDispatches",
                    "HostExactChunks", "CompileCacheHits", "CompileCacheMisses",
                    "PersistentCacheHits", "PersistentCacheMisses",
-                   "DevicePutBytes", "DeviceGetBytes", "ReadbackWaitSeconds")
+                   "DevicePutBytes", "DeviceGetBytes", "ReadbackWaitSeconds",
+                   "StateBoundariesSum", "StateCapacitySum",
+                   "StateBoundariesPeak", "StateEvictedSum")
 
 
 KERNEL_EVIDENCE = ("KernelDispatches", "HostExactChunks",
@@ -194,10 +217,10 @@ def phase_served(seed: int, out_dir: str) -> dict:
              boot_deadline_seconds=BOOT_DEADLINE_SECONDS, knobs=SERVED_KNOBS,
              **{k: m0[k] for k in BOOT_EVIDENCE})
 
-        # ---- load: PAIRS pairs, OPS_PER_TXN sets per transaction
+        # ---- load: PAIRS pairs, LOAD_SETS_PER_TXN sets per transaction
         keys = sorted(model)
-        txn_keys = [keys[i:i + OPS_PER_TXN]
-                    for i in range(0, PAIRS, OPS_PER_TXN)]
+        txn_keys = [keys[i:i + LOAD_SETS_PER_TXN]
+                    for i in range(0, PAIRS, LOAD_SETS_PER_TXN)]
         next_txn = [0]
 
         async def loader():
@@ -218,7 +241,7 @@ def phase_served(seed: int, out_dir: str) -> dict:
         t0 = time.monotonic()
         run(load(), 600.0)
         emit("load", pairs=PAIRS, transactions=len(txn_keys),
-             sets_per_transaction=OPS_PER_TXN, loaders=LOADERS,
+             sets_per_transaction=LOAD_SETS_PER_TXN, loaders=LOADERS,
              seconds=round(time.monotonic() - t0, 1))
 
         # ---- judged: read-modify-write from ACTORS concurrent actors, plus
@@ -376,6 +399,45 @@ def _conflict_stream(seed: int, n_batches: int, max_txns: int,
         version += rng.randint(1, 10_000)
 
 
+def _window_stream(seed: int, n_batches: int, n_keys: int, step: int,
+                   txns: int, sets: int):
+    """Seeded (txns, commit_version) batches, `step` versions apart, of
+    blind point writes uniform over `n_keys` keys, meant to be far more than
+    a window's writes: every batch inserts boundaries and, once the window
+    is full, the window drops about as many. One transaction in four also
+    reads a key at a snapshot up to two windows old."""
+    from foundationdb_tpu.ops.batch import TxnConflictInfo
+    from foundationdb_tpu.utils.knobs import KNOBS
+    rng = random.Random(seed)
+    window = KNOBS.MAX_WRITE_TRANSACTION_LIFE_VERSIONS
+
+    def point():
+        k = key_of(rng.randrange(n_keys))
+        return (k, k + b"\x00")
+    version = 20_000_000
+    for _ in range(n_batches):
+        version += step
+        yield [TxnConflictInfo(
+            read_snapshot=version - rng.randrange(2 * window),
+            read_ranges=[point()] if rng.random() < 0.25 else [],
+            write_ranges=[point() for _ in range(sets)])
+            for _ in range(txns)], version
+
+
+def _oracle_fill_step(oracle, txns, version: int, held: list[bytes]):
+    """One oracle batch by the engine's own account: (verdicts, the
+    boundaries held after it, rows dropped), where `held` are the boundaries
+    before it and dropped = held before + new ones - held after, all over
+    the collected step function (OracleConflictSet.live_boundaries)."""
+    from foundationdb_tpu.ops.batch import COMMITTED
+    verdicts = oracle.detect(txns, version)
+    new = {k for t, s in zip(txns, verdicts) if s == COMMITTED
+           for b, e in t.write_ranges if b < e for k in (b, e)}
+    new.difference_update(held)
+    after = oracle.live_boundaries()
+    return verdicts, after, len(held) + len(new) - len(after)
+
+
 def _status_counts(statuses: list[int]) -> dict:
     from foundationdb_tpu.ops.batch import COMMITTED, CONFLICT, TOO_OLD
     return {"committed": statuses.count(COMMITTED),
@@ -434,6 +496,44 @@ def phase_kernel(seed: int, served_by: dict) -> None:
          warmup_seconds=round(warm_seconds, 1),
          detect_seconds=round(time.monotonic() - t0, 1),
          **{k: km[k] for k in KERNEL_EVIDENCE})
+
+    # ---- the served keys, versions advancing past the window: a fill set
+    # by the rate (a third of the capacity in keys a window) with an insert
+    # and an eviction every step, and the step's own account of both
+    capacity = SERVED_KNOBS["CONFLICT_STATE_CAPACITY"]
+    txns_a_batch = SERVED_KNOBS["CONFLICT_BATCH_TXNS"]
+    a_window = max(4, capacity // 3 // (txns_a_batch * OPS_PER_TXN))
+    dev, oracle = conflict.DeviceConflictSet(), OracleConflictSet()
+    held = oracle.live_boundaries()
+    fills, dropped, got_all = [], [], []
+    t0 = time.monotonic()
+    for n, (txns, version) in enumerate(_window_stream(
+            seed, 2 * a_window + a_window // 2, PAIRS,
+            KNOBS.MAX_WRITE_TRANSACTION_LIFE_VERSIONS // a_window,
+            txns_a_batch, OPS_PER_TXN)):
+        handle = dev.detect_async(txns, version)
+        got = handle.result()
+        want, held, evicted = _oracle_fill_step(oracle, txns, version, held)
+        check(got == want, f"kernel vs oracle differ at window batch {n}")
+        check(handle.steps == [(len(held), evicted)],
+              f"window batch {n}: the step says (boundaries, evicted) = "
+              f"{handle.steps}, the oracle ({len(held)}, {evicted})")
+        fills.append(len(held))
+        dropped.append(evicted)
+        got_all += got
+    counts = _status_counts(got_all)
+    check(all(counts.values()) and all(dropped[a_window + 1:]),
+          f"the window stream did not exercise every status, and the "
+          f"eviction in every batch past the first window: {counts}, "
+          f"rows dropped a batch {dropped}")
+    check(max(fills) < capacity * 7 // 8,
+          f"the window stream filled the state to {max(fills)} of {capacity}")
+    emit("kernel_window", batches=len(fills), batches_a_window=a_window,
+         keys=PAIRS, transactions=len(got_all), **counts,
+         identical_to_oracle=True, fill_equal_to_oracle=True,
+         capacity=capacity, fill_last=fills[-1], fill_peak=max(fills),
+         rows_evicted=sum(dropped),
+         detect_seconds=round(time.monotonic() - t0, 1))
 
 
 # ----------------------------------------------------------- phase: sharded
@@ -588,6 +688,8 @@ def main() -> None:
                     help="seeds the data and every stream (default 0)")
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
                     help="4: only the mesh-sharded engine vs its oracles")
+    ap.add_argument("--config", help="a benchmark configuration file: its "
+                    "record count, conflict knobs and loader's shape")
     ap.add_argument("--out", default=os.path.join(HERE, "chiprun_out"),
                     help="directory for the servers' stderr files")
     args = ap.parse_args()
@@ -595,6 +697,8 @@ def main() -> None:
     if jaxenv.cpu_requested():
         fail("JAX_PLATFORMS=cpu: this script proves the system on the "
              "accelerator and has no CPU mode (tests/ cover the CPU)")
+    if args.config:
+        use_config(args.config)
     t_start = time.monotonic()
     cache_dir = jaxenv.enable_compile_cache()  # exported to every child
     os.makedirs(args.out, exist_ok=True)

@@ -709,6 +709,9 @@ def _merge_phase(state, batch, statuses, commit, shapes, max_write_life,
             [jnp.full(1, NEG, jnp.int32), rep_val_carried[:-1]])
         keep2 = rep & ((cum_rep == 1) | (newval != prev_rep_val))
         n2 = jnp.sum(keep2.astype(jnp.int32))
+        # rows the window drops this step: union slots (the state's live
+        # boundaries and the new ones) the kept-mask does not keep
+        evicted = cum_rep[-1] - n2
         # compact kept slots to the front: each kept row's element index and
         # value scattered to its slot (slot K takes the rest), the state's
         # keys gathered through the indices, the new boundaries' (<= 2NW
@@ -753,6 +756,7 @@ def _merge_phase(state, batch, statuses, commit, shapes, max_write_life,
         "poisoned": poisoned,
     }
     info = {"overflow": poisoned, "boundaries": n2,
+            "evicted": jnp.where(poisoned, 0, evicted),
             "committed": jnp.sum(commit.astype(jnp.int32)),
             "converged": converged, "eligible": eligible}
     return new_state, statuses, info
@@ -1082,11 +1086,10 @@ def detect_async_impl(engine, txns: list[TxnConflictInfo],
             jaxenv.count_device_put(batch)
             new_state, statuses, info = step(engine._state, batch)
             engine._state = new_state
-            # statuses + intra-eligibility + overflow + convergence fused
-            # into ONE fixed-shape device array (enqueue-only): every chunk
-            # is read back as a single transfer
-            combined = _combine_status(statuses, info["eligible"],
-                                       info["overflow"], info["converged"])
+            # statuses + intra-eligibility + overflow + convergence + the
+            # state's fill and churn fused into ONE fixed-shape device array
+            # (enqueue-only): every chunk is read back as a single transfer
+            combined = _combine_status(statuses, info)
             enc.mark_in_flight(combined)
             # double-buffering: the D2H copy starts NOW, overlapped with the
             # NEXT chunk's/batch's encode + dispatch, so a later drain (or
@@ -1195,21 +1198,26 @@ class DeviceConflictSet:
         self._state = init_state(self.shapes, oldest=0)
 
 
+# the step's scalars that ride each chunk's verdicts, in readback order
+_STATUS_SCALARS = ("overflow", "converged", "boundaries", "evicted")
+
+
 @functools.cache
 def _combine_fn():
     # one program per process: statuses/eligible are always (shapes.txns,),
-    # overflow/converged scalars — the fixed output layout
-    # [statuses | eligible | overflow | converged] makes every chunk
-    # readback a single transfer
-    def combine_status(s, g, o, c):
+    # the rest scalars — the fixed output layout
+    # [statuses | eligible | *_STATUS_SCALARS] makes every chunk readback a
+    # single transfer
+    def combine_status(s, g, *scalars):
         return jnp.concatenate(
-            [s.astype(jnp.int32), g.astype(jnp.int32),
-             jnp.asarray(o, jnp.int32)[None], jnp.asarray(c, jnp.int32)[None]])
+            [s.astype(jnp.int32), g.astype(jnp.int32)]
+            + [jnp.asarray(x, jnp.int32)[None] for x in scalars])
     return jax.jit(combine_status)
 
 
-def _combine_status(statuses, eligible, overflow, converged):
-    return _combine_fn()(statuses, eligible, overflow, converged)
+def _combine_status(statuses, info):
+    return _combine_fn()(statuses, info["eligible"],
+                         *(info[name] for name in _STATUS_SCALARS))
 
 
 def _status_to_host(combined) -> np.ndarray:
@@ -1318,11 +1326,14 @@ class DetectHandle:
     """Deferred result of detect_async: statuses fetched on first result().
 
     Each chunk is (sub_txns, host_too_old, combined) where combined is the
-    device readback [statuses(T) | eligible(T) | overflow | converged]."""
+    device readback [statuses(T) | eligible(T) | overflow | converged |
+    boundaries | evicted]. After result(), `steps` holds one (boundaries the
+    state held after the step, rows its window dropped) per chunk."""
 
     def __init__(self, chunks, ident: str = "", clock=time.monotonic):
         self._chunks = chunks
         self._result: list[int] | None = None
+        self.steps: list[tuple[int, int]] = []
         self.ident, self.clock = ident, clock  # for drain_and_collect's sections
 
     def result(self) -> list[int]:
@@ -1331,8 +1342,9 @@ class DetectHandle:
             for sub, host_too_old, combined in self._chunks:
                 arr = _status_to_host(combined)
                 n = len(sub)
-                tc = (len(arr) - 2) // 2
-                if arr[2 * tc]:
+                tc = (len(arr) - len(_STATUS_SCALARS)) // 2
+                overflow, converged, boundaries, evicted = arr[2 * tc:]
+                if overflow:
                     # Overflow: the truncated state dropped the highest-key
                     # history segments and could cause false commits —
                     # fatal; the owner reconstructs (clearConflictSet
@@ -1340,7 +1352,8 @@ class DetectHandle:
                     raise FDBError(
                         "internal_error",
                         "conflict state capacity exceeded; raise CONFLICT_STATE_CAPACITY")
-                if arr[2 * tc + 1]:
+                self.steps.append((int(boundaries), int(evicted)))
+                if converged:
                     statuses = arr[:n]
                 else:
                     _host_exact_chunks.increment()

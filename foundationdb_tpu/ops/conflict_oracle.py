@@ -116,6 +116,20 @@ class OracleConflictSet:
         self.keys, self.vals = nk, nv
         self.keys[0] = b""
 
+    def live_boundaries(self) -> list[bytes]:
+        """The step function's boundaries once every value is clamped to the
+        window's floor and equal neighbours are one segment: what an engine
+        that collects at every batch holds (remove_before sweeps only now
+        and then, which no decision can see)."""
+        out: list[bytes] = []
+        last = None
+        for k, v in zip(self.keys, self.vals):
+            v = max(v, self.oldest_version)
+            if v != last:
+                out.append(k)
+                last = v
+        return out
+
     # -- batch interface (ConflictBatch) --
     def detect(self, txns: list[TxnConflictInfo], commit_version: int) -> list[int]:
         statuses = [COMMITTED] * len(txns)

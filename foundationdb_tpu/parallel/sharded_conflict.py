@@ -279,6 +279,7 @@ def _build_sharded_step(mesh: Mesh, shapes: ConflictShapes,  # noqa: C901
             info = {
                 "overflow": lax.pmax(info["overflow"], RESOLVER_AXIS),
                 "boundaries": lax.pmax(info["boundaries"], RESOLVER_AXIS),
+                "evicted": lax.psum(info["evicted"], RESOLVER_AXIS),
                 # every shard's boundary count, for the host's balance
                 "fill": lax.all_gather(new_state["nb"], RESOLVER_AXIS),
                 # mask padding slots (forced COMMITTED inside conflict_step)
@@ -311,6 +312,7 @@ def _build_sharded_step(mesh: Mesh, shapes: ConflictShapes,  # noqa: C901
         local_step, mesh=mesh,
         in_specs=(state_specs, batch_specs),
         out_specs=(state_specs, P(), {"overflow": P(), "boundaries": P(),
+                                      "evicted": P(),
                                       "fill": P(), "committed": P(),
                                       "converged": P(), "eligible": P()}),
         # conflict_step's bounded-scan carries start from unvarying constants

@@ -27,7 +27,7 @@ from foundationdb_tpu.server.interfaces import (
 from foundationdb_tpu.utils.errors import FDBError
 from foundationdb_tpu.utils.knobs import KNOBS
 from foundationdb_tpu.utils.stats import CounterCollection, trace_counters_loop
-from foundationdb_tpu.utils.trace import g_trace_batch
+from foundationdb_tpu.utils.trace import SevWarn, TraceEvent, g_trace_batch
 
 
 def new_conflict_set(oldest_version: int = 0,
@@ -164,6 +164,16 @@ class Resolver:
         self._c_batches = self.counters.counter("BatchesIn")
         self._c_txns = self.counters.counter("TxnResolved")
         self._c_groups = self.counters.counter("DrainGroups")
+        # the device state's fill and churn, one sample a kernel step, read
+        # off the scalars that ride each step's verdicts (DetectHandle.steps):
+        # boundaries held after the step and the capacity they are held in
+        # (so a mean fill needs no constant), the fullest step so far, and
+        # the rows the step's window GC dropped
+        self._c_state_boundaries = self.counters.counter("StateBoundariesSum")
+        self._c_state_capacity = self.counters.counter("StateCapacitySum")
+        self._c_state_peak = self.counters.counter("StateBoundariesPeak")
+        self._c_state_evicted = self.counters.counter("StateEvictedSum")
+        self._state_near_full = False
         # conflict-hotspot detection (docs/contention.md): every rejected
         # txn's write ranges feed the decayed sketch; ratekeeper and DD poll
         # the snapshot via RESOLVER_HOT_RANGES
@@ -244,6 +254,10 @@ class Resolver:
             settle_failed(reply, e)
             raise
         if self._poisoned is not None:
+            # the batch still takes its place in the order, so that the ones
+            # chained behind it are answered too and not left at the gate
+            if req.version > self.version.get():
+                self.version.set(req.version)
             reply.send_error(self._poisoned)
             return
         if req.version <= self.version.get():
@@ -350,7 +364,7 @@ class Resolver:
             await self._drained_seq.when_at_least(seq - 1)
             if results is None:
                 results = [(None, None)] * len(entries)
-            for (req, reply, _handle, dispatch_s), (statuses, herr) in zip(
+            for (req, reply, handle, dispatch_s), (statuses, herr) in zip(
                     entries, results):
                 if err is None and herr is not None:
                     err = herr  # state overflow: fatal
@@ -360,12 +374,12 @@ class Resolver:
                     # batch errors too; the proxy's pipeline failure then
                     # drives a recovery that builds a fresh conflict set
                     if self._poisoned is None:
-                        from foundationdb_tpu.utils.trace import TraceEvent
                         TraceEvent("ResolverPoisoned", self.process.address) \
                             .detail("Version", req.version).error(err).log()
                     self._poisoned = err
                     reply.send_error(err)
                     continue
+                self._note_state(handle.steps, req.version)
                 self._finish_batch(req, reply, statuses, dispatch_s)
         finally:
             # The finally covers BOTH awaits: a cancel landing in
@@ -373,6 +387,26 @@ class Resolver:
             # sequencing gate, or every later drain group wedges forever on
             # when_at_least(seq - 1) (round-5 ADVICE, resolver.py:148).
             self._advance_drained(seq)
+
+    def _note_state(self, steps: list[tuple[int, int]], version: int):
+        """Count what each step of a batch left in the device state; say so
+        once when a step leaves it above 7/8 of its capacity (an overflow
+        poisons this resolver), and again only after it has been below."""
+        capacity = self.conflict_set.shapes.capacity
+        for boundaries, evicted in steps:
+            self._c_state_boundaries.increment(boundaries)
+            self._c_state_capacity.increment(capacity)
+            self._c_state_evicted.increment(evicted)
+            if boundaries > self._c_state_peak.value:
+                self._c_state_peak.set(boundaries)
+            near_full = 8 * boundaries > 7 * capacity
+            if near_full and not self._state_near_full:
+                TraceEvent("ResolverStateNearFull", self.process.address,
+                           severity=SevWarn) \
+                    .detail("Version", version) \
+                    .detail("Boundaries", boundaries) \
+                    .detail("Capacity", capacity).log()
+            self._state_near_full = near_full
 
     async def _balance_loop(self):
         """Cross-epoch cut rebalancing — the resolutionBalancing analogue

@@ -12,6 +12,7 @@ fixture: only one process may load the TPU library, and it is the worker that
 is given this file, once one of its tests has started.
 """
 
+import dataclasses
 import functools
 import re
 
@@ -29,6 +30,11 @@ from foundationdb_tpu.parallel import sharded_conflict as S
 
 WINDOW = 5_000_000
 SMALL = ConflictShapes(capacity=8192, txns=64, reads=128, writes=128)
+# the same batch on a state as large as fdb-bench-1chip-1m's
+# (benchmark/configs: CONFLICT_STATE_CAPACITY 2^19)
+SMALL_BATCH = pytest.mark.parametrize(
+    "shapes", [SMALL, dataclasses.replace(SMALL, capacity=1 << 19)],
+    ids=["k8192", "k524288"])
 # the full bucket chip_smoke.py's core serves with (SERVED_KNOBS)
 SERVED = ConflictShapes(capacity=1 << 18, txns=256, reads=2560, writes=2560)
 
@@ -84,24 +90,26 @@ def _compile_donated_step(shapes, topo):
     return compiled
 
 
-def test_conflict_step_donated_small_shape(topo):
-    _compile_donated_step(SMALL, topo)
+@SMALL_BATCH
+def test_conflict_step_donated_small_shape(topo, shapes):
+    _compile_donated_step(shapes, topo)
 
 
-def test_small_step_sorts_the_batch_not_the_state(topo):
+@SMALL_BATCH
+def test_small_step_sorts_the_batch_not_the_state(topo, shapes):
     """The step's order is built from the batch's M rows ranked in the
     sorted state: the compiled module sorts M rows (the eight passes of
     _lex_sort_perm) and nothing of width K + M — the wide sort is gone from
     the program, not bypassed."""
-    M = 2 * SMALL.reads + 2 * SMALL.writes
+    M = 2 * shapes.reads + 2 * shapes.writes
     widths = set()
-    compiled = _compile_donated_step(SMALL, topo)
+    compiled = _compile_donated_step(shapes, topo)
     for line in compiled.as_text().splitlines():
         result, is_sort, _operands = line.partition(") sort(")
         if is_sort:
             widths.update(int(n) for n in re.findall(r"\[(\d+)\]", result))
     assert M in widths, widths
-    assert SMALL.capacity + M not in widths, widths
+    assert shapes.capacity + M not in widths, widths
 
 
 _HLO_RESULT = re.compile(
@@ -132,7 +140,8 @@ def _wide_moves(hlo_text, widths):
     return wide
 
 
-def test_small_step_never_holds_the_keys_in_merged_order(topo):
+@SMALL_BATCH
+def test_small_step_never_holds_the_keys_in_merged_order(topo, shapes):
     """The step finds its key groups from the batch's M sorted rows and the
     order's own arithmetic (_group_starts, _batch_key_ranks), and the
     compaction gathers the kept keys from the state itself: no (L, K + M)
@@ -143,9 +152,9 @@ def test_small_step_never_holds_the_keys_in_merged_order(topo):
     and its gathers of keys (from `skeys`) and of values through them. Now:
     `sval`, the compaction's scatters of kept element indices and of kept
     values, and one gather of kept keys from the state."""
-    K, L = SMALL.capacity, SMALL.limbs
-    N = K + 2 * SMALL.reads + 2 * SMALL.writes
-    text = _compile_donated_step(SMALL, topo).as_text()
+    K, L = shapes.capacity, shapes.limbs
+    N = K + 2 * shapes.reads + 2 * shapes.writes
+    text = _compile_donated_step(shapes, topo).as_text()
     assert not re.search(rf"= u32\[({L},{N}|{N},{L})\]", text)
     assert re.search(rf"= u32\[{L},{K}\]", text)  # the pattern does match
     wide = _wide_moves(text, {K, N})
@@ -163,7 +172,8 @@ def test_combine_fn(topo):
     T = SERVED.txns
     sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
     C._combine_fn().lower(sds((T,), jnp.int32), sds((T,), jnp.bool_),
-                          sds((), jnp.bool_), sds((), jnp.bool_)).compile()
+                          sds((), jnp.bool_), sds((), jnp.bool_),
+                          sds((), jnp.int32), sds((), jnp.int32)).compile()
 
 
 def test_rebase_donated(topo):

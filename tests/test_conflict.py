@@ -899,3 +899,39 @@ def test_serving_jaxpr_has_no_while_loop():
                                      max_write_life=life))(state, batch))
     assert "while[" not in serving, "unbounded fixpoint back in serving path"
     assert "scan[" in serving  # the bounded sandwich is there
+
+
+# ---------------------------------------------------------------------------
+# a state whose fill follows the write rate: inserts and evictions every step
+# ---------------------------------------------------------------------------
+
+def test_fill_and_evictions_follow_the_oracle_past_the_window():
+    """Key space 20x a window's writes, versions advancing past the window
+    (chip_smoke.py's stream, which the chip runs at the served capacity):
+    every step inserts boundaries and, once the window is full, evicts about
+    as many; the state's fill is set by the rate, not by the key count. The
+    step's own account (info -> DetectHandle.steps) is the oracle's after
+    every step, at a capacity that is nobody's default."""
+    from chip_smoke import _oracle_fill_step, _window_stream
+    KNOBS.set("MAX_WRITE_TRANSACTION_LIFE_VERSIONS", 1000)
+    dev = small_device_set(capacity=768)  # holds ~ 2 x 240 writes a window
+    oracle = OracleConflictSet()
+    held = oracle.live_boundaries()
+    fills, dropped, verdicts = [], [], []
+    for txns, version in _window_stream(3201, n_batches=48, n_keys=4800,
+                                        step=100, txns=8, sets=3):
+        handle = dev.detect_async(txns, version)
+        got = handle.result()
+        want, held, evicted = _oracle_fill_step(oracle, txns, version, held)
+        assert got == want, f"device={got} oracle={want} @v{version}"
+        assert handle.steps == [(len(held), evicted)], version
+        assert len(held) == int(dev._state["nb"])
+        fills.append(len(held))
+        dropped.append(evicted)
+        verdicts += got
+    assert {COMMITTED, CONFLICT, TOO_OLD} <= set(verdicts)
+    # the window filled in ten steps; from then on every step drops rows and
+    # the fill stands where the rate puts it, far under the 9,600 of the keys
+    assert all(d > 0 for d in dropped[12:])
+    assert 300 < min(fills[12:]) and max(fills) < 768
+    assert sum(dropped) > 3 * max(fills)

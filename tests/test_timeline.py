@@ -343,7 +343,8 @@ def test_the_other_programs_have_names_too():
     from foundationdb_tpu.ops import conflict
     shapes = conflict._resolve_shapes(**SMALL)
     t = jnp.zeros(shapes.txns, jnp.int32)
-    text = _lowered(conflict._combine_fn(), t, t.astype(bool), False, True)
+    text = _lowered(conflict._combine_fn(), t, t.astype(bool), False, True,
+                    np.int32(1), np.int32(0))
     assert "module @jit_combine_status " in text
     text = _lowered(conflict._compiled_rebase(), conflict.init_state(shapes),
                     np.int32(5))
@@ -360,13 +361,14 @@ def test_transfer_counters_rise_by_the_batch_and_the_status_array():
     txns = [TxnConflictInfo(read_snapshot=5, read_ranges=[(b"a", b"b")],
                             write_ranges=[(b"a", b"b")])]
     # what crosses: the encoded batch in (with its floor flag), and the
-    # combined status array [statuses | eligible | overflow | converged] out
+    # combined status array [statuses | eligible | overflow | converged |
+    # boundaries | evicted] out: the state's fill and churn ride the verdicts
     nr, nw = 1, 1
     shapes, _step = cs.plan_chunk(nr, nw)
     batch = conflict.BatchEncoder(shapes).encode_batch(txns, 10, shapes=shapes)
     batch["advance_floor"] = np.bool_(True)
     batch_bytes = sum(np.asarray(v).nbytes for v in batch.values())
-    status_bytes = (2 * shapes.txns + 2) * 4
+    status_bytes = (2 * shapes.txns + 4) * 4
     before = jaxenv.transfer_metrics.as_dict()
     assert cs.detect(txns, 10) == [conflict.COMMITTED]
     after = jaxenv.transfer_metrics.as_dict()
