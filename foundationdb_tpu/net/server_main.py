@@ -67,6 +67,37 @@ def build_role(process, role: str, args: dict):
     raise ValueError(f"unknown role {role!r}")
 
 
+# Net allocations of container objects before a young collection (the
+# interpreter's default is 700, and a full collection follows every hundredth
+# young one). At 700 a commit batch's few thousand short-lived containers
+# (requests, ranges, futures, span records) are alive at several young
+# collections of their own batch and are promoted into the generation a full
+# collection walks; with the heap frozen the core still made 48-50 full
+# collections a run, each over the batches in flight. At 50,000 a batch is
+# born and dies young and a whole run makes none: +4% operations a second
+# over freeze alone where the key space is large (PERF.md section 6, PR 35).
+YOUNG_GENERATION_THRESHOLD = 50_000
+
+
+def settle_heap():
+    """The collector's policy of a server process, set once when every role
+    is built and before `ready`. Boot's garbage goes; everything alive now
+    (the modules, JAX, the loaded programs' Python side, the roles) can never
+    be garbage while the process serves, so it moves to the permanent
+    generation and no full collection walks it again; what is allocated from
+    here on (a storage server's records too) stays collectable. The watcher
+    goes in after the boot's own collection, so the counters say what
+    collections cost while serving."""
+    import gc
+
+    from foundationdb_tpu.utils import stats, trace
+    gc.collect()
+    gc.freeze()
+    gc.set_threshold(YOUNG_GENERATION_THRESHOLD, *gc.get_threshold()[1:])
+    stats.frozen_objects.set(gc.get_freeze_count())
+    trace.span_full_collections()
+
+
 def main(spec_json: str):
     from foundationdb_tpu.net.transport import NetTransport, RealEventLoop
     from foundationdb_tpu.utils.jaxenv import enable_compile_cache
@@ -99,6 +130,7 @@ def main(spec_json: str):
         role = build_role(net.process, r["role"], args)
         built.setdefault(r["role"], []).append(role)
         roles.append(role)
+    settle_heap()
     print(f"ready {spec['listen']} roles={[r['role'] for r in spec['roles']]}",
           flush=True)
     import os
@@ -123,7 +155,6 @@ def main(spec_json: str):
         trace_file = trace.RollingTraceFile(os.path.join(
             trace_dir, f"trace.{spec['listen'].replace(':', '_')}.jsonl"))
         trace.set_sink(trace_file.write)
-        trace.span_full_collections()
     try:
         loop.aio.run_forever()
     finally:
@@ -131,12 +162,11 @@ def main(spec_json: str):
             pr.disable()
             pr.dump_stats(f"{prof_path}.{spec['listen'].replace(':', '_')}")
         if trace_file is not None:
+            from foundationdb_tpu.utils.stats import whole_process_counters
             from foundationdb_tpu.utils.trace import g_trace_batch, set_sink
             # final counter dump: a short run may never reach the periodic
             # 5s tick, and the rollup wants end-of-run totals either way
-            tc = getattr(net, "transport_counters", None)
-            extra = ({"Transport" + k: v for k, v in tc().items()}
-                     if tc is not None else None)
+            extra = whole_process_counters(net)
             for role in roles:
                 coll = getattr(role, "counters", None)
                 if hasattr(coll, "trace"):

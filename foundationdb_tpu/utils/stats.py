@@ -74,42 +74,53 @@ process_metrics = CounterCollection("Process")
 loop_stalls = process_metrics.counter("LoopStalls")
 loop_stall_seconds = process_metrics.counter("LoopStallSeconds")
 loop_stall_max_seconds = process_metrics.counter("LoopStallMaxSeconds")
+# Full (generation 2) garbage collections, which hold the GIL: how many and
+# how long in all (fed by utils/trace.span_full_collections, the process's
+# one gc callback), and how many objects the heap policy of a server process
+# froze before `ready` (net/server_main.settle_heap; 0 = no policy engaged).
+full_collections = process_metrics.counter("FullCollections")
+full_collection_seconds = process_metrics.counter("FullCollectionSeconds")
+frozen_objects = process_metrics.counter("FrozenObjects")
 
 
 def process_counters() -> dict:
-    """The stall counters and the CPU seconds (user + system) this process
-    has used so far, read now."""
+    """The stall and collection counters and the CPU seconds (user + system)
+    this process has used so far, read now."""
     t = os.times()
     return dict(process_metrics.as_dict(),
                 ProcessCpuSeconds=round(t.user + t.system, 6))
 
 
+def whole_process_counters(net) -> dict:
+    """What belongs to the whole process and not to one role: the
+    transport's counters (FramesIn/Out, BytesIn/Out, ChecksumRejects,
+    NativeFastPathHits, PySlowPathFalls, ...) under a `Transport` prefix,
+    and process_counters(). A sim network has no transport and its
+    processes share one interpreter: nothing."""
+    tc = getattr(net, "transport_counters", None)
+    if tc is None:
+        return {}
+    return dict({"Transport" + k: v for k, v in tc().items()},
+                **process_counters())
+
+
 def fold_transport_counters(process, snap: dict) -> dict:
-    """Merge what belongs to the whole process into a role's metrics
-    snapshot: the transport's counters (FramesIn/Out, BytesIn/Out,
-    ChecksumRejects, NativeFastPathHits, PySlowPathFalls, ...) and
-    process_counters(). Co-hosted roles report the same tallies — the
-    rollup dedupes by process address. A sim network has no transport and
-    its processes share one interpreter; the snapshot passes through."""
-    tc = getattr(getattr(process, "net", None), "transport_counters", None)
-    if tc is not None:
-        for k, v in tc().items():
-            snap["Transport" + k] = v
-        snap.update(process_counters())
+    """Merge whole_process_counters() into a role's metrics snapshot.
+    Co-hosted roles report the same tallies — the rollup dedupes by process
+    address. On a sim network the snapshot passes through."""
+    snap.update(whole_process_counters(getattr(process, "net", None)))
     return snap
 
 
 def trace_counters_loop(process, collection: CounterCollection,
                         interval: float = 5.0):
     """Spawnable actor: dump the collection every `interval` seconds.
-    Real-network processes also carry the transport tallies in each dump
-    (Transport*-prefixed, same folding as the metrics RPC) so trace_analyze
-    can roll up wire-plane activity from the files alone."""
+    Real-network processes also carry whole_process_counters() in each dump
+    (the same folding as the metrics RPC) so trace_analyze can roll up
+    wire-plane activity, stalls and collections from the files alone."""
     async def loop():
         while True:
             await process.net.loop.delay(interval)
-            tc = getattr(process.net, "transport_counters", None)
-            extra = ({"Transport" + k: v for k, v in tc().items()}
-                     if tc is not None else None)
-            collection.trace(process.net.loop.now(), extra=extra)
+            collection.trace(process.net.loop.now(),
+                             extra=whole_process_counters(process.net))
     return process.spawn(loop(), f"traceCounters/{collection.name}")

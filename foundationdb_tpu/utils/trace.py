@@ -326,12 +326,16 @@ g_trace_batch = TraceBatch(max_buffer=1024, enabled=False)
 
 
 def span_full_collections():
-    """Write a `Loop.FullGC` span pair round every full (generation 2)
-    garbage collection of this process: it holds the GIL, so for its length
-    no thread of the process runs Python — the loop does not tick and a
-    blocking thread cannot return. Returns the callback (gc.callbacks), so
-    that a test can take it out again."""
+    """Install the process's one watcher of full (generation 2) garbage
+    collections. A full collection holds the GIL, so for its length no
+    thread of the process runs Python — the loop does not tick and a
+    blocking thread cannot return. Each one adds to the process counters
+    `FullCollections` and `FullCollectionSeconds` (utils/stats) and, while
+    spans are recorded, writes a `Loop.FullGC` span pair. Returns the
+    callback (gc.callbacks), so that a test can take it out again."""
     import gc
+
+    from foundationdb_tpu.utils import stats  # it imports this module
     began = [0.0]
 
     def on_gc(phase: str, info: dict) -> None:
@@ -339,12 +343,16 @@ def span_full_collections():
             return
         if phase == "start":
             began[0] = time.monotonic()
-        elif g_trace_batch.enabled:
+            return
+        ended = time.monotonic()
+        stats.full_collections.increment()
+        stats.full_collection_seconds.increment(ended - began[0])
+        if g_trace_batch.enabled:
             ident = f"gc{gc.get_stats()[2]['collections']}"
             g_trace_batch.span_begin("LoopSpan", ident, "Loop.FullGC",
                                      at=began[0])
             g_trace_batch.span_end("LoopSpan", ident, "Loop.FullGC",
-                                   at=time.monotonic())
+                                   at=ended)
 
     gc.callbacks.append(on_gc)
     return on_gc

@@ -255,7 +255,8 @@ def test_a_full_collection_writes_a_span_pair_while_someone_listens():
 # ------------------------------------- process counters in every snapshot
 
 PROCESS_COUNTERS = ("ProcessCpuSeconds", "LoopStalls", "LoopStallSeconds",
-                    "LoopStallMaxSeconds")
+                    "LoopStallMaxSeconds", "FullCollections",
+                    "FullCollectionSeconds", "FrozenObjects")
 
 
 @pytest.fixture(scope="module")
